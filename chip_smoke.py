@@ -1,0 +1,511 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the
+chip.
+
+    python chip_smoke.py
+
+drives the two main paths once through the entry points a user calls,
+at the full width of models the repo supports, in ONE process (a chip
+belongs to one process; nothing started here touches JAX):
+
+  trainer  ERNIE-base (hidden 768, 12 layers, vocab 18000), batch 32 x
+           seq 512, bf16 autocast, AdamW, fed by `paddle.io.DataLoader`
+           with two fork workers into `Engine.train_batch`.
+  server   gpt2-small (hidden 768, 12 layers, vocab 50304, max_seq
+           1024) behind `serving.Server(max_slots=8)` and `http_front`.
+  hybrid   (when >= 4 chips are visible) gpt2-medium, batch 16 x seq
+           512, bf16 autocast, through `make_gpt_hybrid_engine` on
+           dp2.mp2 and pp2.mp2 meshes.
+
+Weights are random from a seed.  Every check that fails, every request
+error and every exception is a non-zero exit.  A plain invocation
+refuses any platform but "tpu"; `--rehearse-cpu` is the explicit switch
+for walking the same code at toy sizes on the CPU backend while
+debugging — it checks no kernel, prints no result line and proves
+nothing about the chip.
+
+The last line of standard output is the result:
+    {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}
+Step times and compile seconds printed on the way are smoke timings
+for orientation, not measurements.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import threading
+import time
+import urllib.request
+
+import numpy as np
+
+
+# Compiled serving step vs eager forward: max |logit gap| allowed, as a
+# fraction of the logits' own standard deviation.  Measured on the v5e
+# in PR 22: the gap was 0.0 (both paths hand the MXU the same f32
+# operands at its default precision); the leg also prints what a
+# one-position slip would measure, which must be far outside the band.
+LOGIT_TOL = 0.01
+
+# dp2.mp2 vs pp2.mp2 under bf16 autocast: max relative loss gap per
+# step over three steps.  Measured on four v5e chips in PR 22: 2.1e-4.
+FACTORIZATION_RTOL = 2e-3
+
+
+class SmokeFailure(AssertionError):
+    pass
+
+
+def check(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def say(msg):
+    print(msg, flush=True)
+
+
+# ---------------------------------------------------------------------------
+# trainer leg
+# ---------------------------------------------------------------------------
+
+
+def _mlm_dataset(n, seq, vocab, seed):
+    """A small seeded in-memory MLM set: 15% of positions carry their
+    own token as the label, the rest are ignored (-100)."""
+    import paddle_tpu as paddle
+
+    rng = np.random.RandomState(seed)
+    ids = rng.randint(0, vocab, (n, seq)).astype(np.int32)
+    labels = ids.copy()
+    labels[rng.rand(n, seq) > 0.15] = -100
+
+    class MLMSet(paddle.io.Dataset):
+        def __len__(self):
+            return n
+
+        def __getitem__(self, i):
+            return ids[i], labels[i]
+
+    return MLMSet()
+
+
+def trainer_leg(on_chip):
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, native, observe
+    from paddle_tpu.engine import Engine
+    from paddle_tpu.nlp.transformers import (
+        ErnieConfig, ErnieForPretraining, ErniePretrainingCriterion,
+    )
+    from paddle_tpu.ops import fused_loss
+
+    if on_chip:
+        cfg = ErnieConfig(vocab_size=18000, hidden_size=768, num_layers=12,
+                          num_heads=12, ffn_hidden_size=3072,
+                          max_seq_len=512, dropout=0.1, attn_dropout=0.1,
+                          use_parallel=False)
+        batch, seq, cycle, epochs = 32, 512, 4, 3
+    else:
+        cfg = ErnieConfig(vocab_size=512, hidden_size=64, num_layers=2,
+                          num_heads=4, ffn_hidden_size=128, max_seq_len=32,
+                          dropout=0.1, attn_dropout=0.1, use_parallel=False)
+        batch, seq, cycle, epochs = 4, 32, 4, 3
+
+    # the two C++ libraries build from tracked sources with the system
+    # g++; no compiler at all is fine (numpy paths), a compile that
+    # FAILED is not
+    native.available()
+    native.ps_table_lib()
+    build_errors = native.build_errors()
+    check(not build_errors, f"native build failed: {build_errors}")
+
+    paddle.seed(0)
+    model = ErnieForPretraining(cfg)
+    criterion = ErniePretrainingCriterion(cfg)
+    optimizer = paddle.optimizer.AdamW(
+        learning_rate=1e-4, parameters=model.parameters(), weight_decay=0.01)
+    engine = Engine(model, optimizer,
+                    lambda out, mlm: criterion(out[0], out[1], mlm))
+
+    loader = paddle.io.DataLoader(
+        _mlm_dataset(batch * cycle, seq, cfg.vocab_size, seed=0),
+        batch_size=batch, shuffle=False, drop_last=True, num_workers=2)
+
+    lm_traces = fused_loss._TRACE_COUNT
+    losses, step_s = [], []
+    with amp.auto_cast(enable=True, dtype="bfloat16"):
+        for _ in range(epochs):
+            for ids, labels in loader:
+                t0 = time.perf_counter()
+                loss = float(np.asarray(
+                    engine.train_batch(ids, labels)._value))
+                step_s.append(time.perf_counter() - t0)
+                losses.append(loss)
+        text = engine.compiled_text() if on_chip else ""
+        step_peak = engine.memory_analysis()["peak"]
+    say(f"trainer: losses {[round(v, 4) for v in losses]}")
+    say(f"trainer: first step (compile + run) {step_s[0]:.1f} s, later "
+        f"steps {1e3 * float(np.median(step_s[1:])):.0f} ms median "
+        "(smoke timing)")
+
+    check(len(losses) >= 10, f"only {len(losses)} steps ran")
+    check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    first, last = np.mean(losses[:cycle]), np.mean(losses[-cycle:])
+    check(last < first and losses[-1] < losses[0],
+          f"loss did not fall over the cycle: {first:.4f} -> {last:.4f}")
+    compiles = [e for e in observe.compile_events()
+                if e["name"] == "train_step"]
+    check(len(compiles) == 1,
+          f"train_step compiled {len(compiles)} times: {compiles}")
+
+    info = {"compile_s": round(step_s[0], 1)}
+    if on_chip:
+        # flash fwd + dq + dk/dv in every layer, LM loss fwd + dx + dw
+        n_calls = text.count("tpu_custom_call")
+        want = 3 * cfg.num_layers + 3
+        check(fused_loss._TRACE_COUNT > lm_traces,
+              "the fused LM-head loss kernel was never traced")
+        check(n_calls >= want,
+              f"{n_calls} Mosaic calls in the compiled step, expected "
+              f">= {want} (flash attention and the fused LM-head loss)")
+        stats = paddle.device.memory_stats()
+        check(stats.get("source") != "live_array_census"
+              and stats.get("peak_bytes_in_use", -1) > 0,
+              f"no allocator peak from the device: {stats}")
+        say(f"trainer: {n_calls} Mosaic calls in the compiled step; "
+            f"allocator peak {stats['peak_bytes_in_use'] / 2**30:.2f} GiB "
+            f"(live buffers), compiled step peak {step_peak / 2**30:.2f} "
+            "GiB (XLA buffer assignment)")
+        info["mosaic_calls"] = n_calls
+    return info
+
+
+# ---------------------------------------------------------------------------
+# server leg
+# ---------------------------------------------------------------------------
+
+
+def _prefill_logits(eng, prompt):
+    """Drive one request synchronously on an IDLE engine (no loop
+    thread; the tests' idiom) and return the logits row its last
+    prefill step handed to sampling — the compiled step's answer for
+    the prompt's next token."""
+    fut = eng.submit(np.asarray(prompt, np.int32), max_new_tokens=2,
+                     timeout=None)
+    eng._admit()
+    first = None
+    while eng.active:
+        eng._step()
+        if first is None:
+            for s in eng._slots:
+                if s is not None and s.state == "decode" \
+                        and s.next_logits is not None:
+                    first = np.asarray(s.next_logits).copy()
+    fut.result(timeout=60)
+    return first
+
+
+def server_leg(on_chip):
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.nlp.transformers import (
+        GPTConfig, GPTForPretraining, gpt_config,
+    )
+
+    if on_chip:
+        cfg = gpt_config("gpt2-small", dropout=0.0, attn_dropout=0.0,
+                         use_parallel=False)
+        max_new = 32
+    else:
+        cfg = GPTConfig(vocab_size=512, hidden_size=64, num_layers=2,
+                        num_heads=4, max_seq_len=128, dropout=0.0,
+                        attn_dropout=0.0, use_parallel=False)
+        max_new = 8
+
+    paddle.seed(1)
+    model = GPTForPretraining(cfg)
+    srv = serving.Server(model, max_slots=8)
+    eng = srv.engine
+    chunk = eng.prefill_chunk
+    rng = np.random.RandomState(2)
+
+    def prompt(n):
+        return rng.randint(1, cfg.vocab_size, n).astype(np.int32)
+
+    # warm-up compile, then — while the engine is still idle and owns
+    # nothing — one pinned prompt through the compiled step against the
+    # eager forward of the same weights on the same device
+    t0 = time.perf_counter()
+    eng.warmup()
+    warm_s = time.perf_counter() - t0
+    check(eng.compile_counts == {"decode": 1, "cow": 1},
+          f"warm-up compile counts {eng.compile_counts}")
+    pinned = prompt(2 * chunk + 7)      # crosses two chunk boundaries
+    got = _prefill_logits(eng, pinned)
+    eager = np.asarray(
+        model(paddle.to_tensor(pinned[None, :]))._value)[0] \
+        .astype(np.float32)
+    want = eager[-1]
+    check(got is not None and got.shape == want.shape,
+          "no prefill logits from the compiled step")
+    err = float(np.abs(got - want).max())
+    spread = float(want.std())
+    # what a position slip would look like — the comparison has teeth
+    # only if this is far outside the tolerance
+    slip = float(np.abs(got - eager[-2]).max())
+    say(f"server: warm-up compile {warm_s:.1f} s (smoke timing); pinned "
+        f"prompt logits max |compiled - eager| = {err:.3e} (one "
+        f"position off would be {slip:.3e}), logit std {spread:.3e}, "
+        f"argmax equal: {int(got.argmax()) == int(want.argmax())}")
+    check(slip > 10 * LOGIT_TOL * spread,
+          "the eager reference does not separate positions")
+    check(np.isfinite(got).all() and err <= LOGIT_TOL * spread,
+          f"compiled-step logits off the eager forward by {err:.3e} "
+          f"(> {LOGIT_TOL} x logit std {spread:.3e})")
+
+    srv.start()
+    httpd = serving.http_front(srv)
+    port = httpd.server_address[1]
+    results, errors = {}, []
+
+    def run(name, ids):
+        try:
+            results[name] = (ids, np.asarray(srv.generate(
+                ids, timeout=600.0, max_new_tokens=max_new)))
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(f"{name}: {type(e).__name__}: {e}")
+
+    def run_http(name, ids):
+        try:
+            body = json.dumps({"prompt": ids.tolist(), "timeout": 600.0,
+                               "max_new_tokens": max_new}).encode()
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{port}/v1/generate", data=body,
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=600) as resp:
+                results[name] = (ids, np.asarray(
+                    json.loads(resp.read())["ids"]))
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(f"{name}: {type(e).__name__}: {e}")
+
+    def wave(jobs):
+        threads = [threading.Thread(target=fn, args=(name, ids))
+                   for fn, name, ids in jobs]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(900)
+        check(not any(t.is_alive() for t in threads),
+              "a request thread is still waiting after 900 s")
+
+    try:
+        shared = prompt(chunk + chunk // 2)
+        t0 = time.perf_counter()
+        # seven at once (shorter and longer than the prefill chunk) ...
+        wave([(run, f"r{i}", p) for i, p in enumerate([
+            prompt(chunk // 3), prompt(chunk - 1), prompt(chunk + 1),
+            prompt(3 * chunk + 5), prompt(5 * chunk),
+            np.concatenate([shared, prompt(3)]), prompt(chunk // 2)])])
+        # ... then, once the first owner of the shared prefix finished
+        # and its blocks are indexed, its sibling and the HTTP request
+        wave([(run, "sibling", np.concatenate([shared, prompt(5)])),
+              (run_http, "http", prompt(chunk + 3))])
+        serve_s = time.perf_counter() - t0
+        snap = srv.snapshot()
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+        srv.shutdown(drain=True)
+
+    check(not errors, f"request errors: {errors}")
+    check(len(results) == 9, f"{len(results)} of 9 requests answered")
+    for name, (ids, out) in results.items():
+        check(out.shape == (ids.size + max_new,),
+              f"{name}: answer shape {out.shape}, wanted "
+              f"{ids.size + max_new}")
+        check((out[:ids.size] == ids).all(), f"{name}: prompt not echoed")
+        check(((out >= 0) & (out < cfg.vocab_size)).all(),
+              f"{name}: token outside the vocabulary")
+    counters = snap["counters"]
+    bad = {k: v for k, v in counters.items() if v and (
+        k in ("failed", "timeouts", "cancelled", "step_errors")
+        or k.startswith("rejected"))}
+    check(not bad, f"errors or sheds in snapshot(): {bad}")
+    check(counters.get("completed") == 10,     # pinned + 9
+          f"completed {counters.get('completed')} of 10")
+    check(eng.compile_counts == {"decode": 1, "cow": 1},
+          f"compile counts after the last request: {eng.compile_counts}")
+    hits = snap.get("prefix_cache", {}).get("hit_tokens", 0)
+    check(hits > 0, "the prefix cache reports no hit")
+    say(f"server: 9 requests x {max_new} new tokens in {serve_s:.1f} s "
+        f"(smoke timing), prefix-cache hit tokens {hits}, compile counts "
+        f"{eng.compile_counts}")
+    return {"warmup_s": round(warm_s, 1), "logit_err": err,
+            "logit_std": spread}
+
+
+# ---------------------------------------------------------------------------
+# four-chip leg
+# ---------------------------------------------------------------------------
+
+
+def _hybrid_run(name, degrees, cfg, tokens, steps, on_chip):
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu import amp
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.distributed.hybrid import make_gpt_hybrid_engine
+    from paddle_tpu.distributed.topology import (
+        set_hybrid_communicate_group,
+    )
+    from paddle_tpu.nlp.transformers import (
+        GPTForPretraining, GPTPretrainingCriterion,
+    )
+
+    strategy = fleet.DistributedStrategy()
+    strategy.hybrid_configs = dict(
+        {"dp_degree": 1, "mp_degree": 1, "pp_degree": 1,
+         "sharding_degree": 1}, **degrees)
+    fleet.init(is_collective=True, strategy=strategy)
+    hcg = fleet.get_hybrid_communicate_group()
+    try:
+        paddle.seed(7)          # both factorizations start identical
+        model = GPTForPretraining(cfg)
+        crit = GPTPretrainingCriterion(cfg)
+        opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                     parameters=model.parameters(),
+                                     weight_decay=0.01)
+        pp = degrees.get("pp_degree", 1)
+        eng = make_gpt_hybrid_engine(
+            model, crit, opt, hcg,
+            accumulate_steps=2 * pp if pp > 1 else 1)
+        x, y = tokens[:, :-1], tokens[:, 1:]
+        with amp.auto_cast(enable=True, dtype="bfloat16"):
+            t0 = time.perf_counter()
+            losses = [float(np.asarray(eng.train_batch(x, y)._value))]
+            compile_s = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            for _ in range(steps - 1):
+                losses.append(
+                    float(np.asarray(eng.train_batch(x, y)._value)))
+            step_ms = 1e3 * (time.perf_counter() - t0) / (steps - 1)
+            text = eng.compiled_text() if on_chip else ""
+        say(f"hybrid {name}: losses {[round(v, 4) for v in losses]}; "
+            f"compile + first step {compile_s:.1f} s, later steps "
+            f"{step_ms:.0f} ms (smoke timing)")
+        check(all(np.isfinite(losses)), f"{name}: non-finite loss")
+
+        # against "everything on the first chip": an mp-sharded weight
+        # has one shard on each of four distinct devices, and every
+        # device's allocator holds bytes
+        leaf = eng.block_params["attn.qkv_proj.weight"]
+        devs = {s.device for s in leaf.addressable_shards}
+        check(len(devs) == 4,
+              f"{name}: qkv weight sits on {len(devs)} device(s)")
+        check(leaf.addressable_shards[0].data.size < leaf.size,
+              f"{name}: qkv weight is not sharded")
+        if on_chip:
+            for d in jax.devices()[:4]:
+                used = d.memory_stats()["bytes_in_use"]
+                check(used > 0, f"{name}: nothing resident on {d}")
+            n_calls = text.count("tpu_custom_call")
+            check(n_calls >= 3,
+                  f"{name}: {n_calls} Mosaic calls in the partitioned "
+                  "program; flash attention fell out of it")
+            say(f"hybrid {name}: {n_calls} Mosaic calls in the "
+                "partitioned program")
+        return losses, compile_s
+    finally:
+        set_hybrid_communicate_group(None)
+
+
+def hybrid_leg(on_chip):
+    from paddle_tpu.nlp.transformers import GPTConfig, gpt_config
+
+    if on_chip:
+        cfg = gpt_config("gpt2-medium", max_seq_len=512, dropout=0.0,
+                         use_parallel=True, sequence_parallel=True)
+        batch, seq = 16, 512
+    else:
+        cfg = GPTConfig(vocab_size=256, hidden_size=64, num_layers=4,
+                        num_heads=4, max_seq_len=32, dropout=0.0,
+                        attn_dropout=0.0, use_parallel=True,
+                        sequence_parallel=True)
+        batch, seq = 8, 32
+    tokens = np.random.RandomState(3).randint(
+        0, cfg.vocab_size, (batch, seq + 1)).astype(np.int32)
+    runs, compile_s = {}, {}
+    for name, degrees in (("dp2.mp2", {"dp_degree": 2, "mp_degree": 2}),
+                          ("pp2.mp2", {"pp_degree": 2, "mp_degree": 2})):
+        runs[name], compile_s[name] = _hybrid_run(
+            name, degrees, cfg, tokens, 3, on_chip)
+    a, b = np.asarray(runs["dp2.mp2"]), np.asarray(runs["pp2.mp2"])
+    rel = float(np.max(np.abs(a - b) / np.abs(a)))
+    say(f"hybrid: dp2.mp2 vs pp2.mp2 max relative loss gap {rel:.2e}")
+    check(rel <= FACTORIZATION_RTOL,
+          f"the two factorizations disagree: {rel:.2e} > "
+          f"{FACTORIZATION_RTOL}")
+    return {"factorization_gap": rel,
+            "compile_s": {k: round(v, 1) for k, v in compile_s.items()}}
+
+
+# ---------------------------------------------------------------------------
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument(
+        "--rehearse-cpu", action="store_true",
+        help="walk the same code at toy sizes on a non-TPU backend "
+             "(debugging aid; prints no result line)")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    say(f"devices: platform={device['platform']} "
+        f"kind={device['kind']} count={device['count']}")
+    on_chip = device["platform"] == "tpu"
+    if not on_chip and not args.rehearse_cpu:
+        print(f"chip_smoke.py needs a TPU; JAX found platform "
+              f"{device['platform']!r}", file=sys.stderr)
+        return 2
+    if on_chip and args.rehearse_cpu:
+        print("--rehearse-cpu is for machines without a TPU",
+              file=sys.stderr)
+        return 2
+
+    t_all = time.perf_counter()
+    legs = [("trainer", trainer_leg), ("server", server_leg)]
+    if len(devs) >= 4:
+        legs.append(("hybrid", hybrid_leg))
+    else:
+        say(f"hybrid: skipped, {len(devs)} device(s) visible and the "
+            "dp2.mp2 / pp2.mp2 meshes need four")
+    info = {}
+    for name, leg in legs:
+        t0 = time.perf_counter()
+        try:
+            info[name] = leg(on_chip)
+        except Exception:  # noqa: BLE001 — any failure fails the smoke
+            import traceback
+
+            traceback.print_exc()
+            print(f"chip_smoke.py: {name} leg FAILED", file=sys.stderr)
+            return 1
+        say(f"{name} leg passed in {time.perf_counter() - t0:.1f} s")
+    say(f"smoke summary: {json.dumps(info)} "
+        f"total {time.perf_counter() - t_all:.1f} s")
+    if not on_chip:
+        say("rehearsal finished: NOT a chip result")
+        return 0
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

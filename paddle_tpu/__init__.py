@@ -14,53 +14,21 @@ Architecture (see SURVEY.md §7 for the full mapping):
 
 from __future__ import annotations
 
-# -- jax version compat ----------------------------------------------------
-# top-level `jax.shard_map` (with the `axis_names=` / `check_vma=`
-# keywords) only exists in newer jax; on older installs adapt the
-# experimental API (`auto=` complement, `check_rep=`) so the pipeline /
-# context-parallel shard_map call sites run unchanged on either version.
+# -- persistent compile cache ----------------------------------------------
+# One place for every entry point (trainer, server, launcher children,
+# bench scripts, chip_smoke.py).  JAX_COMPILATION_CACHE_DIR, when set,
+# is read by jax itself and nothing here overrides it; otherwise the
+# cache sits at one fixed path inside the checkout — the path is part
+# of the cache key, so it never comes from tempfile, a pid or the time.
+import os as _os
+
 import jax as _jax
 
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-                   check_vma=True):
-        manual = frozenset(axis_names) if axis_names is not None \
-            else frozenset(mesh.axis_names)
-        # promote trivial (size-1) non-manual axes to manual: their
-        # per-shard view IS the full array, so semantics are unchanged.
-        # Genuinely partial-manual programs (auto axes of size > 1) hit
-        # XLA check failures on this jax — refuse cleanly instead.
-        auto = frozenset(a for a in mesh.axis_names
-                         if a not in manual and mesh.shape[a] > 1)
-        if auto:
-            raise NotImplementedError(
-                f"partial-manual shard_map (auto axes {sorted(auto)}) "
-                "requires a newer jax than this install")
-        # NB: `bool` is shadowed at module scope by the dtype handle —
-        # pass the flag through untouched (call sites pass a plain bool)
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs,
-                               check_rep=True if check_vma else False,
-                               auto=frozenset())
-
-    _shard_map.__paddle_tpu_compat__ = True
-    _jax.shard_map = _shard_map
-
-if not hasattr(_jax.sharding, "get_abstract_mesh"):
-    from jax._src import mesh as _mesh_lib
-
-    _jax.sharding.get_abstract_mesh = _mesh_lib.get_abstract_mesh
-
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-
-    if not hasattr(_pltpu, "CompilerParams") \
-            and hasattr(_pltpu, "TPUCompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except ImportError:  # pallas backend not present on this install
-    pass
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
 
 # -- core ------------------------------------------------------------------
 from .core.tensor import Parameter, Tensor  # noqa: F401

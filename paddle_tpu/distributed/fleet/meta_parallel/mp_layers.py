@@ -43,31 +43,9 @@ def shard_hint(x, *spec):
 
     # inside shard_map (e.g. the pipeline's manual 'pp' region) the trace
     # carries an abstract mesh; constraints must be built on it
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            mesh = am
-    except (AttributeError, RuntimeError):
-        pass
-    if getattr(jax.shard_map, "__paddle_tpu_compat__", False):
-        # old-jax compat shard_map runs fully manual (trivial axes are
-        # promoted), so a hint naming a manual axis is rejected at
-        # lowering; it would constrain a size-1 axis — a no-op — so
-        # dropping it is exact
-        try:
-            from jax._src import core as _core
-
-            manual = set(_core.get_axis_env().axis_sizes)
-        except (AttributeError, ImportError):
-            manual = set()
-        if manual:
-            named = set()
-            for part in spec:
-                if part is None:
-                    continue
-                named.update(part if isinstance(part, tuple) else (part,))
-            if named & manual:
-                return x
+    am = jax.sharding.get_abstract_mesh()
+    if am.axis_names:
+        mesh = am
     constrained = jax.lax.with_sharding_constraint(
         v, NamedSharding(mesh, P(*spec)))
     if isinstance(x, Tensor):

@@ -263,7 +263,7 @@ class PipelineEngine:
 
             _observe.record_compile(
                 "pp.train_step", signature=_observe.signature_of(x, y))
-            with gspmd_tracing():
+            with gspmd_tracing(mesh):
                 def loss_of(rows, shared):
                     losses = run(rows, (shared, bufs), x, extra=y,
                                  key=key)
@@ -352,15 +352,9 @@ class PipelineEngine:
 
         def step_fn(params, opt_state, buffers, x, y, lr, key):
             from .. import observe as _observe
-            from ..ops.fused_ops import gspmd_tracing
 
             _observe.record_compile(
                 "pp.train_step", signature=_observe.signature_of(x, y))
-            with gspmd_tracing():  # meshed: attention partitions via cp
-                return _step_impl(params, opt_state, buffers, x, y, lr,
-                                  key)
-
-        def _step_impl(params, opt_state, buffers, x, y, lr, key):
             # x, y: [M, micro_batch, ...]
             def accum(carry, mb):
                 gsum, lsum, i = carry

@@ -193,15 +193,11 @@ def host_offload_shardings(mesh, dev_sh_tree):
 
 
 def _host_memory_kind(mesh):
-    """'pinned_host' when the backend exposes it (TPU + recent CPU), else
+    """'pinned_host' when the backend exposes it (TPU and CPU do), else
     None — offload degrades to device memory with a warning."""
-    try:
-        dev = next(iter(mesh.devices.flat))
-        kinds = {m.kind for m in dev.addressable_memories()}
-        if "pinned_host" in kinds:
-            return "pinned_host"
-    except Exception:  # noqa: BLE001 — older jax without memories API
-        pass
+    dev = next(iter(mesh.devices.flat))
+    if "pinned_host" in {m.kind for m in dev.addressable_memories()}:
+        return "pinned_host"
     import warnings
 
     warnings.warn("optimizer-state offload requested but the backend has "
@@ -525,13 +521,13 @@ def make_train_step(layer, loss_fn, optimizer, *, grad_clip=None,
     if mesh is None:
         step_fn = _step_impl
     else:
-        # meshed step: GSPMD-partitioned program — attention routes
-        # through custom_partitioning so the Mosaic kernel runs
-        # per-shard (fused_ops.gspmd_tracing)
+        # meshed step: GSPMD-partitioned program — attention runs under
+        # a shard_map over the batch/head axes so the Mosaic kernel
+        # runs per-shard (fused_ops.gspmd_tracing)
         def step_fn(params, buffers, opt_state, batch, lr, key):
             from .ops.fused_ops import gspmd_tracing
 
-            with gspmd_tracing():
+            with gspmd_tracing(mesh):
                 return _step_impl(params, buffers, opt_state, batch,
                                   lr, key)
 
@@ -590,6 +586,18 @@ def make_eval_step(layer, mesh=None):
                 layer.train()
 
     return jax.jit(eval_fn)
+
+
+def compile_step(step_fn, protos):
+    """Lower and compile the SAME program an engine's `train_batch`
+    runs (a persistent-cache hit), kept out of the compile-event
+    registry and any no_retrace guard."""
+    if step_fn is None or protos is None:
+        raise RuntimeError("run train_batch() once first")
+    from . import observe as _observe
+
+    with _observe.retrace.suppress():
+        return step_fn.lower(*protos).compile()
 
 
 class Engine:
@@ -859,16 +867,11 @@ class Engine:
         alias (donated arg<->output reuse), generated_code, peak
         (XLA's peak liveness when reported, else arg+temp+out-alias);
         host_* mirror them for host-memory-kind buffers (offload)."""
-        if self._step_fn is None or self._step_protos is None:
-            raise RuntimeError("run train_batch() once first")
         if self._mem_analysis is None:
             from . import observe as _observe
 
-            # deliberate re-lowering of the SAME program: keep it out
-            # of the compile-event registry (and any no_retrace guard)
-            with _observe.retrace.suppress():
-                ma = self._step_fn.lower(*self._step_protos) \
-                    .compile().memory_analysis()
+            ma = compile_step(self._step_fn,
+                              self._step_protos).memory_analysis()
             peak = getattr(ma, "peak_memory_in_bytes", 0) or (
                 ma.argument_size_in_bytes + ma.temp_size_in_bytes
                 + ma.output_size_in_bytes - ma.alias_size_in_bytes)
@@ -891,6 +894,11 @@ class Engine:
             # peak memory next to each program's signature
             _observe.annotate("train_step", peak_bytes=peak)
         return dict(self._mem_analysis)
+
+    def compiled_text(self) -> str:
+        """Optimized HLO of the compiled train step — the program the
+        device runs; a Mosaic kernel shows in it as `tpu_custom_call`."""
+        return compile_step(self._step_fn, self._step_protos).as_text()
 
     def attach_checkpoint_manager(self, manager):
         """Give the anomaly guard a rollback target: when

@@ -7,6 +7,8 @@ a per-version cache and loaded via ctypes (no pybind11 dependency).
 
 Public surface:
   available()                     -> bool (toolchain + build ok)
+  build_errors()                  -> {component: message} for builds that
+                                     were attempted and FAILED
   gather_rows(src, indices)       -> np.ndarray, == src[indices] but
                                      GIL-free and multi-threaded
   gather_images_u8_chw(src, idx, scale, shift)
@@ -90,14 +92,38 @@ def _build():
     return lib
 
 
+_NO_COMPILER = "no g++ on this machine"
+
+
+def _try_build(build):
+    """(lib, None) or (None, why).  A machine without a compiler is a
+    supported configuration (the numpy paths serve); a compiler that
+    ran and failed, or a library that will not load, is a defect that
+    `build_errors()` reports."""
+    try:
+        return build(), None
+    except FileNotFoundError:
+        return None, _NO_COMPILER
+    except subprocess.CalledProcessError as e:
+        return None, f"{e}\n{e.stderr or ''}".strip()
+    except OSError as e:
+        return None, str(e)
+
+
+def build_errors() -> dict:
+    """Native builds that were attempted and failed, by component;
+    empty when every attempted build worked or there is no compiler."""
+    with _lock:
+        found = {"datafeed": _build_error, "ps_table": _ps_build_error}
+    return {k: v for k, v in found.items()
+            if v is not None and v != _NO_COMPILER}
+
+
 def _get_lib():
     global _lib, _build_error
     with _lock:
         if _lib is None and _build_error is None:
-            try:
-                _lib = _build()
-            except (OSError, subprocess.CalledProcessError) as e:
-                _build_error = str(e)
+            _lib, _build_error = _try_build(_build)
         return _lib
 
 
@@ -245,8 +271,5 @@ def ps_table_lib():
     global _ps_lib, _ps_build_error
     with _lock:
         if _ps_lib is None and _ps_build_error is None:
-            try:
-                _ps_lib = _build_ps()
-            except (OSError, subprocess.CalledProcessError) as e:
-                _ps_build_error = str(e)
+            _ps_lib, _ps_build_error = _try_build(_build_ps)
         return _ps_lib

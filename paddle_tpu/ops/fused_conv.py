@@ -28,9 +28,8 @@ lax reference conv (jax.linear_transpose — exact, no extra forward).
 Gating mirrors fused_ops: FLAGS_use_pallas_conv + on-TPU backend, with
 PADDLE_TPU_CONV_FORCE=pallas|lax overriding (pallas off-TPU runs the
 kernels in interpreter mode so CPU tier-1 certifies the exact kernel
-math + backward).  On a real TPU the first use runs a tiny probe conv
-and permanently falls back to the XLA path if Mosaic rejects the
-lowering, so the bench can never be wedged by a kernel regression.
+math + backward).  On a TPU a conv the plan accepts either lowers or
+the step raises with Mosaic's message — there is no fallback.
 """
 
 from __future__ import annotations
@@ -42,13 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.op_registry import register_op
 from .nn_ops import _bn_act_core, _conv_padding, _pair
@@ -60,37 +53,21 @@ from .nn_ops import _bn_act_core, _conv_padding, _pair
 _VMEM_BUDGET = 10 * 2**20
 _MAX_TAPS = 4  # per spatial dim, post stride-lowering (k<=8 at s=2)
 
-# incremented whenever a pallas conv is traced (not the lax fallback) —
-# the tpu-tier spy test asserts the compiled ResNet step goes through
-# the kernel rather than silently falling back
+# incremented whenever a pallas conv is traced (not the lax path) — the
+# tpu-tier spy test asserts the compiled ResNet step goes through the
+# kernel
 _TRACE_COUNT = 0
-
-_warned_no_pltpu = False
-_probe_result = None  # None=untried, True=kernel lowers, False=disabled
 
 
 def _use_pallas_conv() -> bool:
     force = os.environ.get("PADDLE_TPU_CONV_FORCE", "")
     if force == "pallas":
-        if not _HAS_PLTPU:
-            global _warned_no_pltpu
-            if not _warned_no_pltpu:
-                _warned_no_pltpu = True
-                import warnings
-
-                warnings.warn("pallas TPU backend unavailable; conv uses "
-                              "the XLA path")
-            return False
         return True
     if force == "lax":
         return False
     from ..framework.flags import flag
 
-    if not flag("FLAGS_use_pallas_conv"):
-        return False
-    if not (_HAS_PLTPU and jax.default_backend() == "tpu"):
-        return False
-    return _probe()
+    return flag("FLAGS_use_pallas_conv") and jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -99,34 +76,7 @@ def _interpret() -> bool:
 
 
 def _compiler_params(semantics):
-    if not _HAS_PLTPU:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    return cls(dimension_semantics=tuple(semantics)) if cls else None
-
-
-def _probe() -> bool:
-    """One tiny conv through the kernel on first on-TPU use; a Mosaic
-    lowering failure disables the pallas path for the session instead of
-    wedging every subsequent step (this container is CPU-only, so the
-    real-TPU lowering is exactly the part tier-1 cannot certify)."""
-    global _probe_result
-    if _probe_result is None:
-        try:
-            x = jnp.zeros((1, 8, 10, 16), jnp.float32)
-            w = jnp.zeros((128, 16, 3, 3), jnp.float32)
-            plan = _plan(x.shape, w.shape, (1, 1), ((1, 1), (1, 1)), 4)
-            xp, wk = _lower(x, w, plan)
-            _pallas_conv(xp, wk, plan)[0].block_until_ready()
-            _probe_result = True
-        except Exception as e:  # noqa: BLE001 — any lowering error
-            _probe_result = False
-            import warnings
-
-            warnings.warn(f"pallas conv probe failed ({e!r}); convs use "
-                          "the XLA path")
-    return _probe_result
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
 
 def _mm(a, b, ca: int, cb: int):
@@ -253,12 +203,9 @@ def _conv_kernel(x_ref, w_ref, *refs, kk, wo, act, fuse, has_res, moments):
         m1, m2 = carry
         acc = jnp.zeros((wo, ot), jnp.float32)
         for dh in range(kkh):
-            # all-slice indices: int indices break interpret-mode
-            # discharge on older jax
-            xrow = pl.load(x_ref, (pl.dslice(0, 1), pl.dslice(i + dh, 1),
-                                   slice(None), slice(None)))[0, 0]  # (Wp, Ce)
             for dw in range(kkw):
-                acc += _mm(xrow[dw:dw + wo], w_ref[dh * kkw + dw], 1, 0)
+                acc += _mm(x_ref[0, i + dh, pl.ds(dw, wo), :],
+                           w_ref[dh * kkw + dw], 1, 0)
         if moments:
             m1 = m1 + jnp.sum(acc, axis=0, keepdims=True)
             m2 = m2 + jnp.sum(acc * acc, axis=0, keepdims=True)
@@ -266,14 +213,10 @@ def _conv_kernel(x_ref, w_ref, *refs, kk, wo, act, fuse, has_res, moments):
         if fuse:
             z = z * g_ref[...] + b_ref[...]
         if has_res:
-            z = z + pl.load(
-                r_ref, (pl.dslice(0, 1), pl.dslice(i, 1), slice(None),
-                        slice(None)))[0, 0].astype(jnp.float32)
+            z = z + r_ref[0, i].astype(jnp.float32)
         if act == "relu":
             z = jnp.maximum(z, 0.0)
-        pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(i, 1), slice(None),
-                         slice(None)),
-                 z[None, None].astype(o_ref.dtype))
+        o_ref[0, i] = z.astype(o_ref.dtype)
         return m1, m2
 
     z0 = jnp.zeros((1, ot), jnp.float32)
@@ -305,8 +248,7 @@ def _pallas_conv(xp, wk, plan, *, g=None, b=None, res=None,
     out_dtype = out_dtype or xp.dtype
 
     def bspec(shape, imap):
-        return pl.BlockSpec(shape, imap,
-                            memory_space=pltpu.VMEM if _HAS_PLTPU else None)
+        return pl.BlockSpec(shape, imap, memory_space=pltpu.VMEM)
 
     in_specs = [bspec((1, hp, wp, ce), lambda i, j: (i, 0, 0, 0)),
                 bspec((kk, ce, ot), lambda i, j: (0, 0, j))]

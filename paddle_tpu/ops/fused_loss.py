@@ -20,10 +20,10 @@ Numerics match `cross_entropy(matmul(x, w.T))` exactly at fp32 (same
 lse formulation) and to bf16 tolerance under AMP: operands stay bf16
 into the MXU with f32 accumulation (`_mm`), loss/lse are f32.
 
-Three execution paths, gated exactly like fused_conv:
-  * Pallas TPU kernels when `FLAGS_use_pallas` and the backend is TPU
-    (first use probes a tiny call and permanently falls back if Mosaic
-    rejects the lowering).
+Three execution paths, selected by platform only:
+  * Pallas TPU kernels when `FLAGS_use_pallas` and the backend is TPU;
+    a kernel Mosaic refuses raises out of the step with Mosaic's
+    message — there is no fallback on the chip.
   * The same kernels in interpreter mode when
     PADDLE_TPU_LMLOSS_FORCE=pallas off-TPU, so CPU tier-1 certifies the
     exact kernel math + backward.
@@ -40,13 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.op_registry import register_op
 
@@ -64,9 +58,6 @@ _CHUNK_V = 1024
 # fallback) — tests assert the forced path really goes through the
 # kernels rather than silently falling back
 _TRACE_COUNT = 0
-
-_warned_no_pltpu = False
-_probe_result = None  # None=untried, True=kernel lowers, False=disabled
 
 
 def _mm(a, b, ca: int, cb: int):
@@ -86,65 +77,23 @@ def _round_up(a: int, b: int) -> int:
 
 
 def _compiler_params(semantics):
-    if not _HAS_PLTPU:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    return cls(dimension_semantics=tuple(semantics)) if cls else None
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
 
 def _use_pallas_lm() -> bool:
     force = os.environ.get("PADDLE_TPU_LMLOSS_FORCE", "")
     if force == "pallas":
-        if not _HAS_PLTPU:
-            global _warned_no_pltpu
-            if not _warned_no_pltpu:
-                _warned_no_pltpu = True
-                import warnings
-
-                warnings.warn("pallas TPU backend unavailable; fused "
-                              "lm loss uses the lax path")
-            return False
         return True
     if force == "lax":
         return False
     from ..framework.flags import flag
 
-    if not flag("FLAGS_use_pallas"):
-        return False
-    if not (_HAS_PLTPU and jax.default_backend() == "tpu"):
-        return False
-    return _probe()
+    return flag("FLAGS_use_pallas") and jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
     return (os.environ.get("PADDLE_TPU_LMLOSS_FORCE", "") == "pallas"
             and jax.default_backend() != "tpu")
-
-
-def _probe() -> bool:
-    """One tiny fused loss through the kernels on first on-TPU use; a
-    Mosaic lowering failure disables the pallas path for the session
-    instead of wedging every step (mirrors fused_conv._probe — the
-    real-TPU lowering is the one part CPU tier-1 cannot certify)."""
-    global _probe_result
-    if _probe_result is None:
-        try:
-            x = jnp.zeros((8, 128), jnp.float32)
-            w = jnp.zeros((256, 128), jnp.float32)
-            lbl = jnp.zeros((8,), jnp.int32)
-            nll, lse = _fwd_pallas(x, w, lbl, 128)
-            jax.block_until_ready(
-                _bwd_pallas(x, w, lbl, lse, jnp.ones_like(nll), 128))
-            _probe_result = True
-        except Exception as e:  # pragma: no cover - TPU only
-            _probe_result = False
-            import warnings
-
-            warnings.warn(
-                "pallas fused lm loss failed to lower; using the lax "
-                f"chunked path for this session ({type(e).__name__}: {e})")
-    return _probe_result
 
 
 # ---------------------------------------------------------------------------
@@ -302,7 +251,7 @@ def _fwd_pallas(x, w, labels, cv):
     n, h = x.shape
     v = w.shape[0]
     xp, wp, lblp, bn, nr, n_pad, cv, nv = _pad_operands(x, w, labels, cv)
-    vmem = pltpu.VMEM  # call sites gate on _HAS_PLTPU
+    vmem = pltpu.VMEM
     bspec = lambda shape, imap: pl.BlockSpec(  # noqa: E731
         shape, imap, memory_space=vmem)
     loss, lse = pl.pallas_call(

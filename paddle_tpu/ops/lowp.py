@@ -28,9 +28,10 @@ donated carry — never a host sync or retrace), or dynamic current-step
 abs-max (everywhere else: the hybrid block scan, the overlap-ring
 per-shard partials, eager calls).
 
-Three execution paths, gated exactly like quant_ops/fused_loss:
-  * Pallas TPU kernels when FLAGS_use_pallas and the backend is TPU
-    (first use probes a tiny call, permanent fallback on failure).
+Three execution paths, selected by platform only (as in
+quant_ops/fused_loss):
+  * Pallas TPU kernels when FLAGS_use_pallas and the backend is TPU;
+    a lowering Mosaic refuses raises — no fallback on the chip.
   * The same kernels in interpreter mode when
     PADDLE_TPU_LOWP_FORCE=pallas off-TPU, so CPU tier-1 certifies the
     exact kernel math (int8 parity with the lax path is bitwise:
@@ -49,13 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..framework import monitor
 
@@ -76,9 +71,7 @@ _EPS = 1e-9
 # kernels rather than silently falling back
 _TRACE_COUNT = 0
 
-_warned_no_pltpu = False
 _warned_slots = False
-_probe_result = None  # None=untried, True=kernels lower, False=disabled
 
 
 def mode() -> str:
@@ -103,65 +96,23 @@ def _round_up(a: int, b: int) -> int:
 
 
 def _compiler_params(semantics):
-    if not _HAS_PLTPU:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    return cls(dimension_semantics=tuple(semantics)) if cls else None
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
 
 def _use_pallas_lowp() -> bool:
     force = os.environ.get("PADDLE_TPU_LOWP_FORCE", "")
     if force == "pallas":
-        if not _HAS_PLTPU:
-            global _warned_no_pltpu
-            if not _warned_no_pltpu:
-                _warned_no_pltpu = True
-                import warnings
-
-                warnings.warn("pallas TPU backend unavailable; lowp "
-                              "matmuls use the lax path")
-            return False
         return True
     if force == "lax":
         return False
     from ..framework.flags import flag
 
-    if not flag("FLAGS_use_pallas"):
-        return False
-    if not (_HAS_PLTPU and jax.default_backend() == "tpu"):
-        return False
-    return _probe()
+    return flag("FLAGS_use_pallas") and jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
     return (os.environ.get("PADDLE_TPU_LOWP_FORCE", "") == "pallas"
             and jax.default_backend() != "tpu")
-
-
-def _probe() -> bool:
-    """One tiny scaled matmul per qdtype through the kernels on first
-    on-TPU use; a Mosaic lowering failure disables the pallas path for
-    the session (mirrors quant_ops._probe)."""
-    global _probe_result
-    if _probe_result is None:
-        try:
-            a = jnp.zeros((8, 128), jnp.float32)
-            b = jnp.zeros((128, 128), jnp.float32)
-            s = jnp.ones((), jnp.float32)
-            jax.block_until_ready(_smm_pallas(a, b, s, s, "int8"))
-            jax.block_until_ready(_smm_pallas(a, b, s, s, "fp8"))
-            q = jnp.zeros((128, 128), jnp.int8)
-            jax.block_until_ready(_w8a8_pallas(a, q, s, s))
-            _probe_result = True
-        except Exception as e:  # pragma: no cover - TPU only
-            _probe_result = False
-            import warnings
-
-            warnings.warn(
-                "pallas lowp matmul failed to lower; using the lax "
-                f"path for this session ({type(e).__name__}: {e})")
-    return _probe_result
 
 
 # ---------------------------------------------------------------------------

@@ -20,10 +20,9 @@ Three primitives cover the Megatron block:
 - ``matmul_reducescatter``  row-parallel, sequence-parallel output
                             (x·W followed by reduce-scatter of seq)
 
-All three run SPMD-manual inside `jax.shard_map` (the compat shim in
-paddle_tpu/__init__.py covers old jax) and are exact up to partial-sum
-reassociation: the ring accumulates the mp partial products in ring
-order rather than the single fused reduction's order, so parity vs the
+All three run SPMD-manual inside `jax.shard_map` and are exact up to
+partial-sum reassociation: the ring accumulates the mp partial products
+in ring order rather than the single fused reduction's order, so parity vs the
 GSPMD path is bitwise for the gather phase and ~1 ulp for the reduce
 phases (tests use rtol 1e-6 on fp32).
 
@@ -99,8 +98,8 @@ def supported(mesh):
     """Ring decomposition applies on pure dp x mp meshes with mp > 1.
 
     Any other nontrivial axis (pp, sharding, sep) means the step is
-    already inside — or about to enter — another manual region the ring
-    shard_map can't nest under old jax, so the GSPMD path stays.
+    already inside — or about to enter — another manual region, and
+    the ring shard_map does not nest there, so the GSPMD path stays.
     """
     if mesh is None:
         return False
@@ -149,14 +148,9 @@ def current():
 
 
 def _inside_manual_region():
-    """True when tracing already runs under a shard_map's named axes —
-    the ring shard_map must not nest there (old-jax compat is
-    fully-manual only)."""
-    try:
-        from jax._src import core as _core
-        return bool(_core.get_axis_env().axis_sizes)
-    except (AttributeError, ImportError):
-        return False
+    """True when tracing already runs under a shard_map's manual axes —
+    the ring shard_map must not nest there."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 # -- ring primitives ---------------------------------------------------------

@@ -24,13 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.op_registry import register_op
 
@@ -122,9 +116,9 @@ def fake_quantize_dequantize_moving_average_abs_max(
 #                               contraction, and the int8 operand is
 #                               what rides HBM.
 #
-# Execution paths gated exactly like fused_conv / fused_loss:
-#   * Pallas TPU kernel when FLAGS_use_pallas and backend==tpu (first
-#     use probes a tiny call, permanent lax fallback on Mosaic reject).
+# Execution paths, selected by platform only (as in fused_loss):
+#   * Pallas TPU kernel when FLAGS_use_pallas and backend==tpu; a
+#     lowering Mosaic refuses raises — no fallback on the chip.
 #   * The same kernel in interpreter mode when
 #     PADDLE_TPU_QUANT_FORCE=pallas off-TPU, so CPU tier-1 certifies
 #     the exact kernel math.
@@ -138,9 +132,6 @@ _DQ_BLOCK_N = 512
 # incremented whenever the pallas dequant-matmul is traced (not the lax
 # fallback) — tests assert the forced path really hits the kernel
 _TRACE_COUNT = 0
-
-_warned_no_pltpu = False
-_probe_result = None  # None=untried, True=kernel lowers, False=disabled
 
 
 def _mm(a, b, ca: int, cb: int):
@@ -160,61 +151,23 @@ def _round_up(a: int, b: int) -> int:
 
 
 def _compiler_params(semantics):
-    if not _HAS_PLTPU:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    return cls(dimension_semantics=tuple(semantics)) if cls else None
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
 
 def _use_pallas_quant() -> bool:
     force = os.environ.get("PADDLE_TPU_QUANT_FORCE", "")
     if force == "pallas":
-        if not _HAS_PLTPU:
-            global _warned_no_pltpu
-            if not _warned_no_pltpu:
-                _warned_no_pltpu = True
-                import warnings
-
-                warnings.warn("pallas TPU backend unavailable; "
-                              "dequant_matmul uses the lax path")
-            return False
         return True
     if force == "lax":
         return False
     from ..framework.flags import flag
 
-    if not flag("FLAGS_use_pallas"):
-        return False
-    if not (_HAS_PLTPU and jax.default_backend() == "tpu"):
-        return False
-    return _probe()
+    return flag("FLAGS_use_pallas") and jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
     return (os.environ.get("PADDLE_TPU_QUANT_FORCE", "") == "pallas"
             and jax.default_backend() != "tpu")
-
-
-def _probe() -> bool:
-    """One tiny dequant-matmul through the kernel on first on-TPU use; a
-    Mosaic lowering failure disables the pallas path for the session
-    instead of wedging every decode step (mirrors fused_conv._probe)."""
-    global _probe_result
-    if _probe_result is None:
-        try:
-            x = jnp.zeros((8, 128), jnp.float32)
-            q = jnp.zeros((32, 128), jnp.int8)
-            s = jnp.ones((32,), jnp.float32)
-            jax.block_until_ready(_dq_mm_pallas(x, q, s))
-            _probe_result = True
-        except Exception as e:  # pragma: no cover - TPU only
-            import warnings
-
-            warnings.warn(f"pallas dequant_matmul disabled (probe "
-                          f"failed: {e}); using the lax path")
-            _probe_result = False
-    return _probe_result
 
 
 def dequant_int8(q, scale):
@@ -249,7 +202,7 @@ def _dq_mm_pallas(x2, q, scale):
     qp = jnp.zeros((np_, kp), q.dtype).at[:n, :k].set(q)
     sp = jnp.zeros((np_, 8), jnp.float32).at[:n, :].set(
         jnp.broadcast_to(scale[:, None], (n, 8)))
-    vmem = pltpu.VMEM  # call sites gate on _HAS_PLTPU
+    vmem = pltpu.VMEM
     bspec = lambda shape, imap: pl.BlockSpec(  # noqa: E731
         shape, imap, memory_space=vmem)
     out = pl.pallas_call(

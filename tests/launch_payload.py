@@ -23,10 +23,7 @@ os.environ["XLA_FLAGS"] = " ".join(_flags)
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", _ndev)
-except AttributeError:  # older jax: the XLA_FLAGS pin above applies
-    pass
+jax.config.update("jax_num_cpu_devices", _ndev)
 
 from paddle_tpu.distributed.parallel import init_parallel_env  # noqa: E402
 

@@ -20,10 +20,6 @@ from paddle_tpu.distributed import fleet
 from paddle_tpu.distributed.topology import set_hybrid_communicate_group
 from paddle_tpu.engine import Engine
 
-_OLD_JAX_SHARD_MAP = getattr(jax.shard_map, "__paddle_tpu_compat__",
-                            False) if hasattr(jax, "shard_map") else True
-
-
 
 @pytest.fixture
 def hybrid_env():
@@ -194,8 +190,6 @@ def test_pipeline_loss_matches_sequential(hybrid_env):
     np.testing.assert_allclose(l_pp, l_seq, rtol=1e-3)
 
 
-@pytest.mark.skipif(_OLD_JAX_SHARD_MAP, reason=
-    "partial-manual shard_map (pp manual + dp/mp auto) needs newer jax")
 def test_hybrid_4d_matches_single_device(hybrid_env):
     from paddle_tpu.distributed.hybrid import make_gpt_hybrid_engine
 

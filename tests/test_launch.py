@@ -159,15 +159,6 @@ def test_multiprocess_compiled_hybrid_step(tmp_path):
     np.testing.assert_allclose(got, ref, rtol=1e-4)
 
 
-import jax  # noqa: E402
-import pytest  # noqa: E402
-import paddle_tpu  # noqa: F401,E402  (installs the old-jax shard_map shim)
-
-_OLD_JAX_SHARD_MAP = getattr(jax.shard_map, "__paddle_tpu_compat__", False)
-
-
-@pytest.mark.skipif(_OLD_JAX_SHARD_MAP, reason=
-    "partial-manual shard_map (pp manual + dp auto) needs newer jax")
 def test_multiprocess_pipeline_step(tmp_path):
     """VERDICT r4 item 6: the pipeline ring's ppermute must cross a REAL
     process boundary (pp axis spanning 2 launched processes) and still

@@ -17,11 +17,6 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
 import __graft_entry__ as graft  # noqa: E402
 
-import jax  # noqa: E402
-import paddle_tpu  # noqa: E402,F401 — installs the old-jax shard_map shim
-
-_OLD_JAX_SHARD_MAP = getattr(jax.shard_map, "__paddle_tpu_compat__", False)
-
 
 @pytest.fixture(scope="module")
 def baseline():
@@ -38,18 +33,6 @@ def test_factorization_matches_single_device(
 
     if jax.device_count() < dp * mp * pp * sharding:
         pytest.skip(f"needs {dp * mp * pp * sharding} devices")
-    if _OLD_JAX_SHARD_MAP and pp > 1 and dp * mp * sharding > 1:
-        pytest.skip("partial-manual shard_map (pp manual + auto axes) "
-                    "needs newer jax")
-    if _OLD_JAX_SHARD_MAP and name == "pp2.hetero":
-        # old shard_map's check_rep=False transpose mis-specs the scalar
-        # output ring's cotangent, and its check_rep=True path lacks the
-        # scan rewrite — the hetero pipeline's grad needs newer jax
-        pytest.skip("hetero-pipeline grad under shard_map needs newer jax")
-    if _OLD_JAX_SHARD_MAP:
-        # older XLA CPU reassociates the dp all-reduce differently;
-        # observed drift is ~3e-4, still far under the update magnitude
-        rtol = max(rtol, 1e-3)
     ref, master = baseline
     got = graft.run_sweep_config(name, dp, mp, pp, sharding, zero, off,
                                  master, seq_parallel=sp)
@@ -97,10 +80,6 @@ def test_offload_config_lands_in_host_memory(baseline):
         set_hybrid_communicate_group(None)
 
 
-@pytest.mark.skipif(
-    _OLD_JAX_SHARD_MAP,
-    reason="dp2.pp4 is partial-manual shard_map (pp manual + dp auto); "
-           "needs newer jax")
 def test_tied_embedding_weight_matches_single_device(baseline):
     """Weight tying across pp (VERDICT r3 item 5): the GPT sweep model
     ties lm-head logits to the embedding weight, so the embedding

@@ -36,9 +36,6 @@ import paddle_tpu as paddle  # noqa: E402 — installs the shard_map shim
 from paddle_tpu import observe  # noqa: E402
 from paddle_tpu.ops import overlap as ovl  # noqa: E402
 
-_OLD_JAX_SHARD_MAP = getattr(jax.shard_map, "__paddle_tpu_compat__", False)
-
-
 @pytest.fixture(scope="module")
 def baseline():
     losses, master = graft.baseline_losses()
