@@ -518,18 +518,18 @@ def make_train_step(layer, loss_fn, optimizer, *, grad_clip=None,
             new_buffers[BAD_STEPS_KEY] = jnp.where(shrink, 0, bad_next)
         return loss, new_params, new_buffers, new_opt
 
-    if mesh is None:
-        step_fn = _step_impl
-    else:
+    # the function's name is the program's: `jit_train_step` on the
+    # capture's `XLA Modules` line, `PjitFunction(train_step)` on the host's
+    def train_step(params, buffers, opt_state, batch, lr, key):
+        if mesh is None:
+            return _step_impl(params, buffers, opt_state, batch, lr, key)
         # meshed step: GSPMD-partitioned program — attention runs under
         # a shard_map over the batch/head axes so the Mosaic kernel
         # runs per-shard (fused_ops.gspmd_tracing)
-        def step_fn(params, buffers, opt_state, batch, lr, key):
-            from .ops.fused_ops import gspmd_tracing
+        from .ops.fused_ops import gspmd_tracing
 
-            with gspmd_tracing(mesh):
-                return _step_impl(params, buffers, opt_state, batch,
-                                  lr, key)
+        with gspmd_tracing(mesh):
+            return _step_impl(params, buffers, opt_state, batch, lr, key)
 
     in_shardings = None
     out_shardings = None
@@ -558,14 +558,14 @@ def make_train_step(layer, loss_fn, optimizer, *, grad_clip=None,
         out_shardings = (repl, p_sh, buf_sh, o_sh)
     donate_argnums = (0, 1, 2) if donate else ()
     if mesh is not None:
-        jitted = jax.jit(step_fn, donate_argnums=donate_argnums,
+        jitted = jax.jit(train_step, donate_argnums=donate_argnums,
                          in_shardings=in_shardings,
                          out_shardings=out_shardings)
     else:
-        jitted = jax.jit(step_fn, donate_argnums=donate_argnums)
+        jitted = jax.jit(train_step, donate_argnums=donate_argnums)
     # the un-jitted step is re-usable inside larger traced loops (bench
     # scans N steps in one program to amortise dispatch latency)
-    jitted._raw_step_fn = step_fn
+    jitted._raw_step_fn = train_step
     # exposed so Engine can pre-place live state into these shardings
     # (offload moves opt state to host memory; jit requires the arg's
     # memory kind to already match)

@@ -23,6 +23,7 @@ import traceback
 
 import numpy as np
 
+from .. import observe
 from ..core.tensor import Tensor
 from ..framework import random as _random
 
@@ -358,17 +359,20 @@ class _MultiprocessIter:
         base_seed = (int(_random.default_generator.initial_seed())
                      * 1000003 + epoch * 7919) & 0x7FFFFFFF
         collate = loader._worker_collate_fn
-        for w in range(self.num_workers):
-            iq = ctx.Queue()
-            p = ctx.Process(
-                target=_worker_loop,
-                args=(loader.dataset, iq, self.result_queue, collate,
-                      loader.worker_init_fn, w, self.num_workers,
-                      base_seed),
-                daemon=True)
-            p.start()
-            self.index_queues.append(iq)
-            self.workers.append(p)
+        # spans close in this (the parent) process only, and hold no
+        # lock while open: nothing is held across the fork
+        with observe.span("input.spawn", cat="input"):
+            for w in range(self.num_workers):
+                iq = ctx.Queue()
+                p = ctx.Process(
+                    target=_worker_loop,
+                    args=(loader.dataset, iq, self.result_queue, collate,
+                          loader.worker_init_fn, w, self.num_workers,
+                          base_seed),
+                    daemon=True)
+                p.start()
+                self.index_queues.append(iq)
+                self.workers.append(p)
         self._next_send = 0
         self._next_recv = 0
         self._reorder: dict[int, object] = {}
@@ -398,6 +402,21 @@ class _MultiprocessIter:
         if self._next_recv >= self._next_send and self._exhausted:
             self._shutdown()
             raise StopIteration
+        if self._next_recv not in self._reorder:
+            with observe.span("input.wait", cat="input"):
+                self._take_next()
+        status, data = self._reorder.pop(self._next_recv)
+        self._next_recv += 1
+        self._dispatch_one()
+        if status == "err":
+            self._shutdown()
+            raise RuntimeError(
+                f"DataLoader worker raised {data.type_name}:\n{data.tb}")
+        return _to_tensor_tree(data)
+
+    def _take_next(self):
+        """Block on the workers' queue until the next batch in order
+        has arrived."""
         waited = 0.0
         while self._next_recv not in self._reorder:
             try:
@@ -416,14 +435,6 @@ class _MultiprocessIter:
                         f"DataLoader timed out after {self.timeout}s")
                 continue  # timeout unset (block indefinitely) or not yet
             self._reorder[batch_id] = payload
-        status, data = self._reorder.pop(self._next_recv)
-        self._next_recv += 1
-        self._dispatch_one()
-        if status == "err":
-            self._shutdown()
-            raise RuntimeError(
-                f"DataLoader worker raised {data.type_name}:\n{data.tb}")
-        return _to_tensor_tree(data)
 
     def _shutdown(self):
         if self._shutdown_done:

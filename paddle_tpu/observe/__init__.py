@@ -2,8 +2,8 @@
 
 Four coupled pieces, one import:
 
-* `timeline` / `phase(name)` — nested step-phase spans with bounded
-  aggregates, plus `attribute(logdir)` device-time bucketing
+* `timeline` / `phase(name)` / `span(name)` — nested spans with
+  bounded aggregates, plus `attribute(logdir)` device-time bucketing
   (matmul/attention/collective/elementwise/other).
 * `retrace` — global compile-event registry; `no_retrace()` raises on
   any unexpected recompilation, `suppress()` mutes deliberate ones.
@@ -15,7 +15,7 @@ Four coupled pieces, one import:
 
 from .timeline import (BUCKETS, StepTimeline, attribute, attribute_rows,  # noqa: F401
                        classify_op, overlap_report, overlap_stats, phase,
-                       timeline)
+                       span, timeline)
 from .retrace import (RetraceError, annotate, compile_events, no_retrace,  # noqa: F401
                       record_compile, signature_of, suppress)
 from . import retrace  # noqa: F401
@@ -26,7 +26,7 @@ from .export import dump, goodput, prometheus_text, snapshot  # noqa: F401
 
 __all__ = [
     "BUCKETS", "StepTimeline", "attribute", "attribute_rows", "classify_op",
-    "overlap_report", "overlap_stats", "phase", "timeline",
+    "overlap_report", "overlap_stats", "phase", "span", "timeline",
     "RetraceError", "annotate", "compile_events", "no_retrace",
     "record_compile", "signature_of", "suppress", "retrace",
     "FlightRecorder", "flight", "flight_guard", "install_signal_handler",

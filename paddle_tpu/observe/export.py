@@ -36,7 +36,13 @@ _GOODPUT_CATS = {
     "host-prep": "host",
     "h2d": "host",
     "sample": "host",
+    "admit": "host",
+    "commit": "host",
+    "input.wait": "host",
+    "input.spawn": "host",
     "anomaly-readback": "host",
+    # a serving loop with no live slot, parked on its queue
+    "loop.idle": "idle",
     # gang supervisor: teardown + backoff + respawn after a rank died
     # or stalled — wall time lost to the coordinated restart
     "gang-restart": "restart",
@@ -44,6 +50,10 @@ _GOODPUT_CATS = {
 # background writer time overlaps the step thread: report it, but keep
 # it out of the goodput denominator
 _OVERLAPPED = {"checkpoint-write-async"}
+# spans that lie inside (dispatch, readback: the two halves of serving's
+# device-step) or round (serving.loop) phases counted above: counting
+# them too would count their seconds twice
+_NESTED = {"serving.loop", "dispatch", "readback"}
 
 
 def goodput(aggregates=None):
@@ -51,9 +61,12 @@ def goodput(aggregates=None):
     if aggregates is None:
         aggregates = _timeline.aggregates()
     cats = {"productive": 0.0, "compile": 0.0, "checkpoint": 0.0,
-            "restore": 0.0, "restart": 0.0, "host": 0.0, "other": 0.0}
+            "restore": 0.0, "restart": 0.0, "host": 0.0, "idle": 0.0,
+            "other": 0.0}
     overlapped = 0.0
     for name, agg in aggregates.items():
+        if name in _NESTED:
+            continue
         if name in _OVERLAPPED:
             overlapped += agg["total_s"]
             continue

@@ -5,17 +5,38 @@ The runtime's engines wrap each stage of a step in a named phase::
     host-prep            batch normalization, fault points, key/lr
     h2d                  host->device batch placement (+ offload moves)
     compile              a step call that traces/compiles a new program
-    device-step          the compiled step dispatch (training + decode)
+    device-step          training: the compiled step's dispatch (the
+                         enqueue; closing it would need a sync).
+                         serving: dispatch -> logits on the host, the
+                         interval the `decode`/`prefill` series get
+                         (folded in with `add`, it has no span of its own)
     anomaly-readback     the guard's host sync at step boundaries
-    sample               serving host-side token sampling
     checkpoint-snapshot  device->host state copy on the step thread
     checkpoint-write     synchronous checkpoint serialization + commit
     checkpoint-write-async  the same, on the background writer thread
     checkpoint-restore   checkpoint load/verify
 
-Each `phase(...)` context both emits a `profiler.RecordEvent` span (so
-phases land in the chrome trace and XProf annotations) and folds the
-duration into an O(1) per-phase aggregate here — the aggregate is what
+and the serving loop's thread spans every part of an iteration::
+
+    serving.loop         one working iteration: admit + step
+    loop.idle            the wait for a request when no slot is live
+    admit                queue -> slots, prefix match, block staging
+    sample               host-side token sampling + slot bookkeeping
+    draft                the speculative draft phase
+    dispatch             staging the step's host arrays + the jit call
+    readback             the wait for the logits (`np.asarray`)
+    commit               the post-step slot loop + the metrics calls
+
+and the input pipeline's consumer (parent process only)::
+
+    input.spawn          starting one epoch's fork workers
+    input.wait           the blocking take from the worker queue
+
+A `phase(name)` context emits the `profiler.RecordEvent` span
+`step.<name>`, a `span(name)` context the span `<name>` as it stands;
+both land in the chrome trace and, being `TraceAnnotation`s, in a
+profiler capture on the device trace's clock. Both fold the duration
+into an O(1) aggregate under `name` here — the aggregate is what
 `goodput()` and the Prometheus export read, so the timeline stays
 bounded no matter how long the run is.
 
@@ -27,14 +48,37 @@ into matmul / attention / collective / elementwise / other buckets —
 
 from __future__ import annotations
 
-import contextlib
 import re
 import threading
 import time
 
-__all__ = ["StepTimeline", "timeline", "phase", "BUCKETS", "classify_op",
-           "attribute", "attribute_rows", "overlap_stats",
+__all__ = ["StepTimeline", "timeline", "phase", "span", "BUCKETS",
+           "classify_op", "attribute", "attribute_rows", "overlap_stats",
            "overlap_report"]
+
+
+class _Timed:
+    """One `RecordEvent` span whose duration is folded into a timeline
+    aggregate on exit."""
+
+    __slots__ = ("_timeline", "_name", "_event", "_t0")
+
+    def __init__(self, timeline, name, span, cat):
+        from .. import profiler
+
+        self._timeline = timeline
+        self._name = name
+        self._event = profiler.RecordEvent(span, cat=cat)
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._event.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._event.__exit__(*exc)
+        self._timeline.add(self._name, time.perf_counter() - self._t0)
+        return False
 
 
 class StepTimeline:
@@ -44,21 +88,15 @@ class StepTimeline:
         self._lock = threading.Lock()
         self._agg: dict = {}  # name -> [calls, total_s, max_s]
 
-    @contextlib.contextmanager
     def phase(self, name, cat="phase"):
-        from .. import profiler
+        """Span `step.<name>`, aggregate `name`."""
+        return _Timed(self, name, f"step.{name}", cat)
 
-        t0 = time.perf_counter()
-        try:
-            with profiler.RecordEvent(f"step.{name}", cat=cat):
-                yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                c = self._agg.setdefault(name, [0, 0.0, 0.0])
-                c[0] += 1
-                c[1] += dt
-                c[2] = max(c[2], dt)
+    def span(self, name, cat="phase"):
+        """Span and aggregate both `name`: for what is no stage of a
+        step (the serving loop's iteration, its idle wait, the input
+        pipeline's waits)."""
+        return _Timed(self, name, name, cat)
 
     def add(self, name, seconds):
         """Fold an externally-timed duration into a phase aggregate."""
@@ -90,6 +128,7 @@ class StepTimeline:
 #: process-global timeline every engine reports into
 timeline = StepTimeline()
 phase = timeline.phase
+span = timeline.span
 
 
 # ---------------------------------------------------------------------------

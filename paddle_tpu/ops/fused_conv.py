@@ -270,6 +270,7 @@ def _pallas_conv(xp, wk, plan, *, g=None, b=None, res=None,
         functools.partial(_conv_kernel, kk=(plan.kkh, plan.kkw), wo=wo_k,
                           act=act, fuse=g is not None,
                           has_res=res is not None, moments=moments),
+        name="fused_conv",
         grid=(n, o // ot), in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=_compiler_params(("parallel", "parallel")),

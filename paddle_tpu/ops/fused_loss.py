@@ -256,6 +256,7 @@ def _fwd_pallas(x, w, labels, cv):
         shape, imap, memory_space=vmem)
     loss, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, vocab=v, last_voff=(nv - 1) * cv),
+        name="lm_loss_fwd",
         grid=(nr, nv),
         in_specs=[
             bspec((1, 8, 128), lambda i, j: (j, 0, 0)),
@@ -294,6 +295,7 @@ def _bwd_pallas(x, w, labels, lse, g, cv):
     dx = pl.pallas_call(
         functools.partial(_bwd_dx_kernel, vocab=v,
                           last_voff=(nv - 1) * cv),
+        name="lm_loss_dx",
         grid=(nr, nv),
         in_specs=[
             bspec((1, 8, 128), lambda i, j: (j, 0, 0)),
@@ -314,6 +316,7 @@ def _bwd_pallas(x, w, labels, lse, g, cv):
     dw = pl.pallas_call(
         functools.partial(_bwd_dw_kernel, vocab=v,
                           last_roff=(nr - 1) * bn),
+        name="lm_loss_dw",
         grid=(nv, nr),
         in_specs=[
             bspec((1, 8, 128), lambda a, b: (a, 0, 0)),

@@ -269,6 +269,7 @@ def _flash_fwd_pallas(q, k, v, seed, scale, causal, dropout_p):
     seed_arr = _seed_input(seed)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pos_spec,
@@ -425,6 +426,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, seed, scale, causal,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           kv_len=sk, last_k_off=(nk - 1) * bk,
                           causal_off=sk - sq, dropout_p=dropout_p),
+        name="flash_dq",
         grid=(bh, nq, nk),
         in_specs=[
             pos128(lambda i, j, t: (j, 0, 0)),
@@ -451,6 +453,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, seed, scale, causal,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           q_len=sq, last_q_off=(nq - 1) * bq,
                           causal_off=sk - sq, dropout_p=dropout_p),
+        name="flash_dkv",
         grid=(bh, nk, nq),
         in_specs=[
             pos128(lambda i, j, t: (j, 0, 0)),

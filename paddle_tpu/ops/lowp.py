@@ -205,10 +205,11 @@ def _smem11(s):
     return jnp.broadcast_to(jnp.asarray(s, jnp.float32), (1, 1))
 
 
-def _pallas_mm(kernel, a, b, sa, sb):
+def _pallas_mm(kernel, name, a, b, sa, sb):
     """Shared pad/grid/specs for the quantizing matmul kernels: a
     [m, k] float, b [k, n] float or int8, scalars in SMEM; zero padding
-    quantizes to zero so the padded contraction is exact."""
+    quantizes to zero so the padded contraction is exact. `name` is the
+    kernel's in a compiled program and a capture."""
     global _TRACE_COUNT
     _TRACE_COUNT += 1
     m, k = a.shape
@@ -223,6 +224,7 @@ def _pallas_mm(kernel, a, b, sa, sb):
     vmem = pltpu.VMEM
     out = pl.pallas_call(
         kernel,
+        name=name,
         grid=(mp // bm, np_ // bn),
         in_specs=[
             smem, smem,
@@ -242,11 +244,11 @@ def _pallas_mm(kernel, a, b, sa, sb):
 
 def _smm_pallas(a, b, sa, sb, qdtype):
     return _pallas_mm(functools.partial(_qmm_kernel, qdtype=qdtype),
-                      a, b, sa, sb)
+                      "lowp_scaled_matmul", a, b, sa, sb)
 
 
 def _w8a8_pallas(a, qb, sb, sa):
-    return _pallas_mm(_w8a8_kernel, a, qb, sa, sb)
+    return _pallas_mm(_w8a8_kernel, "w8a8_matmul", a, qb, sa, sb)
 
 
 # ---------------------------------------------------------------------------

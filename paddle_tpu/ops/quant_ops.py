@@ -207,6 +207,7 @@ def _dq_mm_pallas(x2, q, scale):
         shape, imap, memory_space=vmem)
     out = pl.pallas_call(
         _dq_kernel,
+        name="dequant_matmul",
         grid=(mp // bm, np_ // bn),
         in_specs=[bspec((bm, kp), lambda i, j: (i, 0)),
                   bspec((bn, kp), lambda i, j: (j, 0)),

@@ -27,7 +27,7 @@ import threading
 import time
 
 __all__ = [
-    "RecordEvent", "RecordMemEvent", "enable_op_profiling",
+    "RecordEvent", "record_span", "RecordMemEvent", "enable_op_profiling",
     "disable_op_profiling", "is_op_profiling_enabled", "reset", "events",
     "mem_events", "record_device_memory", "summary", "percentiles",
     "export_chrome_tracing", "profile", "start_trace", "stop_trace",
@@ -88,6 +88,21 @@ class RecordEvent:
                 "depth": _tls.depth,
             })
         return False
+
+
+def record_span(name, start_s, dur_s, cat="host", **fields):
+    """Put an interval known only after the fact straight into the
+    ring: a request's queue wait, prefill and decode cross many engine
+    steps and many other requests' lives, so they cannot be nested
+    `RecordEvent`s (and get no TraceAnnotation). `start_s` is on
+    `time.perf_counter()`'s clock, like every span's start. `fields`
+    ride along on the event; spans that belong together share an
+    `id`, and `export_chrome_tracing` pairs those up as async events."""
+    ev = {"name": name, "cat": cat, "ts": start_s * 1e6,
+          "dur": dur_s * 1e6, "tid": threading.get_ident(), "depth": 0}
+    ev.update(fields)
+    with _lock:
+        _events.append(ev)
 
 
 _mem_events: collections.deque = collections.deque(maxlen=_MAX_MEM_EVENTS)
@@ -257,18 +272,26 @@ def export_chrome_tracing(path):
     Spans are sorted by start time and carry their recorded nesting
     `depth` (spans land in `_events` at EXIT, so inner spans precede
     their parents in recording order — the sort restores enclosure
-    order so chrome stacks nested spans correctly). Memory events are
+    order so chrome stacks nested spans correctly). `record_span`
+    intervals that carry an `id` become async pairs. Memory events are
     emitted as counter (``ph:"C"``) rows so the measured
     bytes-in-use/peak series renders as a track under the spans."""
     pid = os.getpid()
-    trace_events = [
-        {
-            "name": e["name"], "cat": e["cat"], "ph": "X",
-            "ts": e["ts"], "dur": e["dur"], "pid": pid,
-            "tid": e["tid"], "args": {"depth": e.get("depth", 0)},
-        }
-        for e in sorted(events(), key=lambda e: (e["tid"], e["ts"]))
-    ]
+    trace_events = []
+    for e in sorted(events(), key=lambda e: (e["tid"], e["ts"])):
+        row = {"name": e["name"], "cat": e["cat"], "ts": e["ts"],
+               "pid": pid, "tid": e["tid"]}
+        if "id" in e:
+            # `record_span` intervals sharing an id (one request's
+            # life) overlap other ids' on the recording thread: chrome
+            # draws async begin/end pairs on a track per id
+            trace_events.append(dict(row, ph="b", id=e["id"]))
+            trace_events.append(dict(row, ph="e", id=e["id"],
+                                     ts=e["ts"] + e["dur"]))
+        else:
+            trace_events.append(dict(
+                row, ph="X", dur=e["dur"],
+                args={"depth": e.get("depth", 0)}))
     for m in mem_events():
         args = {"bytes_in_use": m["bytes"]}
         if "host_bytes_in_use" in m:

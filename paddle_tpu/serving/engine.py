@@ -98,6 +98,22 @@ Supervised (fleet-owned) engines additionally
 fire ``serving.replica_heartbeat`` every loop iteration and
 ``serving.replica_step`` before each decode step, both tagged with the
 replica name — the fleet chaos sites (see framework/faults.py).
+
+Measured from inside, always on. The loop's thread spans each part of
+an iteration (`observe.span` / `observe.phase`: a `RecordEvent`, hence
+on a profiler capture's clock, plus a timeline aggregate):
+``serving.loop`` round a working iteration, ``loop.idle`` round the
+wait when nothing is live or queued; inside an iteration ``step.admit``,
+``step.sample``, then ``serving.step`` round ``step.dispatch`` (host
+arrays staged, the jit call) and ``step.readback`` (the wait for the
+logits), then ``step.commit``. The dispatch -> read-back interval is
+the `decode` / `prefill` series' sample and the timeline's
+``device-step``. A request's own stamps (queueing.Request) are folded
+once, when it leaves its slot with an answer: into the series `ttft`,
+`prefill_req`, `itl`, and into the profiler ring as ``request.queue``,
+``request.prefill`` (with `steps` and `prefix_hit_tokens`) and
+``request.decode`` (with `token_s`, the token stamps), all three under
+the request's `id`. A request that fails folds nothing.
 """
 
 from __future__ import annotations
@@ -121,6 +137,11 @@ from .queueing import (
 )
 
 __all__ = ["SlotEngine", "speculative_accept"]
+
+# a Request is stamped on time.monotonic(), as its arrival and deadline
+# are; the profiler ring is on time.perf_counter() (the same clock on
+# Linux, a fixed offset apart elsewhere)
+_RING_CLOCK_OFFSET = time.perf_counter() - time.monotonic()
 
 
 def speculative_accept(p_list, q_list, proposals, rng):
@@ -435,8 +456,8 @@ class SlotEngine:
             out = out._value if isinstance(out, Tensor) else out
             return (out[:, 0, :] if squeeze else out).astype(jnp.float32)
 
-        def step_fn(values, tok, pos, nvalid, tables, ks, vs,
-                    act_scale=None, aid=None, la=None, lb=None):
+        def serving_step(values, tok, pos, nvalid, tables, ks, vs,
+                         act_scale=None, aid=None, la=None, lb=None):
             # trace-time only: the compile counter + retrace registry
             _count("decode")
             observe.record_compile(
@@ -499,7 +520,7 @@ class SlotEngine:
                 return lv, amax, out_ks, out_vs
             return lv, out_ks, out_vs
 
-        def cow_fn(ks, vs, src, dst):
+        def serving_cow(ks, vs, src, dst):
             from jax import lax
 
             _count("cow")
@@ -537,16 +558,16 @@ class SlotEngine:
                 # per-slot adapter ids + replicated A/B banks
                 step_in = step_in + (rep, rep, rep)
             self._decode = jax.jit(
-                step_fn,
+                serving_step,
                 in_shardings=step_in,
                 out_shardings=step_out)
             self._cow = jax.jit(
-                cow_fn,
+                serving_cow,
                 in_shardings=(pools, pools, rep, rep),
                 out_shardings=(pools, pools))
         else:
-            self._decode = jax.jit(step_fn)
-            self._cow = jax.jit(cow_fn)
+            self._decode = jax.jit(serving_step)
+            self._cow = jax.jit(serving_cow)
 
         # -- speculative draft trace (only when spec is on: a disabled
         # engine keeps compile counters {decode: 1, cow: 1} exactly) --
@@ -584,7 +605,7 @@ class SlotEngine:
                 * jnp.zeros((), dtype).nbytes)
             self._draft_chunk = self.spec_len + 1
 
-            def draft_fn(dvalues, tok, pos, nvalid, tables, ks, vs):
+            def serving_draft(dvalues, tok, pos, nvalid, tables, ks, vs):
                 _count("draft")
                 observe.record_compile(
                     "serving.draft",
@@ -607,7 +628,7 @@ class SlotEngine:
                 return (lv, [c[0] for c in new_caches],
                         [c[1] for c in new_caches])
 
-            self._draft = jax.jit(draft_fn)
+            self._draft = jax.jit(serving_draft)
 
     # -- introspection ------------------------------------------------------
 
@@ -969,9 +990,11 @@ class SlotEngine:
             self._pos[slot] = fill
             self._aid[slot] = int(req.gen.get("adapter_id", 0) or 0)
             self._slots[slot] = _Slot(req, ids, fill, blocks)
+            req.admitted = time.monotonic()
+            req.queue_wait = req.admitted - req.arrival
+            req.prefix_hit_tokens = fill
             self.metrics.inc("admitted")
-            self.metrics.observe_latency(
-                "queue", time.monotonic() - req.arrival)
+            self.metrics.observe_latency("queue", req.queue_wait)
 
     # -- KV migration (prefill->decode disaggregation, ISSUE 17) ------------
 
@@ -1241,23 +1264,46 @@ class SlotEngine:
         self._bt[idx, :] = NULL_BLOCK
         self._pos[idx] = 0
         self._aid[idx] = 0
-        tenant = slot.req.gen.get("tenant")
+        req = slot.req
+        tenant = req.gen.get("tenant")
+        req.finished = time.monotonic()
         if error is not None:
             self.metrics.inc("failed")
             if tenant:
                 self.metrics.tenant_inc(tenant, "failed")
-            slot.req._fail(error)
+            req._fail(error)
         else:
             self.metrics.inc("completed")
-            self.metrics.observe_latency(
-                "e2e", time.monotonic() - slot.req.arrival)
+            self.metrics.observe_latency("e2e", req.finished - req.arrival)
+            self._fold_request(req)
             if tenant:
                 self.metrics.tenant_inc(tenant, "completed")
                 self.metrics.tenant_inc(tenant, "tokens_out",
                                         slot.produced)
                 self.metrics.tenant_observe_latency(
-                    tenant, time.monotonic() - slot.req.arrival)
-            slot.req._complete(np.asarray(slot.tokens, np.int32))
+                    tenant, req.finished - req.arrival)
+            req._complete(np.asarray(slot.tokens, np.int32))
+
+    def _fold_request(self, req):
+        """A finished request's stamps, once: into the series `ttft`,
+        `prefill_req` and `itl`, and into the profiler ring as the
+        request's three spans, which tile arrival -> finished."""
+        took = req.timings()
+        self.metrics.observe_latency("ttft", took["first_token_s"])
+        self.metrics.observe_latency("prefill_req", took["prefill_s"])
+        self.metrics.observe_latencies("itl", took["token_gaps_s"])
+        ring = _RING_CLOCK_OFFSET
+        first = req.token_times[0]
+        profiler.record_span(
+            "request.queue", req.arrival + ring, req.queue_wait,
+            cat="request", id=req.id, queue_s=req.queue_wait)
+        profiler.record_span(
+            "request.prefill", req.admitted + ring, took["prefill_s"],
+            cat="request", id=req.id, steps=req.prefill_steps,
+            prefix_hit_tokens=req.prefix_hit_tokens)
+        profiler.record_span(
+            "request.decode", first + ring, req.finished - first,
+            cat="request", id=req.id, token_s=req.token_times)
 
     def _fail_all_active(self, error):
         for i, slot in enumerate(self._slots):
@@ -1311,7 +1357,7 @@ class SlotEngine:
                      if self._slots[i].state == "prefill")
         t0 = time.monotonic()
         with profiler.RecordEvent("serving.step", cat="serving"):
-            with observe.phase("device-step", cat="serving"):
+            with observe.phase("dispatch", cat="serving"):
                 if self.w8a8:
                     logits, amax, self._ks, self._vs = \
                         self._dispatch_decode(tok, self._pos, nvalid)
@@ -1319,38 +1365,49 @@ class SlotEngine:
                 else:
                     logits, self._ks, self._vs = \
                         self._dispatch_decode(tok, self._pos, nvalid)
-        logits = np.asarray(logits)
-        self._observe_step_latency(time.monotonic() - t0,
-                                   prefill_tokens, len(live) - n_pref)
-        for i in live:
-            slot = self._slots[i]
-            self._pos[i] += slot.advance
-            if slot.state == "prefill":
-                slot.fill += slot.advance
-                if slot.fill >= slot.prompt_len:
-                    slot.state = "decode"
+            with observe.phase("readback", cat="serving"):
+                logits = np.asarray(logits)
+        dt = time.monotonic() - t0
+        with observe.phase("commit", cat="serving"):
+            self._observe_step_latency(dt, prefill_tokens,
+                                       len(live) - n_pref)
+            for i in live:
+                slot = self._slots[i]
+                self._pos[i] += slot.advance
+                if slot.state == "prefill":
+                    slot.req.prefill_steps += 1
+                    slot.fill += slot.advance
+                    if slot.fill >= slot.prompt_len:
+                        slot.state = "decode"
+                        slot.next_logits = logits[i]
+                        self.metrics.inc("prefills")
+                else:
                     slot.next_logits = logits[i]
-                    self.metrics.inc("prefills")
-            else:
-                slot.next_logits = logits[i]
-        self.metrics.inc("steps")
-        if prefill_tokens:
-            self.metrics.inc("prefill_tokens", prefill_tokens)
-        self.metrics.observe_occupancy(len(live), self.max_slots)
-        self.metrics.observe_blocks(self._alloc.blocks_in_use,
-                                    self._alloc.usable)
+            self._count_step(len(live), prefill_tokens)
 
     def _observe_step_latency(self, dt, prefill_tokens, n_decoding):
-        """Attribute one device step to the phase-latency series: a step
-        staging prompt tokens is a 'prefill' sample, a step advancing at
-        least one decoding slot is a 'decode' sample (a mixed colocated
-        step is honestly both — decoding slots really did wait for the
-        chunk-wide prefill program). These feed the decode p99 /
-        prefill p50 columns the disaggregation bench compares."""
+        """Attribute one device step, dispatch to the logits on the
+        host, to the phase-latency series: a step staging prompt tokens
+        is a 'prefill' sample, a step advancing at least one decoding
+        slot is a 'decode' sample (a mixed colocated step is honestly
+        both — decoding slots really did wait for the chunk-wide
+        prefill program). These feed the decode p99 / prefill p50
+        columns the disaggregation bench compares. The same interval is
+        the timeline's `device-step`, the productive time of
+        `observe.goodput()`."""
+        observe.timeline.add("device-step", dt)
         if prefill_tokens:
             self.metrics.observe_latency("prefill", dt)
         if n_decoding:
             self.metrics.observe_latency("decode", dt)
+
+    def _count_step(self, n_live, prefill_tokens):
+        self.metrics.inc("steps")
+        if prefill_tokens:
+            self.metrics.inc("prefill_tokens", prefill_tokens)
+        self.metrics.observe_occupancy(n_live, self.max_slots)
+        self.metrics.observe_blocks(self._alloc.blocks_in_use,
+                                    self._alloc.usable)
 
     def _consume_slots(self, now, tok, nvalid, live):
         """Host-side half of a step: sample each decoding slot's pending
@@ -1385,6 +1442,7 @@ class SlotEngine:
             nxt = self._pick(slot)
             slot.tokens.append(nxt)
             slot.produced += 1
+            req.token_times.append(now)
             self.metrics.inc("tokens_out")
             gen = req.gen
             eos = gen.get("eos_token_id")
@@ -1451,7 +1509,7 @@ class SlotEngine:
                      if self._slots[i].state == "prefill")
         t0 = time.monotonic()
         with profiler.RecordEvent("serving.step", cat="serving"):
-            with observe.phase("device-step", cat="serving"):
+            with observe.phase("dispatch", cat="serving"):
                 if self.w8a8:
                     lv, sv, amax, self._ks, self._vs = \
                         self._dispatch_decode(tok, self._pos, nvalid)
@@ -1459,30 +1517,29 @@ class SlotEngine:
                 else:
                     lv, sv, self._ks, self._vs = \
                         self._dispatch_decode(tok, self._pos, nvalid)
-        lv = np.asarray(lv)
-        sv = np.asarray(sv)
-        self._observe_step_latency(time.monotonic() - t0,
-                                   prefill_tokens, len(live) - n_pref)
-        for i in live:
-            slot = self._slots[i]
-            if slot.state == "prefill":
-                self._pos[i] += slot.advance
-                slot.fill += slot.advance
-                self._advance_dfill(slot)
-                if slot.fill >= slot.prompt_len:
-                    slot.state = "decode"
-                    slot.next_logits = lv[i]
-                    self.metrics.inc("prefills")
-            else:
-                self._commit_spec(i, slot, lv[i], sv[i])
-        self.metrics.inc("steps")
-        if plan:
-            self.metrics.inc("spec_rounds")
-        if prefill_tokens:
-            self.metrics.inc("prefill_tokens", prefill_tokens)
-        self.metrics.observe_occupancy(len(live), self.max_slots)
-        self.metrics.observe_blocks(self._alloc.blocks_in_use,
-                                    self._alloc.usable)
+            with observe.phase("readback", cat="serving"):
+                lv = np.asarray(lv)
+                sv = np.asarray(sv)
+        done = time.monotonic()
+        with observe.phase("commit", cat="serving"):
+            self._observe_step_latency(done - t0, prefill_tokens,
+                                       len(live) - n_pref)
+            for i in live:
+                slot = self._slots[i]
+                if slot.state == "prefill":
+                    slot.req.prefill_steps += 1
+                    self._pos[i] += slot.advance
+                    slot.fill += slot.advance
+                    self._advance_dfill(slot)
+                    if slot.fill >= slot.prompt_len:
+                        slot.state = "decode"
+                        slot.next_logits = lv[i]
+                        self.metrics.inc("prefills")
+                else:
+                    self._commit_spec(i, slot, lv[i], sv[i], done)
+            if plan:
+                self.metrics.inc("spec_rounds")
+            self._count_step(len(live), prefill_tokens)
 
     def _consume_spec(self, now, tok, nvalid, live, plan):
         """Speculative twin of `_consume_slots`: same cancel / deadline
@@ -1524,6 +1581,7 @@ class SlotEngine:
                 nxt = self._pick(slot)
                 slot.tokens.append(nxt)
                 slot.produced += 1
+                req.token_times.append(now)
                 self.metrics.inc("tokens_out")
                 eos = gen.get("eos_token_id")
                 if (eos is not None and nxt == eos) or \
@@ -1618,8 +1676,9 @@ class SlotEngine:
         slot.drafted = []
         slot.qdists = []
 
-    def _commit_spec(self, i, slot, lv_i, sv_i):
-        """Host-side accept/commit for one slot after a verify step.
+    def _commit_spec(self, i, slot, lv_i, sv_i, now):
+        """Host-side accept/commit for one slot after a verify step;
+        every token it commits is stamped `now`, the read-back's end.
         Greedy: accept the longest prefix of proposals that match the
         verify argmaxes, then hand the first-mismatch logits row to the
         NEXT round's `_pick` — every emitted token is an argmax of the
@@ -1663,6 +1722,7 @@ class SlotEngine:
         for t in props[:a]:
             slot.tokens.append(int(t))
             slot.produced += 1
+            slot.req.token_times.append(now)
             self.metrics.inc("tokens_out")
             m += 1
             if (eos is not None and t == eos) or \
@@ -1677,6 +1737,7 @@ class SlotEngine:
         if resampled is not None:
             slot.tokens.append(int(resampled))
             slot.produced += 1
+            slot.req.token_times.append(now)
             self.metrics.inc("tokens_out")
             slot.next_logits = None
             slot.unfed = True
@@ -1724,20 +1785,29 @@ class SlotEngine:
                         self._abort_error or RequestCancelled(
                             "server aborted (non-drain shutdown)"))
                     return
-                self._admit()
-                if self.active == 0:
+                if self.active == 0 and self.queue.depth == 0:
                     if self.queue.drained():
                         return
-                    self.queue.wait_nonempty(0.02)
+                    with observe.span("loop.idle", cat="serving"):
+                        self.queue.wait_nonempty(0.02)
                     continue
-                try:
-                    if self.supervised:
-                        faults.fault_point("serving.replica_step",
-                                           tag=self.name)
-                    self._step()
-                except Exception as e:  # noqa: BLE001 — engine stays up
-                    self.metrics.inc("step_errors")
-                    self._fail_all_active(e)
+                with observe.span("serving.loop", cat="serving"):
+                    self._iterate()
+
+    def _iterate(self):
+        """One working iteration: join-at-step admission, then a step
+        if anything is live (everything queued may have expired)."""
+        with observe.phase("admit", cat="serving"):
+            self._admit()
+        if self.active == 0:
+            return
+        try:
+            if self.supervised:
+                faults.fault_point("serving.replica_step", tag=self.name)
+            self._step()
+        except Exception as e:  # noqa: BLE001 — engine stays up
+            self.metrics.inc("step_errors")
+            self._fail_all_active(e)
 
     def abandon(self, error):
         """Supervisor-side takeover of a dead/hung replica: stop the

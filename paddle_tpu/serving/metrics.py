@@ -2,10 +2,18 @@
 
 Ref parity: the reference's serving stack exports brpc/bvar counters
 (qps, latency quantiles, queue depth); here one registry aggregates the
-same signals host-side and exports them as JSON. Latency series are also
-recorded as `profiler.RecordEvent` spans by the engine/batcher, so the
-same numbers land in the chrome trace and `profiler.percentiles` agrees
-with `snapshot()`.
+same signals host-side and exports them as JSON.
+
+Latency series (seconds, each a bounded FIFO window): `queue` (arrival
+-> a slot), `prefill` / `decode` (one engine step, dispatch -> logits on
+the host, by what the step held), `e2e` (arrival -> whole answer), and
+from a request's own stamps, folded in once when it finishes: `ttft`
+(arrival -> first token), `prefill_req` (a slot -> first token), `itl`
+(every gap between consecutive tokens of one request). `snapshot()`
+and the Prometheus text give each one's count and p50/p95/p99/max;
+`latency_mark()` / `latency_since()` give a caller the samples of its
+own window. The series are not spans: what the engine also puts into
+the `profiler` ring is named in its own docstring.
 """
 
 from __future__ import annotations
@@ -71,6 +79,7 @@ class ServingMetrics:
         self._lock = threading.Lock()
         self._counters: dict = {}
         self._latency: dict = {}      # kind -> [seconds]
+        self._latency_seen: dict = {}  # kind -> samples ever observed
         self._mesh = None             # (spec, devices) when mesh-sharded
         self._role = None             # disagg role ('prefill'/'decode')
         self._occ_sum = 0.0
@@ -183,11 +192,34 @@ class ServingMetrics:
             return self._counters.get(name, 0)
 
     def observe_latency(self, kind, seconds):
+        self.observe_latencies(kind, (seconds,))
+
+    def observe_latencies(self, kind, samples):
+        """Several samples of one series under one lock (a finished
+        request's token gaps)."""
+        samples = [float(s) for s in samples]
         with self._lock:
             series = self._latency.setdefault(kind, [])
-            series.append(float(seconds))
+            series.extend(samples)
+            self._latency_seen[kind] = \
+                self._latency_seen.get(kind, 0) + len(samples)
             if len(series) > _MAX_SAMPLES:
                 del series[:len(series) - _MAX_SAMPLES]
+
+    def latency_mark(self):
+        """A mark for `latency_since`: how many samples each series has
+        seen so far (a count, so it survives the FIFO trim)."""
+        with self._lock:
+            return dict(self._latency_seen)
+
+    def latency_since(self, mark, kind):
+        """The samples of series `kind` observed after `mark` was
+        taken, oldest first; those the FIFO window has already dropped
+        are gone."""
+        with self._lock:
+            n = self._latency_seen.get(kind, 0) - mark.get(kind, 0)
+            series = self._latency.get(kind, ())
+            return list(series[max(len(series) - n, 0):]) if n > 0 else []
 
     def observe_occupancy(self, active, capacity):
         """One decode-step sample of slot utilisation (active/capacity)."""

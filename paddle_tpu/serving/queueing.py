@@ -30,6 +30,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
+from array import array
 from collections import deque
 
 from ..framework import faults, monitor
@@ -162,6 +163,16 @@ class Request:
     late completion and deliver exactly one outcome to the client.
     Done-callbacks registered via `add_done_callback` fire exactly once,
     on the resolving thread, after the event is set.
+
+    The decode engine stamps the request's life where it happens, on
+    `arrival`'s clock: `admitted` (a slot was taken), one entry of
+    `token_times` per committed token (answers come whole, so a stamp
+    is when a streaming front would have had the token; tokens one
+    speculative step commits share one), `finished` (the slot was
+    freed). `queue_wait` is the very sample of the metrics series
+    `queue`; `prefix_hit_tokens` counts the prompt tokens the prefix
+    cache served at admission and `prefill_steps` the engine steps from
+    admission to the first token. `timings()` reads them back.
     """
 
     def __init__(self, payload, *, timeout=None, priority=0, **gen):
@@ -171,6 +182,12 @@ class Request:
         self.priority = priority
         self.arrival = time.monotonic()
         self.deadline = self.arrival + timeout if timeout else None
+        self.admitted = None
+        self.finished = None
+        self.token_times = array("d")
+        self.queue_wait = None
+        self.prefix_hit_tokens = 0
+        self.prefill_steps = 0
         self._event = threading.Event()
         self._value = None
         self._error = None
@@ -232,6 +249,25 @@ class Request:
             raise TimeoutError(
                 f"request {self.id} not done within {timeout}s")
         return self._error
+
+    def timings(self):
+        """Where this request's time went, in seconds, from the
+        engine's stamps: the wait for a slot, prefill (a slot -> first
+        token), time to the first token from arrival, every gap between
+        consecutive tokens, and the whole; None until the request has
+        left its slot with at least one token."""
+        stamps = self.token_times
+        if self.finished is None or not stamps:
+            return None
+        return {
+            "queue_s": self.queue_wait,
+            "prefill_s": stamps[0] - self.admitted,
+            "first_token_s": stamps[0] - self.arrival,
+            "token_gaps_s": [b - a for a, b in zip(stamps, stamps[1:])],
+            "total_s": self.finished - self.arrival,
+            "prefix_hit_tokens": self.prefix_hit_tokens,
+            "prefill_steps": self.prefill_steps,
+        }
 
     # -- engine side --------------------------------------------------------
 
