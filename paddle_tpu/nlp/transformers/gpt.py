@@ -125,10 +125,11 @@ class GPTAttention(nn.Layer):
 
         Paged mode (the serving block-paged pool): `pos` is a tuple
         ``(pos_vec, block_tables)`` and k/v caches are physical block
-        pools ``[num_blocks, nh, block_size, hd]``. Row b's logical
+        pools ``[num_blocks, block_size, nh, hd]`` (token-major: the
+        order the step writes and reads them in). Row b's logical
         position t lives at physical row ``(tables[b, t // bs],
         t % bs)``; new KV scatters through the table and the logical
-        ``[b, nh, max_seq, hd]`` view is gathered back for the scores.
+        ``[b, max_seq, nh, hd]`` view is gathered back for the scores.
         Padding rows (positions past the sequence / chunk) are routed
         to reserved block 0, so the step shape never depends on how
         many rows are real — the compile-once property survives
@@ -193,13 +194,22 @@ class GPTAttention(nn.Layer):
         this one dispatch, and a rejected suffix's pool rows are just
         more garbage-above-the-frontier — masked out by ``key_idx <=
         t_idx`` now, overwritten by the next round's staging before the
-        coverage frontier reaches them."""
+        coverage frontier reaches them.
+
+        The pool is ``[num_blocks, block_size, nh, hd]`` so that the
+        scatter indexes its two LEADING axes and the gathered view
+        feeds the contraction by reshape alone: a step that donates the
+        pools then updates them in place. A scatter over axes that are
+        not adjacent makes the TPU compiler relayout the whole pool
+        round it, donated or not, and a view in any other order costs a
+        transposing copy of every slot's whole context
+        (tests/test_v5e_compile.py holds the compiled step to this)."""
         import jax
         import jax.numpy as jnp
 
         b, nh = qv.shape[0], qv.shape[1]
         s_new = qv.shape[2]
-        bs = k_pool.shape[2]
+        bs = k_pool.shape[1]
         mb = tables.shape[1]
         s_max = mb * bs
         hd = k_pool.shape[3]
@@ -211,23 +221,21 @@ class GPTAttention(nn.Layer):
         off = safe_t % bs
         # advanced-index scatter through the tables: value rows land at
         # (physical block, in-block offset) of their logical position
-        k_pool = k_pool.at[blk, :, off, :].set(
+        k_pool = k_pool.at[blk, off].set(
             jnp.swapaxes(kv, 1, 2).astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, :, off, :].set(
+        v_pool = v_pool.at[blk, off].set(
             jnp.swapaxes(vv, 1, 2).astype(v_pool.dtype))
-        # gather each row's logical [nh, s_max, hd] view for the scores
-        k_view = k_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            b, nh, s_max, hd)
-        v_view = v_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            b, nh, s_max, hd)
+        # gather each row's logical [s_max, nh, hd] view for the scores
+        k_view = k_pool[tables].reshape(b, s_max, nh, hd)
+        v_view = v_pool[tables].reshape(b, s_max, nh, hd)
         scale = 1.0 / (self.head_dim ** 0.5)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", qv.astype(jnp.float32),
+        scores = jnp.einsum("bhqd,bkhd->bhqk", qv.astype(jnp.float32),
                             k_view.astype(jnp.float32)) * scale
         key_idx = jnp.arange(s_max)
         mask = key_idx[None, None, :] <= t_idx[:, :, None]
         scores = jnp.where(mask[:, None], scores, -1e30)
         p = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhqk,bhkd->bhqd", p,
+        out = jnp.einsum("bhqk,bkhd->bhqd", p,
                          v_view.astype(jnp.float32)).astype(qv.dtype)
         return Tensor(out), (k_pool, v_pool, (pos + s_new, tables))
 

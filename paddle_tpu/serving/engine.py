@@ -5,11 +5,19 @@ mapped onto XLA's compile-per-shape reality, with vLLM-style paged KV
 allocation and SGLang-style prefix sharing:
 
 - ONE physical block pool per layer, shape
-  ``[num_blocks, nh, block_size, hd]``, plus a per-slot block table
+  ``[num_blocks, block_size, nh, hd]`` (token-major, the order the
+  step writes and reads it in), plus a per-slot block table
   ``[max_slots, blocks_per_slot]``. A request holds only the blocks its
   actual length needs, so pool HBM caps *total tokens in flight*, not
   ``max_slots * max_seq`` — short requests no longer pay for long ones
   and concurrency scales with the pool, not the worst case.
+- The pools are DONATED to every program that returns them (the step,
+  the CoW copy, the draft micro-step) and updated in place: the arrays
+  handed in are dead after the call and `_ks` / `_vs` are rebound to
+  its outputs under `_pool_lock`. Everything else that reads or
+  rebinds a pool runs on the loop's thread between steps, or takes
+  that lock (`export_prefix_blocks`). The counter `pool_inplace_steps`
+  counts the steps whose pools really went in place; it equals `steps`.
 - ONE compiled step. Every iteration runs the whole pool through a
   single jitted function over a fixed ``[max_slots, chunk]`` token
   matrix: decoding slots occupy one column, *prefilling* slots up to
@@ -130,7 +138,9 @@ from ..framework import faults
 from ..framework.flags import flag
 from . import kvstore
 from .metrics import ServingMetrics
-from .paging import NULL_BLOCK, BlockAllocator, PoolExhausted, PrefixCache
+from .paging import (
+    BLOCK_ROW_ORDER, NULL_BLOCK, BlockAllocator, PoolExhausted, PrefixCache,
+)
 from .queueing import (
     AdmissionQueue, CapacityExhaustedError, DeadlineExceededError, Request,
     RequestCancelled,
@@ -360,22 +370,24 @@ class SlotEngine:
             self._lora_a = None
             self._lora_b = None
         hd = cfg.hidden_size // cfg.num_heads
-        dtype = cache_dtype or jnp.float32
-        shape = (self.num_blocks, cfg.num_heads, self.block_size, hd)
-        self._ks = [jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)]
-        self._vs = [jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)]
+        self._pool_dtype = cache_dtype or jnp.float32
+        self._pool_shape = (self.num_blocks, self.block_size,
+                            cfg.num_heads, hd)
+        # held from a donating dispatch to the rebind of its outputs
+        # (the arrays in between are deleted), and by a foreign
+        # thread's gather (`export_prefix_blocks`)
+        self._pool_lock = threading.Lock()
+        self._ks, self._vs = self._zero_pools(self._pool_shape,
+                                              cfg.num_layers)
         if self._plan is not None:
             # weights by partition rule, KV pools over the head axis
             # (replicated when heads don't divide mp); block tables and
             # the allocator stay host-side numpy — replica-global
             self._values = self._plan.place_values(self._values)
-            pool_sh = self._plan.pool_sharding(cfg.num_heads)
-            self._ks = [jax.device_put(k, pool_sh) for k in self._ks]
-            self._vs = [jax.device_put(v, pool_sh) for v in self._vs]
             self.metrics.set_gauge("mesh_devices", float(self.mesh.size))
             self.metrics.note_mesh(self.mesh_spec, int(self.mesh.size))
-        self.kv_pool_bytes = int(
-            2 * cfg.num_layers * np.prod(shape) * jnp.zeros((), dtype).nbytes)
+        self.kv_pool_bytes = self._pool_bytes(self._pool_shape,
+                                              cfg.num_layers)
         self._alloc = BlockAllocator(self.num_blocks)
         if prefix_cache is None:
             prefix_cache = flag("FLAGS_serving_prefix_cache")
@@ -560,14 +572,16 @@ class SlotEngine:
             self._decode = jax.jit(
                 serving_step,
                 in_shardings=step_in,
-                out_shardings=step_out)
+                out_shardings=step_out,
+                donate_argnums=(5, 6))
             self._cow = jax.jit(
                 serving_cow,
                 in_shardings=(pools, pools, rep, rep),
-                out_shardings=(pools, pools))
+                out_shardings=(pools, pools),
+                donate_argnums=(0, 1))
         else:
-            self._decode = jax.jit(serving_step)
-            self._cow = jax.jit(serving_cow)
+            self._decode = jax.jit(serving_step, donate_argnums=(5, 6))
+            self._cow = jax.jit(serving_cow, donate_argnums=(0, 1))
 
         # -- speculative draft trace (only when spec is on: a disabled
         # engine keeps compile counters {decode: 1, cow: 1} exactly) --
@@ -594,15 +608,12 @@ class SlotEngine:
                     k: v for k, v in self._dequantize_state(
                         self._dvalues).items()}
             dhd = dcfg.hidden_size // dcfg.num_heads
-            dshape = (self.num_blocks, dcfg.num_heads, self.block_size,
-                      dhd)
-            self._dks = [jnp.zeros(dshape, dtype)
-                         for _ in range(dcfg.num_layers)]
-            self._dvs = [jnp.zeros(dshape, dtype)
-                         for _ in range(dcfg.num_layers)]
-            self.kv_pool_bytes += int(
-                2 * dcfg.num_layers * np.prod(dshape)
-                * jnp.zeros((), dtype).nbytes)
+            self._dpool_shape = (self.num_blocks, self.block_size,
+                                 dcfg.num_heads, dhd)
+            self._dks, self._dvs = self._zero_pools(
+                self._dpool_shape, dcfg.num_layers, place=False)
+            self.kv_pool_bytes += self._pool_bytes(self._dpool_shape,
+                                                   dcfg.num_layers)
             self._draft_chunk = self.spec_len + 1
 
             def serving_draft(dvalues, tok, pos, nvalid, tables, ks, vs):
@@ -628,7 +639,7 @@ class SlotEngine:
                 return (lv, [c[0] for c in new_caches],
                         [c[1] for c in new_caches])
 
-            self._draft = jax.jit(serving_draft)
+            self._draft = jax.jit(serving_draft, donate_argnums=(5, 6))
 
     # -- introspection ------------------------------------------------------
 
@@ -678,6 +689,76 @@ class SlotEngine:
     def _blocks_needed(self, n_positions):
         return -(-int(n_positions) // self.block_size)
 
+    # -- the pools ----------------------------------------------------------
+
+    def _zero_pools(self, shape, n_layers, place=True):
+        """Fresh zeroed K and V pools, one pair a layer: at
+        construction, and again when a program that was handed the
+        pools raised and took them with it (`_recover_pools`)."""
+        import jax
+        import jax.numpy as jnp
+
+        sharding = self._plan.pool_sharding(shape[2]) \
+            if place and self._plan is not None else None
+
+        def pools():
+            zeros = [jnp.zeros(shape, self._pool_dtype)
+                     for _ in range(n_layers)]
+            if sharding is None:
+                return zeros
+            return [jax.device_put(z, sharding) for z in zeros]
+
+        return pools(), pools()
+
+    def _pool_bytes(self, shape, n_layers):
+        import jax.numpy as jnp
+
+        return int(2 * n_layers * np.prod(shape)
+                   * jnp.dtype(self._pool_dtype).itemsize)
+
+    @staticmethod
+    def _lost(pools):
+        """Whether a program took any of these pools with it. What is
+        left of them then goes too: half a set of pools serves nothing
+        and would stand beside the fresh ones in the device's memory."""
+        if not any(a.is_deleted() for a in pools):
+            return False
+        for a in pools:
+            a.delete()
+        return True
+
+    def _recover_pools(self, error):
+        """After a program that was handed the pools raised. One that
+        raised before its dispatch (a fault point, a trace error) donated
+        nothing and nothing is done here. One that raised after it took
+        the pools with it: every live slot's KV is gone, so they fail
+        with `error`, the prefix index is dropped without spilling (the
+        rows it names no longer exist) and the engine goes on with empty
+        pools."""
+        if not self._lost(self._ks + self._vs):
+            return
+        self._fail_all_active(error)
+        if self._cache is not None:
+            self._cache.clear(spill=False)
+        with self._pool_lock:
+            self._ks, self._vs = self._zero_pools(
+                self._pool_shape, self.model.config.num_layers)
+        self.metrics.inc("pool_rebuilds")
+
+    def _recover_draft_pools(self):
+        """The draft's twin of `_recover_pools`: a draft call that
+        raised after its dispatch leaves empty draft pools, and every
+        slot's draft cache starts over (`dfill` 0): the next round's
+        catch-up rewrites it, as after any degraded round."""
+        if not self._lost(self._dks + self._dvs):
+            return
+        self._dks, self._dvs = self._zero_pools(
+            self._dpool_shape, self.draft_model.config.num_layers,
+            place=False)
+        for slot in self._slots:
+            if slot is not None:
+                slot.dfill, slot.fed = 0, []
+
     # -- w8a8 activation scale (frozen after a short calibration) -----------
 
     # warmup + this many real steps feed the running abs-max before the
@@ -715,20 +796,26 @@ class SlotEngine:
         call site (warmup, plain step, speculative verify) builds its
         positional list here, so jax.jit sees exactly one signature per
         engine configuration — the compile-once invariant survives any
-        mix of the w8a8 and adapter options."""
+        mix of the w8a8 and adapter options. Rebinds `_ks` / `_vs` to
+        the step's outputs and returns the rest of them: the logits,
+        the verify logits of a speculative engine, w8a8's abs-max."""
         import jax.numpy as jnp
 
         args = [self._values, jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(nvalid), jnp.asarray(self._bt), self._ks,
-                self._vs]
+                jnp.asarray(nvalid), jnp.asarray(self._bt)]
+        tail = []
         if self.w8a8:
-            args.append(self._act_arg())
+            tail.append(self._act_arg())
         elif self.max_adapters:
-            args.append(None)   # act_scale slot stays positional
+            tail.append(None)   # act_scale slot stays positional
         if self.max_adapters:
-            args.extend((jnp.asarray(self._aid), self._lora_a,
+            tail.extend((jnp.asarray(self._aid), self._lora_a,
                          self._lora_b))
-        return self._decode(*args)
+        # the pools are donated: dead from the dispatch to the rebind
+        with self._pool_lock:
+            *heads, self._ks, self._vs = self._decode(
+                *args, self._ks, self._vs, *tail)
+        return heads
 
     def swap_adapters(self, lora_a, lora_b, version=None, timeout=5.0):
         """Hot-swap the stacked adapter bank (the rollout commit path).
@@ -805,8 +892,9 @@ class SlotEngine:
     def warmup(self, mesh=None):
         """Trace the unified step and the CoW copy before traffic so the
         hot path never compiles. All tables point at the null block, so
-        the dummy step's writes land in reserved scratch; outputs are
-        discarded. Returns `compile_counts`.
+        the dummy step's writes land in reserved scratch; the logits are
+        discarded, the pools (donated, like in any step) rebound.
+        Returns `compile_counts`.
 
         `mesh` (optional) asserts the caller's mesh matches the one the
         engine compiled for — a shard restart that rebuilt topology must
@@ -834,18 +922,14 @@ class SlotEngine:
                             jnp.int32)
             pos = jnp.zeros((self.max_slots,), jnp.int32)
             nvalid = jnp.ones((self.max_slots,), jnp.int32)
+            heads = self._dispatch_decode(tok, pos, nvalid)
             if self.w8a8:
-                out = self._dispatch_decode(tok, pos, nvalid)
-                self._absorb_act_amax(out[2 if self.spec_len else 1])
-            else:
-                self._dispatch_decode(tok, pos, nvalid)
-            self._cow(self._ks, self._vs, jnp.int32(NULL_BLOCK),
-                      jnp.int32(NULL_BLOCK))
+                self._absorb_act_amax(heads[-1])
+            self._copy_block(NULL_BLOCK, NULL_BLOCK)
             if self.spec_len:
                 dtok = jnp.zeros((self.max_slots, self._draft_chunk),
                                  jnp.int32)
-                self._draft(self._dvalues, dtok, pos, nvalid,
-                            jnp.asarray(self._bt), self._dks, self._dvs)
+                self._dispatch_draft(dtok, pos, nvalid)
         self._warmed = True
         return self.compile_counts
 
@@ -896,8 +980,6 @@ class SlotEngine:
         ``(blocks, fill)`` or raises (`PoolExhausted` = wait and retry;
         anything else = fail the request). All-or-nothing: partial
         reservations are rolled back."""
-        import jax.numpy as jnp
-
         shared, n_shared, cow = [], 0, None
         if self._cache is not None:
             if self.spill_store is not None:
@@ -945,9 +1027,7 @@ class SlotEngine:
                 src, rows = cow
                 faults.fault_point("serving.cow_split")
                 with profiler.RecordEvent("serving.cow", cat="serving"):
-                    self._ks, self._vs = self._cow(
-                        self._ks, self._vs, jnp.int32(src),
-                        jnp.int32(new[0]))
+                    self._copy_block(src, new[0])
                 self.metrics.inc("cow_splits")
                 fill += rows
         except Exception:
@@ -961,6 +1041,15 @@ class SlotEngine:
         if pinned_src is not None:
             self._alloc.decref(pinned_src)
         return taken + new, fill
+
+    def _copy_block(self, src, dst):
+        """The compiled copy-on-write copy, every layer's pools at
+        once; they are donated to it like to the step."""
+        import jax.numpy as jnp
+
+        with self._pool_lock:
+            self._ks, self._vs = self._cow(
+                self._ks, self._vs, jnp.int32(src), jnp.int32(dst))
 
     def _admit(self):
         """Join-at-step: fill free slots from the queue while block
@@ -983,6 +1072,7 @@ class SlotEngine:
             except Exception as e:  # noqa: BLE001 — fail req, stay up
                 self.metrics.inc("failed")
                 req._fail(e)
+                self._recover_pools(e)   # a CoW copy that took them
                 continue
             slot = self._free.pop()
             self._bt[slot, :] = NULL_BLOCK
@@ -1001,11 +1091,20 @@ class SlotEngine:
     def export_prefix_blocks(self, prompt_ids):
         """Gather this engine's fully-written cached KV blocks covering
         `prompt_ids` into host numpy for migration. Returns a payload
-        dict (tokens / per-layer (k_rows, v_rows) / geometry) or None
-        when nothing is cached. The matched blocks are pinned (incref)
-        for the duration of the gather so a concurrent reclaim cannot
-        recycle them mid-copy; block tables were host-side all along, so
-        only block payload bytes leave the engine."""
+        dict (tokens / per-layer (k_rows, v_rows) of shape ``[n_blocks,
+        block_size, nh, hd]`` / geometry, the rows' axis order
+        included) or None when nothing is cached. The matched blocks
+        are pinned (incref) for the duration of the gather so a
+        concurrent reclaim cannot recycle them mid-copy; block tables
+        were host-side all along, so only block payload bytes leave the
+        engine.
+
+        Callable from any thread while the loop runs (`migrate.
+        migrate_prefix`). The pools are donated to every step, so an
+        array read here could be deleted under the reader: the
+        device-side gathers are enqueued under `_pool_lock`, which the
+        loop holds from a dispatch to the rebind of its outputs; the
+        copies to the host wait outside it."""
         if self._cache is None:
             return None
         ids = np.asarray(prompt_ids, np.int32).reshape(-1)
@@ -1017,14 +1116,14 @@ class SlotEngine:
         for bid in shared:
             self._alloc.incref(bid)
         try:
-            # snapshot the (immutable) pool arrays once: a concurrent
-            # step rebinding self._ks cannot tear the gather, and the
-            # pinned blocks' rows were fully written before the cache
-            # ever indexed them
-            ks, vs = list(self._ks), list(self._vs)
+            # the pinned blocks' rows were fully written before the
+            # cache ever indexed them, and a gather enqueued here reads
+            # them before any later step's write
             idx = np.asarray(shared, np.int64)
-            layers = [(np.asarray(k[idx]), np.asarray(v[idx]))
-                      for k, v in zip(ks, vs)]
+            with self._pool_lock:
+                rows = [(k[idx], v[idx])
+                        for k, v in zip(self._ks, self._vs)]
+            layers = [(np.asarray(k), np.asarray(v)) for k, v in rows]
         finally:
             for bid in shared:
                 self._alloc.decref(bid)
@@ -1032,15 +1131,18 @@ class SlotEngine:
             "tokens": [int(t) for t in ids[:n_shared]],
             "n_tokens": int(n_shared),
             "block_size": self.block_size,
+            "row_order": BLOCK_ROW_ORDER,
             "layers": layers,
         }
 
     def adopt_prefix_blocks(self, payload, timeout=5.0):
         """Adopt migrated KV blocks into this engine's pool + prefix
         cache. Applied at a step boundary when the serve loop is
-        running (pool rebinds must not race the compiled step), inline
-        otherwise. Returns the number of prompt tokens now served from
-        cache (0 = incompatible payload). All-or-nothing: any fault
+        running (pool rebinds must not race the compiled step, which is
+        handed the pools and deletes them), inline otherwise. Returns
+        the number of prompt tokens now served from cache (0 =
+        incompatible payload: another block size, layer count, block
+        shape or row order). All-or-nothing: any fault
         mid-adoption frees every block taken so far — the pool is
         leak-free and the request simply prefills from scratch."""
         if self._thread is not None and self._thread.is_alive():
@@ -1074,6 +1176,11 @@ class SlotEngine:
         if self._cache is None or payload is None:
             return 0
         if payload.get("block_size") != self.block_size:
+            return 0
+        if payload.get("row_order") != BLOCK_ROW_ORDER:
+            # head-major rows (an engine from before the pool went
+            # token-major): with nh == block_size the shapes agree and
+            # only this says the block is transposed
             return 0
         layers = payload["layers"]
         if len(layers) != len(self._ks):
@@ -1117,12 +1224,12 @@ class SlotEngine:
         if n_rows != self.block_size:
             return
         try:
-            # snapshot the (immutable) pool arrays once; the block is
-            # still cache-referenced, so its rows cannot be recycled
-            # before the hook returns
-            ks, vs = list(self._ks), list(self._vs)
+            # the cache evicts on the loop's thread, between steps (or
+            # with no loop running): no dispatch is in flight, so the
+            # pools are whole; the block is still cache-referenced, so
+            # its rows cannot be recycled before the hook returns
             layers = [(np.asarray(k[bid]), np.asarray(v[bid]))
-                      for k, v in zip(ks, vs)]
+                      for k, v in zip(self._ks, self._vs)]
             self.spill_store.append(key, self.weight_version, tokens,
                                     layers)
         except Exception:  # noqa: BLE001 — durability is best-effort
@@ -1338,8 +1445,6 @@ class SlotEngine:
         slot's pending logits (finishing slots that hit
         EOS/max/deadline), stage the next chunk for prefilling slots,
         then ONE batched step over the whole pool."""
-        import jax.numpy as jnp
-
         try:
             faults.fault_point("serving.step")
         except Exception as e:  # noqa: BLE001 — deterministic mid-decode
@@ -1355,21 +1460,9 @@ class SlotEngine:
             return
         n_pref = sum(1 for i in live
                      if self._slots[i].state == "prefill")
-        t0 = time.monotonic()
-        with profiler.RecordEvent("serving.step", cat="serving"):
-            with observe.phase("dispatch", cat="serving"):
-                if self.w8a8:
-                    logits, amax, self._ks, self._vs = \
-                        self._dispatch_decode(tok, self._pos, nvalid)
-                    self._absorb_act_amax(amax)
-                else:
-                    logits, self._ks, self._vs = \
-                        self._dispatch_decode(tok, self._pos, nvalid)
-            with observe.phase("readback", cat="serving"):
-                logits = np.asarray(logits)
-        dt = time.monotonic() - t0
+        (logits,), t0, done = self._device_step(tok, nvalid)
         with observe.phase("commit", cat="serving"):
-            self._observe_step_latency(dt, prefill_tokens,
+            self._observe_step_latency(done - t0, prefill_tokens,
                                        len(live) - n_pref)
             for i in live:
                 slot = self._slots[i]
@@ -1384,6 +1477,38 @@ class SlotEngine:
                 else:
                     slot.next_logits = logits[i]
             self._count_step(len(live), prefill_tokens)
+
+    def _device_step(self, tok, nvalid):
+        """The iteration's one dispatch of the compiled step and the
+        read-back of what sampling needs. Returns the host arrays (the
+        logits; a speculative engine's verify logits after them) and
+        the clock before the dispatch and after the read-back.
+
+        The step is handed the pools and updates them in place: the
+        lists that went in read `is_deleted()` afterwards, which
+        `pool_inplace_steps` counts. A backend that copied instead
+        leaves them alive and the counter behind `steps`."""
+        ks, vs = self._ks, self._vs
+        t0 = time.monotonic()
+        try:
+            with profiler.RecordEvent("serving.step", cat="serving"):
+                with observe.phase("dispatch", cat="serving"):
+                    heads = self._dispatch_decode(tok, self._pos, nvalid)
+                    if self.w8a8:
+                        self._absorb_act_amax(heads.pop())
+                with observe.phase("readback", cat="serving"):
+                    heads = [np.asarray(h) for h in heads]
+        except Exception:
+            if self._ks is not ks:
+                # dispatched, and its logits cannot be read: what it
+                # left in place of the pools is no KV to serve from
+                for a in self._ks + self._vs:
+                    a.delete()
+            raise
+        done = time.monotonic()
+        if all(a.is_deleted() for a in ks + vs):
+            self.metrics.inc("pool_inplace_steps")
+        return heads, t0, done
 
     def _observe_step_latency(self, dt, prefill_tokens, n_decoding):
         """Attribute one device step, dispatch to the logits on the
@@ -1467,8 +1592,6 @@ class SlotEngine:
         degrades the round to plain decode: proposals are dropped, the
         draft cache keeps whatever catch-up landed, and every slot
         still commits exactly its picked token — no losses, no dups."""
-        import jax.numpy as jnp
-
         try:
             faults.fault_point("serving.step")
         except Exception as e:  # noqa: BLE001 — deterministic mid-decode
@@ -1497,6 +1620,7 @@ class SlotEngine:
         except Exception:  # noqa: BLE001 — degrade to plain decode
             drafted_ok = False
             self.metrics.inc("spec_draft_faults")
+            self._recover_draft_pools()
         for i, slot, nxt, s_i in plan:
             props = slot.drafted[:s_i] if drafted_ok else []
             slot.spec_staged = props
@@ -1507,20 +1631,7 @@ class SlotEngine:
         faults.fault_point("serving.verify")
         n_pref = sum(1 for i in live
                      if self._slots[i].state == "prefill")
-        t0 = time.monotonic()
-        with profiler.RecordEvent("serving.step", cat="serving"):
-            with observe.phase("dispatch", cat="serving"):
-                if self.w8a8:
-                    lv, sv, amax, self._ks, self._vs = \
-                        self._dispatch_decode(tok, self._pos, nvalid)
-                    self._absorb_act_amax(amax)
-                else:
-                    lv, sv, self._ks, self._vs = \
-                        self._dispatch_decode(tok, self._pos, nvalid)
-            with observe.phase("readback", cat="serving"):
-                lv = np.asarray(lv)
-                sv = np.asarray(sv)
-        done = time.monotonic()
+        (lv, sv), t0, done = self._device_step(tok, nvalid)
         with observe.phase("commit", cat="serving"):
             self._observe_step_latency(done - t0, prefill_tokens,
                                        len(live) - n_pref)
@@ -1604,8 +1715,6 @@ class SlotEngine:
         Successful feeds are logged to `slot.fed` AFTER the call
         returns, so a mid-phase fault leaves bookkeeping consistent
         with what actually landed in the draft pools."""
-        import jax.numpy as jnp
-
         width = self._draft_chunk
         idle_pos = self.blocks_per_slot * self.block_size
         qlast: dict = {}
@@ -1638,16 +1747,25 @@ class SlotEngine:
             if not feeds:
                 return
             with profiler.RecordEvent("serving.draft", cat="serving"):
-                lv, self._dks, self._dvs = self._draft(
-                    self._dvalues, jnp.asarray(dtok), jnp.asarray(dpos),
-                    jnp.asarray(dnval), jnp.asarray(self._bt),
-                    self._dks, self._dvs)
+                lv = self._dispatch_draft(dtok, dpos, dnval)
             lv = np.asarray(lv)
             for i, (slot, seg) in feeds.items():
                 slot.fed.extend(int(t) for t in seg)
                 qlast[i] = lv[i]
         raise RuntimeError(
             f"draft catch-up did not converge in {limit} micro-steps")
+
+    def _dispatch_draft(self, tok, pos, nvalid):
+        """One call of the compiled draft micro-step; the draft pools
+        are donated to it and rebound to its outputs (only the loop's
+        thread ever reads them)."""
+        import jax.numpy as jnp
+
+        lv, self._dks, self._dvs = self._draft(
+            self._dvalues, jnp.asarray(tok), jnp.asarray(pos),
+            jnp.asarray(nvalid), jnp.asarray(self._bt), self._dks,
+            self._dvs)
+        return lv
 
     def _draft_pick(self, slot, qrow):
         """Sample one proposal from the draft distribution, recording
@@ -1808,6 +1926,9 @@ class SlotEngine:
         except Exception as e:  # noqa: BLE001 — engine stays up
             self.metrics.inc("step_errors")
             self._fail_all_active(e)
+            # a step that raised after its dispatch took the donated
+            # pools with it; one that raised before it left them whole
+            self._recover_pools(e)
 
     def abandon(self, error):
         """Supervisor-side takeover of a dead/hung replica: stop the

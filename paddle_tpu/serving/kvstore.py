@@ -31,7 +31,12 @@ crc-framed, torn-tail-tolerant WAL + tmp/rename snapshot machinery of
   new weights.
 
 Records are framed ``<I crc32> <I len> payload`` exactly like the PS
-WAL; compaction rewrites the live records via tmp + fsync + rename when
+WAL. A payload's header names the axis order of its KV rows
+(`paging.BLOCK_ROW_ORDER`, ``[block_size, nh, hd]``) right after the
+digest and the generation: a record written head-major by an earlier
+engine carries its token count there instead, is skipped by the scan —
+a miss, so the request re-prefills — and goes at the next compaction.
+Compaction rewrites the live records via tmp + fsync + rename when
 the file crosses ``FLAGS_serving_kv_spill_cap_mb``. One store instance
 is shared per directory (`open_spill_store`), so every replica of a
 fleet spills into — and can resume from — the same tier: a session
@@ -49,15 +54,18 @@ import numpy as np
 
 from ..framework import faults, monitor
 from ..framework.flags import flag
+from .paging import BLOCK_ROW_ORDER
 from .queueing import ServingError
 
 __all__ = ["KVSpillStore", "SpillFencedError", "open_spill_store",
            "reset_spill_stores"]
 
 _HDR = struct.Struct("<II")           # crc32(payload), len(payload)
-#: digest(20B sha1), generation(int64), n_tokens, block_size, n_layers,
-#: n_heads, head_dim, dtype tag (8B ascii, NUL-padded)
-_META = struct.Struct("<20sq5i8s")
+#: digest(20B sha1), generation(int64), row order (4B ascii,
+#: NUL-padded), n_tokens, block_size, n_layers, n_heads, head_dim,
+#: dtype tag (8B ascii, NUL-padded)
+_META = struct.Struct("<20sq4s5i8s")
+_ROW_ORDER = BLOCK_ROW_ORDER.encode().ljust(4, b"\x00")
 
 SPILL_FILE = "kv.spill"
 
@@ -79,10 +87,10 @@ def _frame(payload: bytes) -> bytes:
 def _pack_record(digest, generation, tokens, layers):
     tokens = np.ascontiguousarray(tokens, np.int32)
     k0 = np.ascontiguousarray(layers[0][0])
-    nh, bs, hd = k0.shape
+    bs, nh, hd = k0.shape
     dtype = str(k0.dtype).encode()[:8]
-    parts = [_META.pack(digest, int(generation), tokens.size, bs,
-                        len(layers), nh, hd, dtype),
+    parts = [_META.pack(digest, int(generation), _ROW_ORDER, tokens.size,
+                        bs, len(layers), nh, hd, dtype),
              tokens.tobytes()]
     for k, v in layers:
         parts.append(np.ascontiguousarray(k).tobytes())
@@ -91,20 +99,20 @@ def _pack_record(digest, generation, tokens, layers):
 
 
 def _unpack_record(payload):
-    digest, gen, n_tok, bs, n_layers, nh, hd, dtype = \
+    digest, gen, _order, n_tok, bs, n_layers, nh, hd, dtype = \
         _META.unpack_from(payload, 0)
     pos = _META.size
     tokens = np.frombuffer(payload, np.int32, count=n_tok, offset=pos)
     pos += n_tok * 4
     dt = np.dtype(dtype.rstrip(b"\x00").decode())
-    rows = nh * bs * hd
+    rows = bs * nh * hd
     layers = []
     for _ in range(n_layers):
         k = np.frombuffer(payload, dt, count=rows, offset=pos)
         pos += rows * dt.itemsize
         v = np.frombuffer(payload, dt, count=rows, offset=pos)
         pos += rows * dt.itemsize
-        layers.append((k.reshape(nh, bs, hd), v.reshape(nh, bs, hd)))
+        layers.append((k.reshape(bs, nh, hd), v.reshape(bs, nh, hd)))
     return {"digest": digest, "generation": gen,
             "tokens": tokens, "block_size": bs, "layers": layers}
 
@@ -125,6 +133,7 @@ class KVSpillStore:
         #: digest -> (offset of payload, payload length, generation)
         self._index: dict = {}
         self._fenced: set = set()      # fenced generations
+        self._stale = 0                # records skipped for their order
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         good_end = self._scan()
         self._f = open(path, "r+b" if os.path.exists(path) else "w+b")
@@ -148,11 +157,18 @@ class KVSpillStore:
             if len(body) < n or zlib.crc32(body) != crc:
                 break                   # torn tail — end of durable data
             try:
-                digest, gen = struct.unpack_from("<20sq", body, 0)
+                digest, gen, order = _META.unpack_from(body, 0)[:3]
             except struct.error:
                 break
-            # later records supersede earlier ones for the same prefix
-            self._index[digest] = (pos + _HDR.size, n, gen)
+            if order == _ROW_ORDER:
+                # later records supersede earlier ones for the same
+                # prefix
+                self._index[digest] = (pos + _HDR.size, n, gen)
+            else:
+                # rows in another axis order (head-major, from before
+                # the pool went token-major): never restored, dropped
+                # for good by the next compaction
+                self._stale += 1
             pos += _HDR.size + n
         return pos
 
@@ -285,6 +301,7 @@ class KVSpillStore:
         self._f.close()
         os.replace(tmp, self.path)
         self._index = index
+        self._stale = 0
         self._f = open(self.path, "r+b")
         self._f.seek(0, os.SEEK_END)
         monitor.stat_add("serving.kv_spill_compactions")
@@ -300,6 +317,7 @@ class KVSpillStore:
     def stats(self):
         with self._lock:
             return {"records": len(self._index),
+                    "stale_records": self._stale,
                     "bytes": self._f.tell(),
                     "fenced_generations": sorted(self._fenced)}
 

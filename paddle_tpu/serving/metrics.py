@@ -42,7 +42,10 @@ class ServingMetrics:
     (prompt positions written by chunked prefill), `prompt_tokens` /
     `prefix_lookups` / `prefix_hit_blocks` / `prefix_hit_tokens` /
     `cow_splits` (prefix-cache traffic), `rejected_capacity` (429 sheds
-    whose block demand exceeds the pool), and the fast-decode set:
+    whose block demand exceeds the pool), `pool_inplace_steps` (steps
+    whose donated KV pools were updated in place: equals `steps`) and
+    `pool_rebuilds` (a program raised after it was handed the pools;
+    the engine went on with empty ones), and the fast-decode set:
     `spec_drafted_tokens` / `spec_accepted_tokens` /
     `spec_rejected_tokens` / `spec_rounds` / `spec_draft_faults`
     (speculative decoding, fed via `observe_spec`, surfaced under

@@ -7,7 +7,10 @@ tokens. In-process replicas share no device state (each engine owns its
 pool), so migration is an explicit export -> stream -> adopt pipeline:
 
   * export: the prefill engine gathers the prompt's cached prefix
-    blocks from its pool into host numpy (`export_prefix_blocks`);
+    blocks from its pool into host numpy (`export_prefix_blocks`),
+    rows ``[n_blocks, block_size, nh, hd]`` with their axis order named
+    in the payload (`paging.BLOCK_ROW_ORDER`): a payload in another
+    order is refused by the adopting engine, never adopted transposed;
   * stream: the payload rides `KVMailbox`, an in-process loopback that
     mirrors the gang-layer ``dist.p2p_*`` mailbox contract exactly —
     `deadline_guard("dist.p2p_send")` before the enqueue and

@@ -5,9 +5,11 @@ cache that lets requests sharing a token prefix share physical blocks.
 Design (vLLM PagedAttention + SGLang RadixAttention, collapsed to the
 slot engine's needs):
 
-- The device pool is `[num_blocks, nh, block_size, hd]` per layer;
-  every logical sequence position `t` of a slot maps through its block
-  table to physical row `(table[t // bs], t % bs)`. Block 0 is the
+- The device pool is `[num_blocks, block_size, nh, hd]` per layer
+  (token-major: one block's rows are `[block_size, nh, hd]`,
+  `BLOCK_ROW_ORDER`); every logical sequence position `t` of a slot
+  maps through its block table to physical row
+  `(table[t // bs], t % bs)`, the pool's two leading axes. Block 0 is the
   reserved *null block*: it is never allocated, free slots point every
   table entry at it, and all padding/garbage scatter writes land there
   — so the compiled step can always write `[max_slots, chunk]` rows
@@ -38,11 +40,19 @@ import numpy as np
 
 from ..framework import faults
 
-__all__ = ["NULL_BLOCK", "PoolExhausted", "BlockAllocator", "PrefixCache",
-           "positions_to_rows"]
+__all__ = ["BLOCK_ROW_ORDER", "NULL_BLOCK", "PoolExhausted",
+           "BlockAllocator", "PrefixCache", "positions_to_rows"]
 
 #: physical block 0 — reserved scratch target for padding writes
 NULL_BLOCK = 0
+
+#: axis order of one block's rows wherever they leave a pool:
+#: ``[block_size (t), num_heads (h), head_dim (d)]``. It is part of a
+#: migration payload's geometry and of a spill record's header, because
+#: a shape cannot tell it from the head-major ``[nh, block_size, hd]``
+#: of earlier pools when ``nh == block_size``: rows that do not name
+#: this order are refused, not adopted transposed.
+BLOCK_ROW_ORDER = "thd"
 
 _ROOT = b"\x00root"
 
@@ -237,9 +247,9 @@ class PrefixCache:
         return np.concatenate(chunks[::-1]) if chunks else \
             np.zeros((0,), np.int32)
 
-    def _evict(self, key):
+    def _evict(self, key, spill=True):
         bid = self._blocks[key]
-        if self.spill_hook is not None \
+        if spill and self.spill_hook is not None \
                 and self._alloc.refcount(bid) == 1:
             # append-before-evict: persist the rows while the block
             # still exists — the decref below frees it for reuse
@@ -273,11 +283,13 @@ class PrefixCache:
                 freed += 1
         return freed
 
-    def clear(self):
+    def clear(self, spill=True):
         """Drop every entry (and its allocator reference). Leaves go
         before parents so the spill hook can still resolve each
-        entry's full token prefix through a live parent chain."""
+        entry's full token prefix through a live parent chain.
+        `spill=False` keeps the hook out of it: the pool's rows are
+        gone (a failed step took them), there is nothing to persist."""
         while self._blocks:
             for key in [k for k in self._blocks
                         if not self._children.get(k)]:
-                self._evict(key)
+                self._evict(key, spill)

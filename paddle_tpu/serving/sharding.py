@@ -129,9 +129,9 @@ class ShardingPlan:
     an axis a tensor is too small or too low-rank for degrades to
     replicated rather than failing (GSPMD handles uneven *padding*, but
     a rank-1 bias cannot take a rank-2 spec). The paged KV pool
-    ``[num_blocks, num_heads, block_size, head_dim]`` shards over the
-    head axis iff ``num_heads % mp == 0``; block tables / allocator
-    stay host-side numpy and therefore replica-global.
+    ``[num_blocks, block_size, num_heads, head_dim]`` shards over the
+    head axis (axis 2) iff ``num_heads % mp == 0``; block tables /
+    allocator stay host-side numpy and therefore replica-global.
     """
 
     def __init__(self, mesh, rules=GPT_PARTITION_RULES):
@@ -181,8 +181,5 @@ class ShardingPlan:
         replicated (the engine still serves; it just stops saving KV
         memory — same silent-guard stance as the overlap kernels)."""
         if self.mp > 1 and num_heads % self.mp == 0:
-            return self._named(P(None, MP_AXIS, None, None))
+            return self._named(P(None, None, MP_AXIS, None))
         return self.replicated()
-
-    def place_pool(self, pool, num_heads):
-        return jax.device_put(pool, self.pool_sharding(num_heads))

@@ -15,6 +15,19 @@ import paddle_tpu as paddle
 from paddle_tpu.distributed import ps
 
 
+@pytest.fixture(autouse=True)
+def _restore_ps_runtime():
+    """`_runtime_for` installs a process-wide PS runtime. Put back what
+    was there: a later test file of the same worker would otherwise
+    meet a runtime whose servers are gone (`fleet.metrics` reduces
+    through whatever runtime it finds)."""
+    import paddle_tpu.distributed.ps.runtime as rtmod
+
+    prev = rtmod._runtime
+    yield
+    rtmod._runtime = prev
+
+
 @pytest.fixture()
 def two_servers():
     s1 = ps.PSServer("127.0.0.1:0").start()

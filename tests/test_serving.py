@@ -570,6 +570,191 @@ def test_steady_state_runs_under_no_retrace(gpt):
 # ---------------------------------------------------------------------------
 # robustness: mid-decode faults, deadlines, cancel, drain
 # ---------------------------------------------------------------------------
+# the pools: token-major, donated to every program, updated in place
+# (the fixture has 4 heads of 8 over blocks of 8 tokens... and a head
+# dim of 8, so `[num_blocks, 8, 4, 8]`: a swapped head and token axis
+# cannot pass by shape)
+# ---------------------------------------------------------------------------
+
+
+def _dead(pools):
+    return [a.is_deleted() for a in pools]
+
+
+def test_paged_engine_matches_dense_generate_over_mixed_steps(gpt, eng):
+    """Token for token against `generate()`'s dense `[b, nh, s, hd]`
+    cache, over a run whose steps mix a prefilling slot (a 19-token
+    prompt in chunks of 8) with a decoding one, and whose third request
+    joins a recycled slot."""
+    prompts = [_prompt(70, 19), _prompt(71, 5), _prompt(72, 11)]
+    new = [6, 9, 4]
+    futs = [eng.submit(p, max_new_tokens=n, timeout=None)
+            for p, n in zip(prompts, new)]
+    mixed = 0
+    while eng.active or eng.queue.depth:
+        eng._admit()
+        states = {s.state for s in eng._slots if s is not None}
+        mixed += states == {"prefill", "decode"}
+        eng._step()
+    assert mixed >= 1
+    for p, n, fut in zip(prompts, new, futs):
+        want = gpt.generate(p[None, :], max_new_tokens=n)
+        np.testing.assert_array_equal(fut.result(5),
+                                      np.asarray(want._value)[0])
+    assert eng.metrics.get("pool_inplace_steps") == \
+        eng.metrics.get("steps") > 0
+
+
+def test_warmup_leaves_live_pools(gpt):
+    """`warmup()` hands the pools to the step and to the CoW copy like
+    any caller: what it was built with is gone, what it holds after is
+    live, twice over."""
+    e = serving.SlotEngine(gpt, max_slots=2, block_size=8,
+                           prefill_chunk=8)
+    assert e._ks[0].shape == (e.num_blocks, 8, 4, 8)
+    built = e._ks + e._vs
+    e.warmup()
+    assert all(_dead(built))
+    assert not any(_dead(e._ks + e._vs))
+    e.warmup()                                # under no_retrace
+    assert not any(_dead(e._ks + e._vs))
+    assert e.metrics.get("pool_inplace_steps") == 0   # no step yet
+    p = _prompt(73, 6)
+    out, _ = _drive(e, p, max_new=3)
+    np.testing.assert_array_equal(out, _ref_greedy(gpt, p, 3))
+
+
+def test_step_and_cow_update_the_pools_in_place(gpt, eng):
+    """The arrays handed to a step, and to a copy-on-write copy, read
+    deleted after it; the engine holds live ones; every step counted."""
+    a = list(range(1, 18))
+    eng.submit(np.asarray(a, np.int32), max_new_tokens=3, timeout=None)
+    eng._admit()
+    while eng.active:
+        before, n = eng._ks + eng._vs, eng.metrics.get("steps")
+        eng._step()
+        # (the last call only samples the last token: no dispatch)
+        assert all(_dead(before)) == (eng.metrics.get("steps") > n)
+        assert not any(_dead(eng._ks + eng._vs))
+    b = list(a)
+    b[11] = 77                                # diverge inside block 2
+    fut = eng.submit(np.asarray(b, np.int32), max_new_tokens=3,
+                     timeout=None)
+    before = eng._ks + eng._vs
+    eng._admit()
+    assert eng.metrics.get("cow_splits") == 1
+    assert all(_dead(before))
+    assert not any(_dead(eng._ks + eng._vs))
+    while eng.active:
+        eng._step()
+    np.testing.assert_array_equal(fut.result(5), _ref_greedy(gpt, b, 3))
+    assert eng.metrics.get("pool_inplace_steps") == \
+        eng.metrics.get("steps") > 0
+    assert eng.metrics.get("pool_rebuilds") == 0
+
+
+def test_export_racing_a_running_loop_returns_whole_blocks(gpt):
+    """`export_prefix_blocks` from another thread while the loop steps:
+    every payload is None or the same whole blocks, never "Array has
+    been deleted". The step is held between its dispatch and the rebind
+    so that a reader without the lock would meet the donated arrays."""
+    srv = serving.Server(gpt, max_slots=2, block_size=8).start()
+    eng = srv.engine
+    stop = threading.Event()
+
+    def traffic():
+        i = 0
+        while not stop.is_set():
+            srv.generate(_prompt(200 + i, 6), max_new_tokens=6,
+                         timeout=120)
+            i += 1
+
+    t = threading.Thread(target=traffic, daemon=True)
+    try:
+        p = _prompt(80, 20)
+        srv.generate(p, max_new_tokens=2, timeout=120)
+        ref = eng.export_prefix_blocks(p)     # the loop is idle
+        assert ref["n_tokens"] == 16 and ref["row_order"] == "thd"
+        assert ref["layers"][0][0].shape == (2, 8, 4, 8)
+        real = eng._decode
+
+        def held(*args):
+            out = real(*args)
+            time.sleep(0.002)
+            return out
+
+        eng._decode = held
+        t.start()
+        whole, until = 0, time.monotonic() + 60
+        target = eng.metrics.get("steps") + 40
+        while eng.metrics.get("steps") < target \
+                and time.monotonic() < until:
+            got = eng.export_prefix_blocks(p)
+            if got is None:                   # evicted under pressure
+                continue
+            for (k, v), (k0, v0) in zip(got["layers"], ref["layers"]):
+                np.testing.assert_array_equal(k, k0)
+                np.testing.assert_array_equal(v, v0)
+            whole += 1
+        assert whole > 0
+        assert eng.metrics.get("step_errors") == 0
+    finally:
+        stop.set()
+        if t.is_alive():
+            t.join(120)
+        srv.shutdown(drain=True)
+
+
+class _Unreadable:
+    def __array__(self, *a, **kw):
+        raise RuntimeError("device fell over")
+
+
+@pytest.mark.parametrize("when", ["before", "after", "readback"])
+def test_step_that_raises_leaves_a_serving_engine(gpt, when):
+    """A step that raises once its inputs were donated (or whose logits
+    cannot be read) leaves no pool: the engine rebuilds empty ones,
+    drops the prefix index, fails the live slots and serves the next
+    request. One that raises before its dispatch donated nothing: the
+    pools and the index stay."""
+    srv = serving.Server(gpt, max_slots=2, block_size=8).start()
+    eng = srv.engine
+    try:
+        warm = _prompt(90, 20)
+        srv.generate(warm, max_new_tokens=2, timeout=120)
+        assert eng.prefix_cache_size > 0
+        real, calls = eng._decode, []
+
+        def broken(*args):
+            if calls:
+                return real(*args)
+            calls.append(when)
+            if when == "before":
+                raise RuntimeError("device fell over")
+            out = real(*args)                 # dispatched: inputs gone
+            if when == "after":
+                raise RuntimeError("device fell over")
+            return (_Unreadable(),) + tuple(out[1:])
+
+        eng._decode = broken
+        fut = srv.submit(_prompt(91, 4), max_new_tokens=8, timeout=120)
+        with pytest.raises(RuntimeError, match="fell over"):
+            fut.result(120)
+        lost = when != "before"
+        for p in (_prompt(92, 5), warm):      # the cached prompt too
+            out = srv.generate(p, max_new_tokens=3, timeout=120)
+            np.testing.assert_array_equal(out, _ref_greedy(gpt, p, 3))
+        assert srv.metrics.get("step_errors") == 1
+        assert srv.metrics.get("pool_rebuilds") == int(lost)
+        # the warm prompt's blocks were served from the index only
+        # where the pools behind it survived
+        assert (srv.metrics.get("prefix_hit_blocks") > 0) == (not lost)
+        assert not any(_dead(eng._ks + eng._vs))
+    finally:
+        srv.shutdown(drain=True)
+
+
+# ---------------------------------------------------------------------------
 
 
 def test_mid_decode_fault_fails_inflight_engine_survives(gpt):

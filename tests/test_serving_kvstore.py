@@ -77,8 +77,8 @@ def _record(seed, n_tokens=8, bs=8, n_layers=2, nh=4, hd=16):
     arbitrary 20 bytes; the store never interprets it."""
     rng = np.random.RandomState(seed)
     tokens = rng.randint(0, VOCAB, (n_tokens,)).astype(np.int32)
-    layers = [(rng.randn(nh, bs, hd).astype(np.float32),
-               rng.randn(nh, bs, hd).astype(np.float32))
+    layers = [(rng.randn(bs, nh, hd).astype(np.float32),
+               rng.randn(bs, nh, hd).astype(np.float32))
               for _ in range(n_layers)]
     return bytes(rng.randint(0, 256, (20,), np.uint8)), tokens, layers
 
@@ -189,6 +189,50 @@ def test_store_cap_triggers_compaction(tmp_path):
     assert store.nbytes <= 0.01 * (1 << 20)
     np.testing.assert_array_equal(store.get(d)["tokens"], tokens)
     store.close()
+
+
+def _old_order_frame(digest, generation, tokens, layers):
+    """One record as the store framed it while pools were head-major:
+    no row order in the header, rows `[nh, block_size, hd]`."""
+    import struct
+    import zlib
+
+    bs, nh, hd = layers[0][0].shape
+    parts = [struct.pack("<20sq5i8s", digest, generation, tokens.size, bs,
+                         len(layers), nh, hd,
+                         str(layers[0][0].dtype).encode()[:8]),
+             np.ascontiguousarray(tokens, np.int32).tobytes()]
+    for k, v in layers:
+        parts.append(np.ascontiguousarray(k.transpose(1, 0, 2)).tobytes())
+        parts.append(np.ascontiguousarray(v.transpose(1, 0, 2)).tobytes())
+    body = b"".join(parts)
+    return struct.pack("<II", zlib.crc32(body), len(body)) + body
+
+
+def test_store_skips_old_order_records(tmp_path):
+    """A record written head-major is a miss after a reopen, beside a
+    current one that is found; compaction drops it for good. Heads ==
+    block size here, so nothing but the header's row order could tell."""
+    d_old, t_old, l_old = _record(7, nh=8)
+    d_new, t_new, l_new = _record(8, nh=8)
+    store = KVSpillStore(str(tmp_path))
+    store.append(d_new, 0, t_new, l_new)
+    end = store.nbytes
+    store.close()
+    with open(store.path, "ab") as f:
+        f.write(_old_order_frame(d_old, 0, t_old, l_old))
+    again = KVSpillStore(str(tmp_path))
+    assert d_new in again and d_old not in again
+    assert again.get(d_old) is None
+    assert again.stats()["stale_records"] == 1 and len(again) == 1
+    np.testing.assert_array_equal(again.get(d_new)["layers"][1][0],
+                                  l_new[1][0])
+    again.append(d_old, 0, t_old, l_old)      # re-spilled in this order
+    assert again.get(d_old)["layers"][0][0].shape == (8, 8, 16)
+    assert again.compact() == 2
+    assert again.stats()["stale_records"] == 0
+    assert again.nbytes == 2 * end
+    again.close()
 
 
 def test_open_spill_store_shared_per_dir_and_disabled(tmp_path):
@@ -431,6 +475,42 @@ def test_tampered_spill_reprefills_bitwise(tmp_path, gpt):
     np.testing.assert_array_equal(out2, _ref_greedy(gpt, p2, 3))
     assert srv.metrics.get("kv_restored_blocks") == 2
     assert srv.metrics.get("kv_restore_corrupt") == 1
+    srv.shutdown(drain=True)
+
+
+def test_old_order_spill_records_reprefill_bitwise(tmp_path):
+    """A spill directory left by an engine whose pools were head-major:
+    the session's records are there under the right digests, same
+    weights, same shapes (8 heads over blocks of 8) — and none is
+    restored. The request re-prefills and answers the same tokens."""
+    paddle.seed(17)
+    cfg = GPTConfig(vocab_size=VOCAB, hidden_size=64, num_layers=2,
+                    num_heads=8, max_seq_len=64, dropout=0.0,
+                    attn_dropout=0.0, use_parallel=False)
+    square = GPTForPretraining(cfg)
+    square.eval()
+    srv = _server(square, tmp_path)
+    p1 = _prompt(21, 24)
+    out1 = np.asarray(srv.generate(p1, max_new_tokens=3, timeout=120.0),
+                      np.int32)
+    srv.engine.spill_cache()
+    store = srv.engine.spill_store
+    recs = [store.get(d) for d in list(store._index)]
+    assert len(recs) == 3 and recs[0]["layers"][0][0].shape == (8, 8, 8)
+    srv.shutdown(drain=True)
+    reset_spill_stores()
+    with open(store.path, "wb") as f:
+        for r in recs:
+            f.write(_old_order_frame(r["digest"], r["generation"],
+                                     r["tokens"], r["layers"]))
+    srv = _server(square, tmp_path)
+    assert srv.engine.spill_store.stats()["stale_records"] == 3
+    p2 = np.concatenate([out1, _prompt(22, 6)])
+    out2 = np.asarray(srv.generate(p2, max_new_tokens=3, timeout=120.0),
+                      np.int32)
+    np.testing.assert_array_equal(out2, _ref_greedy(square, p2, 3))
+    assert srv.metrics.get("kv_restored_blocks") == 0
+    assert srv.metrics.get("prefix_hit_tokens") == 0
     srv.shutdown(drain=True)
 
 

@@ -119,7 +119,7 @@ def test_sharding_plan_fits_and_degrades(gpt):
     assert sh[emb].spec == P(None, None)
     assert sh[fc1].spec == P(None, MP_AXIS)
     # pool shards over heads iff divisible; block tables stay host-side
-    assert plan.pool_sharding(4).spec == P(None, MP_AXIS, None, None)
+    assert plan.pool_sharding(4).spec == P(None, None, MP_AXIS, None)
     assert plan.pool_sharding(3).spec == P()
 
 
@@ -154,6 +154,38 @@ def test_greedy_parity_across_mesh_shapes(gpt):
     for spec in ("dp1.mp2", "dp1.mp4"):
         for a, b in zip(outs[None], outs[spec]):
             np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("spec", ["dp1.mp2", "dp1.mp4"])
+def test_meshed_step_keeps_head_sharded_pools_in_place(gpt, spec):
+    """The `in_shardings` / `out_shardings` branch donates like the
+    plain one: the pool `[num_blocks, block_size, nh, hd]` stays
+    sharded over its head axis (2) through warm-up and every step, the
+    arrays handed in are gone after it, every step counted."""
+    eng = _engine(gpt, mesh=spec, prefix_cache=True)
+    want = P(None, None, MP_AXIS, None)
+    assert eng._ks[0].shape == (32, 8, 4, 8)
+    built = eng._ks + eng._vs
+    eng.warmup()
+    assert all(a.is_deleted() for a in built)
+    assert all(a.sharding.spec == want for a in eng._ks + eng._vs)
+    a = np.arange(1, 18, dtype=np.int32)
+    b = a.copy()
+    b[11] = 77                                # CoW inside block 2
+    for p in (a, b):
+        fut = eng.submit(p, max_new_tokens=3)
+        eng._admit()
+        while eng.active:
+            before, n = eng._ks + eng._vs, eng.metrics.get("steps")
+            eng._step()
+            assert all(x.is_deleted() for x in before) \
+                == (eng.metrics.get("steps") > n)
+        fut.result(5)
+    assert eng.metrics.get("cow_splits") == 1
+    assert all(x.sharding.spec == want for x in eng._ks + eng._vs)
+    assert eng.metrics.get("pool_inplace_steps") == \
+        eng.metrics.get("steps") > 0
+    assert eng.compile_counts == {"decode": 1, "cow": 1}
 
 
 def test_overlap_routes_sharded_decode(gpt):
@@ -273,6 +305,42 @@ def test_migrate_prefix_moves_blocks_and_stays_bitwise(gpt):
     finally:
         src.shutdown()
         dst.shutdown()
+
+
+def test_old_order_migration_payload_is_refused(gpt):
+    """A payload whose rows are head-major `[n, nh, block_size, hd]`
+    (an engine from before the pool went token-major), or that does not
+    say, is refused whole — also where heads == block size and the
+    shapes agree, so that only the named order tells them apart."""
+    cfg = GPTConfig(vocab_size=VOCAB, hidden_size=64, num_layers=2,
+                    num_heads=8, max_seq_len=64, dropout=0.0,
+                    attn_dropout=0.0, use_parallel=False)
+    paddle.seed(29)
+    square = GPTForPretraining(cfg)               # 8 heads, blocks of 8
+    square.eval()
+    prompt = np.arange(1, 18, dtype=np.int32)
+    for model in (gpt, square):
+        src = _engine(model, prefix_cache=True)
+        dst = _engine(model, prefix_cache=True)
+        _populate_cache(src.start(), prompt)
+        src.shutdown()
+        payload = src.export_prefix_blocks(prompt)
+        assert payload["row_order"] == "thd"
+        nh = model.config.num_heads
+        assert payload["layers"][0][0].shape == (2, 8, nh, 64 // 8)
+        old = dict(payload, row_order="htd", layers=[
+            (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+            for k, v in payload["layers"]])
+        unnamed = {k: v for k, v in old.items() if k != "row_order"}
+        # the square model's transposed rows have the pool's own shape
+        assert (old["layers"][0][0].shape
+                == payload["layers"][0][0].shape) == (nh == 8)
+        free0 = dst.free_blocks
+        assert dst.adopt_prefix_blocks(old) == 0
+        assert dst.adopt_prefix_blocks(unnamed) == 0
+        assert dst.free_blocks == free0 and dst.prefix_cache_size == 0
+        assert dst.adopt_prefix_blocks(payload) == 16
+        assert dst.prefix_cache_size == 2
 
 
 def test_kv_migrate_fault_is_leak_free(gpt):

@@ -87,6 +87,7 @@ def _drive(eng, prompt, max_new=6, snoop_first_logits=False, **gen):
         except Exception as e:  # noqa: BLE001 — _loop parity
             eng.metrics.inc("step_errors")
             eng._fail_all_active(e)
+            eng._recover_pools(e)
         if snoop_first_logits and first is None:
             for s in eng._slots:
                 if s is not None and s.state == "decode" \
@@ -217,13 +218,81 @@ def test_spec_bulk_scatter_writes_same_pool_rows(gpt):
         # final sampled token (never fed back)
         positions = np.arange(p.size + max_new - 1)
         blk, off = positions_to_rows(table, positions, eng.block_size)
-        return [np.asarray(ks)[blk, :, off, :] for ks in eng._ks] + \
-               [np.asarray(vs)[blk, :, off, :] for vs in eng._vs]
+        return [np.asarray(ks)[blk, off] for ks in eng._ks] + \
+               [np.asarray(vs)[blk, off] for vs in eng._vs]
 
     rows_plain = pool_rows(_engine(gpt))
     rows_spec = pool_rows(_engine(gpt, spec_len=3))
     for a, b in zip(rows_plain, rows_spec):
         np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-6)
+
+
+def test_spec_step_and_draft_update_their_pools_in_place(gpt, draft_gpt):
+    """The verify step is handed the target's pools, each draft
+    micro-step the draft's (2 heads over blocks of 8: `[nb, 8, 2, 8]`):
+    what went in reads deleted afterwards, the engine holds live ones,
+    and every step counted as in place."""
+    eng = serving.SlotEngine(gpt, max_slots=2, block_size=8,
+                             prefill_chunk=8, spec_len=3,
+                             draft_model=draft_gpt)
+    assert eng._ks[0].shape == (eng.num_blocks, 8, 4, 8)
+    assert eng._dks[0].shape == (eng.num_blocks, 8, 2, 8)
+    built = eng._ks + eng._vs + eng._dks + eng._dvs
+    eng.warmup()
+    assert all(a.is_deleted() for a in built)
+    fut = eng.submit(_prompt(41, 7), max_new_tokens=8, timeout=None)
+    eng._admit()
+    drafts = []
+    real = eng._draft
+
+    def watched(*args):
+        handed = list(args[5]) + list(args[6])
+        out = real(*args)
+        drafts.append(all(a.is_deleted() for a in handed))
+        return out
+
+    eng._draft = watched
+    while eng.active:
+        target, n = eng._ks + eng._vs, eng.metrics.get("steps")
+        eng._step()
+        assert all(a.is_deleted() for a in target) \
+            == (eng.metrics.get("steps") > n)
+        live = eng._ks + eng._vs + eng._dks + eng._dvs
+        assert not any(a.is_deleted() for a in live)
+    want, _ = _drive(_engine(gpt), _prompt(41, 7), max_new=8)
+    np.testing.assert_array_equal(fut.result(5), want)
+    assert drafts and all(drafts)
+    assert eng.metrics.get("pool_inplace_steps") == \
+        eng.metrics.get("steps") > 0
+
+
+def test_draft_call_that_raises_after_dispatch_degrades_the_round(gpt):
+    """A draft micro-step that raises once it was handed the draft
+    pools took them with it: the round degrades to plain decode, the
+    draft cache starts over on empty pools and catches up, and the
+    answer is still bitwise plain greedy's."""
+    plain = _engine(gpt)
+    spec = _engine(gpt, spec_len=3)
+    p = _prompt(43, 9)
+    want, _ = _drive(plain, p, max_new=10)
+    real, calls = spec._draft, []
+
+    def broken(*args):
+        out = real(*args)
+        calls.append(1)
+        if len(calls) == 3:
+            raise RuntimeError("device fell over")
+        return out
+
+    spec._draft = broken
+    got, _ = _drive(spec, p, max_new=10)
+    np.testing.assert_array_equal(got, want)
+    assert spec.metrics.get("spec_draft_faults") == 1
+    assert spec.metrics.get("step_errors") == 0
+    assert not any(a.is_deleted() for a in spec._dks + spec._dvs)
+    # drafting went on after the fault, on the rebuilt pools
+    assert len(calls) > 3
+    assert spec.metrics.snapshot()["speculative"]["acceptance_rate"] == 1.0
 
 
 # ---------------------------------------------------------------------------

@@ -530,6 +530,9 @@ def test_slot_engine_on_chip():
     assert eng.compile_counts == {"decode": 1, "cow": 1}
     assert eng.metrics.get("failed") == 0
     assert eng.metrics.get("prefix_hit_tokens") > 0
+    # the chip updates the donated pools in place, every step
+    assert eng.metrics.get("pool_inplace_steps") == \
+        eng.metrics.get("steps") > 0
 
 
 def test_resnet50_batch128_train_step_on_chip():
