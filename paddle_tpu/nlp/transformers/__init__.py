@@ -7,3 +7,7 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTDecoderLayer, GPTForPretraining, GPTModel,
     GPTPretrainingCriterion, gpt_config,
 )
+from .latent_moe import (  # noqa: F401
+    HeldExperts, LatentAttention, LatentMoEConfig, LatentMoEDecoderLayer,
+    LatentMoEForCausalLM, LatentMoEModel,
+)

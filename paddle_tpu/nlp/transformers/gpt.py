@@ -455,6 +455,36 @@ class GPTForPretraining(nn.Layer):
             if was_training:
                 self.train()
 
+    # -- the serving seam (serving.SlotEngine) --------------------------------
+
+    def cache_layout(self):
+        """K and V rows ``[nh, hd]`` a token a layer, blocks token-major
+        (`paging.BLOCK_ROW_ORDER`); the head axis may shard over mp."""
+        from ...serving.paging import BLOCK_ROW_ORDER, CacheLayout
+
+        cfg = self.config
+        row = (cfg.num_heads, cfg.hidden_size // cfg.num_heads)
+        return CacheLayout(BLOCK_ROW_ORDER, (("k", row), ("v", row)),
+                           cfg.num_layers, head_axis=2)
+
+    def paged_forward(self, tok, pos, nvalid, tables, pools):
+        """One serving step: `tok` ``[slots, chunk]``, slot `b`'s
+        columns at positions ``pos[b] + column``; `pools` one ``(k, v)``
+        a layer, scattered into and attended through `tables`
+        (`GPTAttention._attend_paged`). Returns ``(hidden, pools,
+        aux)``; this block has nothing to count, so aux is empty."""
+        import jax.numpy as jnp
+
+        # clamp padding rows' position ids into the embedding table;
+        # their KV writes route to the null block regardless
+        posmat = jnp.minimum(pos[:, None] + jnp.arange(tok.shape[1]),
+                             self.config.max_seq_len - 1)
+        caches = [(k, v, (pos, tables)) for k, v in pools]
+        h, new_caches = self.gpt(Tensor(tok), Tensor(posmat),
+                                 caches=caches)
+        hv = h._value if isinstance(h, Tensor) else h
+        return hv, [(c[0], c[1]) for c in new_caches], {}
+
     def logits(self, h):
         from ...core.dispatch import apply
 

@@ -45,6 +45,7 @@ from .layer.rnn import (  # noqa: F401
     SimpleRNNCell,
 )
 from .layer.decode import BeamSearchDecoder, dynamic_decode  # noqa: F401
+from .layer.rotary import RotaryEmbedding, SwiGLU  # noqa: F401
 from .layer.transformer import (  # noqa: F401
     MultiHeadAttention, Transformer, TransformerDecoder,
     TransformerDecoderLayer, TransformerEncoder, TransformerEncoderLayer,

@@ -47,9 +47,14 @@ def _auto_prefix(layer):
 
 
 class Layer:
-    def __init__(self, name_scope=None, dtype="float32"):
+    def __init__(self, name_scope=None, dtype=None):
+        from ...core.config import get_default_dtype
+
         self.training = True
-        self._dtype = dtype
+        # parameters follow `paddle.set_default_dtype` (float32 unless
+        # set), as the reference's LayerHelper does: a model built
+        # under bfloat16 draws its weights in bfloat16, once
+        self._dtype = dtype or get_default_dtype()
         self._full_name = name_scope or self.__class__.__name__.lower()
         self._parameters: "OrderedDict[str, Parameter]" = OrderedDict()
         self._sub_layers: "OrderedDict[str, Layer]" = OrderedDict()

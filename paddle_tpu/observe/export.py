@@ -573,6 +573,11 @@ def prometheus_text(serving=None, queue_depth=None, fleet=None):
                   spec["dequant_path"],
                   help_="1 while the engine serves int8-frozen weights "
                         "through the dequant epilogue path")
+        # what the served model holds (set once, at engine build)
+        for k, v in sorted(snap.get("model", {}).items()):
+            L.add(f"paddle_serving_model_{k}", v,
+                  help_="served model: cache bytes a token, weight "
+                        "bytes on the device, routed experts held")
         # mesh-sharded serving: shape-labelled gauges + KV-migration
         # counters + the disaggregation role gauge
         mesh = snap.get("mesh")

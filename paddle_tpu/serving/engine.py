@@ -4,17 +4,23 @@ Orca-style iteration-level scheduling (PAPERS.md: continuous batching)
 mapped onto XLA's compile-per-shape reality, with vLLM-style paged KV
 allocation and SGLang-style prefix sharing:
 
-- ONE physical block pool per layer, shape
-  ``[num_blocks, block_size, nh, hd]`` (token-major, the order the
-  step writes and reads it in), plus a per-slot block table
-  ``[max_slots, blocks_per_slot]``. A request holds only the blocks its
-  actual length needs, so pool HBM caps *total tokens in flight*, not
+- The MODEL states what a block holds (`model.cache_layout()`, a
+  `paging.CacheLayout`): dense attention K and V pools of
+  ``[num_blocks, block_size, nh, hd]`` a layer (token-major, the order
+  the step writes and reads them in), latent attention one pool of
+  ``[num_blocks, block_size, width]``. The model also owns the scatter
+  through the block table and the attention over it
+  (`model.paged_forward`); the engine allocates, donates, copies and
+  recovers "a layer's pools" whatever their number and rank, and keeps
+  a per-slot block table ``[max_slots, blocks_per_slot]``. A request
+  holds only the blocks its actual length needs, so pool HBM caps
+  *total tokens in flight*, not
   ``max_slots * max_seq`` — short requests no longer pay for long ones
   and concurrency scales with the pool, not the worst case.
 - The pools are DONATED to every program that returns them (the step,
   the CoW copy, the draft micro-step) and updated in place: the arrays
-  handed in are dead after the call and `_ks` / `_vs` are rebound to
-  its outputs under `_pool_lock`. Everything else that reads or
+  handed in are dead after the call and `_pools` is rebound to its
+  outputs under `_pool_lock`. Everything else that reads or
   rebinds a pool runs on the loop's thread between steps, or takes
   that lock (`export_prefix_blocks`). The counter `pool_inplace_steps`
   counts the steps whose pools really went in place; it equals `steps`.
@@ -218,9 +224,16 @@ class _Slot:
 
 
 class SlotEngine:
-    """Continuous-batching greedy/sampling decode over a GPT model.
+    """Continuous-batching greedy/sampling decode over a causal LM.
 
-    `model` is a `GPTForPretraining` (eval mode is forced). Requests
+    `model` is any layer that offers the serving seam (eval mode is
+    forced): `config` (`vocab_size`, `hidden_size`, `max_seq_len`),
+    `cache_layout()` (what a block of a layer holds),
+    `paged_forward(tok, pos, nvalid, tables, pools) -> (hidden, pools,
+    aux)` (one step over the paged pools; `aux` a dict of int arrays
+    the step counts, summed into `aux_totals` and counters of the same
+    names), `logits(hidden)`, and optionally `serving_gauges()`.
+    `GPTForPretraining` and `LatentMoEForCausalLM` do. Requests
     carry `max_new_tokens`, optional `eos_token_id`, and sampling
     params; results are the full [prompt + generated] int32 id array,
     token-identical to `generate()` / full re-forwarding for greedy.
@@ -369,16 +382,14 @@ class SlotEngine:
         else:
             self._lora_a = None
             self._lora_b = None
-        hd = cfg.hidden_size // cfg.num_heads
+        # the model says what a block holds; the engine carries it
+        self._layout = model.cache_layout()
         self._pool_dtype = cache_dtype or jnp.float32
-        self._pool_shape = (self.num_blocks, self.block_size,
-                            cfg.num_heads, hd)
         # held from a donating dispatch to the rebind of its outputs
         # (the arrays in between are deleted), and by a foreign
         # thread's gather (`export_prefix_blocks`)
         self._pool_lock = threading.Lock()
-        self._ks, self._vs = self._zero_pools(self._pool_shape,
-                                              cfg.num_layers)
+        self._pools = self._zero_pools(self._layout)
         if self._plan is not None:
             # weights by partition rule, KV pools over the head axis
             # (replicated when heads don't divide mp); block tables and
@@ -386,8 +397,17 @@ class SlotEngine:
             self._values = self._plan.place_values(self._values)
             self.metrics.set_gauge("mesh_devices", float(self.mesh.size))
             self.metrics.note_mesh(self.mesh_spec, int(self.mesh.size))
-        self.kv_pool_bytes = self._pool_bytes(self._pool_shape,
-                                              cfg.num_layers)
+        self.kv_pool_bytes = self._pool_bytes(self._layout)
+        # sums of what the model's step counts (`aux`), by name
+        self.aux_totals: dict = {}
+        itemsize = jnp.dtype(self._pool_dtype).itemsize
+        self.metrics.set_gauge("kv_bytes_per_token",
+                               self._layout.bytes_per_token(itemsize))
+        self.metrics.set_gauge("weight_bytes", sum(
+            int(getattr(v, "nbytes", 0)) for v in self._values.values()))
+        for gauge, value in getattr(model, "serving_gauges",
+                                    dict)().items():
+            self.metrics.set_gauge(gauge, value)
         self._alloc = BlockAllocator(self.num_blocks)
         if prefix_cache is None:
             prefix_cache = flag("FLAGS_serving_prefix_cache")
@@ -400,6 +420,13 @@ class SlotEngine:
             spill_dir, metrics=self.metrics) \
             if self._cache is not None else None
         if self.spill_store is not None:
+            if self._layout.row_order != BLOCK_ROW_ORDER:
+                # a spill record is K and V rows of [block_size, nh,
+                # hd]: any other block is refused, never written as one
+                raise ValueError(
+                    f"the KV spill tier stores {BLOCK_ROW_ORDER!r} "
+                    f"blocks; this model's cache layout is "
+                    f"{self._layout.row_order!r}")
             self._cache.spill_hook = self._spill_block
         # per-engine prefix stats (the shared ServingMetrics registry
         # aggregates fleet-wide; per-replica hit rates need local ones)
@@ -468,19 +495,13 @@ class SlotEngine:
             out = out._value if isinstance(out, Tensor) else out
             return (out[:, 0, :] if squeeze else out).astype(jnp.float32)
 
-        def serving_step(values, tok, pos, nvalid, tables, ks, vs,
+        def serving_step(values, tok, pos, nvalid, tables, pools,
                          act_scale=None, aid=None, la=None, lb=None):
             # trace-time only: the compile counter + retrace registry
             _count("decode")
             observe.record_compile(
                 "serving.step",
                 signature=observe.signature_of(tok, pos, tables))
-            caches = [(k, v, (pos, tables)) for k, v in zip(ks, vs)]
-            # clamp padding rows' position ids into the embedding table;
-            # their KV writes route to the null block regardless
-            posmat = jnp.minimum(
-                pos[:, None] + jnp.arange(tok.shape[1]),
-                self.max_seq_len - 1)
             # int8-frozen weights dequantize IN-trace (one canonical
             # formula; XLA fuses it into operand reads) — except the
             # head, which _head routes through the epilogue kernel
@@ -488,12 +509,13 @@ class SlotEngine:
                 else values
 
             def run(m):
-                h, new_caches = m.gpt(Tensor(tok), Tensor(posmat),
-                                      caches=caches)
-                hv = h._value if isinstance(h, Tensor) else h
+                hv, new_pools, aux = m.paged_forward(tok, pos, nvalid,
+                                                     tables, pools)
                 # only each slot's last valid position feeds sampling:
                 # skip the full-vocab projection of the rest of the chunk
-                last = hv[jnp.arange(hv.shape[0]), nvalid - 1]
+                # (an idle slot has no valid column; its row is unread)
+                last = hv[jnp.arange(hv.shape[0]),
+                          jnp.maximum(nvalid - 1, 0)]
                 lv = _head(m, values, last, act_scale)
                 # w8a8 calibration: this step's head-input abs-max
                 # rides the outputs so the host can fold it into the
@@ -517,22 +539,19 @@ class SlotEngine:
                     if la is not None:
                         sv = sv + lora_logits_delta(
                             hv[:, :self.spec_len + 1], aid, la, lb)
-                    return (lv, sv, amax), new_caches
-                return (lv, lv, amax), new_caches
+                    return (lv, sv, amax, aux), new_pools
+                return (lv, lv, amax, aux), new_pools
 
-            (lv, sv, amax), new_caches = functional_apply(
+            (lv, sv, amax, aux), new_pools = functional_apply(
                 self.model, fvals, run, mesh=self.mesh)
-            out_ks = [c[0] for c in new_caches]
-            out_vs = [c[1] for c in new_caches]
-            if self.spec_len:
-                if act_scale is not None:
-                    return lv, sv, amax, out_ks, out_vs
-                return lv, sv, out_ks, out_vs
+            # what sampling reads, then what the model counted, then
+            # the pools: (lv[, sv][, amax], aux, pools)
+            heads = (lv, sv) if self.spec_len else (lv,)
             if act_scale is not None:
-                return lv, amax, out_ks, out_vs
-            return lv, out_ks, out_vs
+                heads += (amax,)
+            return (*heads, aux, new_pools)
 
-        def serving_cow(ks, vs, src, dst):
+        def serving_cow(pools, src, dst):
             from jax import lax
 
             _count("cow")
@@ -543,7 +562,7 @@ class SlotEngine:
                 return lax.dynamic_update_slice_in_dim(pool, blk, dst,
                                                        axis=0)
 
-            return [copy(k) for k in ks], [copy(v) for v in vs]
+            return jax.tree_util.tree_map(copy, pools)
 
         if self._plan is not None:
             # explicit in/out shardings: host-staged step inputs are
@@ -552,16 +571,14 @@ class SlotEngine:
             # freedom to reshard the hot loop between steps)
             rep = self._plan.replicated()
             vsh = self._plan.values_shardings(self._values)
-            pools = [self._plan.pool_sharding(cfg.num_heads)] \
-                * cfg.num_layers
+            pools = self._pool_shardings(self._layout)
+            # heads (logits[, verify logits][, abs-max]), aux, pools
+            n_heads = 1 + bool(self.spec_len) + bool(self.w8a8)
+            step_out = (rep,) * n_heads + (rep, pools)
             if self.w8a8:
-                step_out = (rep, rep, rep, pools, pools) if self.spec_len \
-                    else (rep, rep, pools, pools)
-                step_in = (vsh, rep, rep, rep, rep, pools, pools, rep)
+                step_in = (vsh, rep, rep, rep, rep, pools, rep)
             else:
-                step_out = (rep, rep, pools, pools) if self.spec_len \
-                    else (rep, pools, pools)
-                step_in = (vsh, rep, rep, rep, rep, pools, pools)
+                step_in = (vsh, rep, rep, rep, rep, pools)
                 if self.max_adapters:
                     # explicit act_scale=None slot (an empty pytree:
                     # the leaf sharding applies to zero leaves)
@@ -573,15 +590,15 @@ class SlotEngine:
                 serving_step,
                 in_shardings=step_in,
                 out_shardings=step_out,
-                donate_argnums=(5, 6))
+                donate_argnums=(5,))
             self._cow = jax.jit(
                 serving_cow,
-                in_shardings=(pools, pools, rep, rep),
-                out_shardings=(pools, pools),
-                donate_argnums=(0, 1))
+                in_shardings=(pools, rep, rep),
+                out_shardings=pools,
+                donate_argnums=(0,))
         else:
-            self._decode = jax.jit(serving_step, donate_argnums=(5, 6))
-            self._cow = jax.jit(serving_cow, donate_argnums=(0, 1))
+            self._decode = jax.jit(serving_step, donate_argnums=(5,))
+            self._cow = jax.jit(serving_cow, donate_argnums=(0,))
 
         # -- speculative draft trace (only when spec is on: a disabled
         # engine keeps compile counters {decode: 1, cow: 1} exactly) --
@@ -607,39 +624,29 @@ class SlotEngine:
                 self._dvalues = {
                     k: v for k, v in self._dequantize_state(
                         self._dvalues).items()}
-            dhd = dcfg.hidden_size // dcfg.num_heads
-            self._dpool_shape = (self.num_blocks, self.block_size,
-                                 dcfg.num_heads, dhd)
-            self._dks, self._dvs = self._zero_pools(
-                self._dpool_shape, dcfg.num_layers, place=False)
-            self.kv_pool_bytes += self._pool_bytes(self._dpool_shape,
-                                                   dcfg.num_layers)
+            self._dlayout = self.draft_model.cache_layout()
+            self._dpools = self._zero_pools(self._dlayout, place=False)
+            self.kv_pool_bytes += self._pool_bytes(self._dlayout)
             self._draft_chunk = self.spec_len + 1
 
-            def serving_draft(dvalues, tok, pos, nvalid, tables, ks, vs):
+            def serving_draft(dvalues, tok, pos, nvalid, tables, pools):
                 _count("draft")
                 observe.record_compile(
                     "serving.draft",
                     signature=observe.signature_of(tok, pos, tables))
-                caches = [(k, v, (pos, tables)) for k, v in zip(ks, vs)]
-                posmat = jnp.minimum(
-                    pos[:, None] + jnp.arange(tok.shape[1]),
-                    self.max_seq_len - 1)
 
                 def run(m):
-                    h, new_caches = m.gpt(Tensor(tok), Tensor(posmat),
-                                          caches=caches)
-                    hv = h._value if isinstance(h, Tensor) else h
+                    hv, new_pools, _aux = m.paged_forward(
+                        tok, pos, nvalid, tables, pools)
                     last = hv[jnp.arange(hv.shape[0]), nvalid - 1]
-                    return m.logits(Tensor(last[:, None, :])), new_caches
+                    return m.logits(Tensor(last[:, None, :])), new_pools
 
-                logits, new_caches = functional_apply(
+                logits, new_pools = functional_apply(
                     self.draft_model, dvalues, run)
                 lv = jnp.asarray(logits)[:, 0, :].astype(jnp.float32)
-                return (lv, [c[0] for c in new_caches],
-                        [c[1] for c in new_caches])
+                return lv, new_pools
 
-            self._draft = jax.jit(serving_draft, donate_argnums=(5, 6))
+            self._draft = jax.jit(serving_draft, donate_argnums=(5,))
 
     # -- introspection ------------------------------------------------------
 
@@ -655,18 +662,17 @@ class SlotEngine:
 
     def mesh_info(self):
         """Mesh introspection for fleet snapshots: canonical spec label,
-        device count, and whether the KV pool is actually head-sharded
-        (heads % mp == 0) or silently replicated."""
+        device count, and whether the pools are actually sharded over
+        the head axis the model's layout names (it has one, and it
+        divides mp) or replicated."""
         if self.mesh is None:
             return {"spec": "", "devices": 1, "kv_sharded": False}
-        from ..distributed.topology import MP_AXIS
-
-        mp = dict(self.mesh.shape).get(MP_AXIS, 1)
         return {
             "spec": self.mesh_spec,
             "devices": int(self.mesh.size),
-            "kv_sharded": bool(
-                mp > 1 and self.model.config.num_heads % mp == 0),
+            "kv_sharded": any(
+                not sh.is_fully_replicated
+                for sh in self._pool_shardings(self._layout)[0]),
         }
 
     @property
@@ -691,30 +697,42 @@ class SlotEngine:
 
     # -- the pools ----------------------------------------------------------
 
-    def _zero_pools(self, shape, n_layers, place=True):
-        """Fresh zeroed K and V pools, one pair a layer: at
-        construction, and again when a program that was handed the
-        pools raised and took them with it (`_recover_pools`)."""
+    def _pool_shardings(self, layout):
+        """One tuple of the layout's arrays' shardings a layer."""
+        shapes = layout.pool_shapes(self.num_blocks, self.block_size)
+        return [tuple(self._plan.pool_sharding(layout, shape)
+                      for shape in shapes)] * layout.layers
+
+    def _zero_pools(self, layout, place=True):
+        """Fresh zeroed pools, ``[(array, ...), ...]``: one tuple of the
+        layout's arrays a layer. At construction, and again when a
+        program that was handed the pools raised and took them with it
+        (`_recover_pools`)."""
         import jax
         import jax.numpy as jnp
 
-        sharding = self._plan.pool_sharding(shape[2]) \
-            if place and self._plan is not None else None
+        shapes = layout.pool_shapes(self.num_blocks, self.block_size)
+        shardings = self._pool_shardings(layout)[0] \
+            if place and self._plan is not None else [None] * len(shapes)
 
-        def pools():
-            zeros = [jnp.zeros(shape, self._pool_dtype)
-                     for _ in range(n_layers)]
-            if sharding is None:
-                return zeros
-            return [jax.device_put(z, sharding) for z in zeros]
+        def pool(shape, sharding):
+            zeros = jnp.zeros(shape, self._pool_dtype)
+            return zeros if sharding is None \
+                else jax.device_put(zeros, sharding)
 
-        return pools(), pools()
+        return [tuple(pool(sh, sd) for sh, sd in zip(shapes, shardings))
+                for _ in range(layout.layers)]
 
-    def _pool_bytes(self, shape, n_layers):
+    def _pool_bytes(self, layout):
         import jax.numpy as jnp
 
-        return int(2 * n_layers * np.prod(shape)
-                   * jnp.dtype(self._pool_dtype).itemsize)
+        return self.num_blocks * self.block_size * layout.bytes_per_token(
+            jnp.dtype(self._pool_dtype).itemsize)
+
+    @staticmethod
+    def _arrays(pools):
+        """Every array of ``[(array, ...), ...]``, layer-major."""
+        return [a for layer in pools for a in layer]
 
     @staticmethod
     def _lost(pools):
@@ -735,14 +753,13 @@ class SlotEngine:
         with `error`, the prefix index is dropped without spilling (the
         rows it names no longer exist) and the engine goes on with empty
         pools."""
-        if not self._lost(self._ks + self._vs):
+        if not self._lost(self._arrays(self._pools)):
             return
         self._fail_all_active(error)
         if self._cache is not None:
             self._cache.clear(spill=False)
         with self._pool_lock:
-            self._ks, self._vs = self._zero_pools(
-                self._pool_shape, self.model.config.num_layers)
+            self._pools = self._zero_pools(self._layout)
         self.metrics.inc("pool_rebuilds")
 
     def _recover_draft_pools(self):
@@ -750,11 +767,9 @@ class SlotEngine:
         raised after its dispatch leaves empty draft pools, and every
         slot's draft cache starts over (`dfill` 0): the next round's
         catch-up rewrites it, as after any degraded round."""
-        if not self._lost(self._dks + self._dvs):
+        if not self._lost(self._arrays(self._dpools)):
             return
-        self._dks, self._dvs = self._zero_pools(
-            self._dpool_shape, self.draft_model.config.num_layers,
-            place=False)
+        self._dpools = self._zero_pools(self._dlayout, place=False)
         for slot in self._slots:
             if slot is not None:
                 slot.dfill, slot.fed = 0, []
@@ -796,9 +811,10 @@ class SlotEngine:
         call site (warmup, plain step, speculative verify) builds its
         positional list here, so jax.jit sees exactly one signature per
         engine configuration — the compile-once invariant survives any
-        mix of the w8a8 and adapter options. Rebinds `_ks` / `_vs` to
-        the step's outputs and returns the rest of them: the logits,
-        the verify logits of a speculative engine, w8a8's abs-max."""
+        mix of the w8a8 and adapter options. Rebinds `_pools` to the
+        step's outputs and returns the rest of them: ``(heads, aux)``,
+        heads the logits, then the verify logits of a speculative
+        engine, then w8a8's abs-max; aux what the model's step counted."""
         import jax.numpy as jnp
 
         args = [self._values, jnp.asarray(tok), jnp.asarray(pos),
@@ -813,9 +829,9 @@ class SlotEngine:
                          self._lora_b))
         # the pools are donated: dead from the dispatch to the rebind
         with self._pool_lock:
-            *heads, self._ks, self._vs = self._decode(
-                *args, self._ks, self._vs, *tail)
-        return heads
+            *heads, aux, self._pools = self._decode(
+                *args, self._pools, *tail)
+        return heads, aux
 
     def swap_adapters(self, lora_a, lora_b, version=None, timeout=5.0):
         """Hot-swap the stacked adapter bank (the rollout commit path).
@@ -922,7 +938,7 @@ class SlotEngine:
                             jnp.int32)
             pos = jnp.zeros((self.max_slots,), jnp.int32)
             nvalid = jnp.ones((self.max_slots,), jnp.int32)
-            heads = self._dispatch_decode(tok, pos, nvalid)
+            heads, _aux = self._dispatch_decode(tok, pos, nvalid)
             if self.w8a8:
                 self._absorb_act_amax(heads[-1])
             self._copy_block(NULL_BLOCK, NULL_BLOCK)
@@ -1048,8 +1064,8 @@ class SlotEngine:
         import jax.numpy as jnp
 
         with self._pool_lock:
-            self._ks, self._vs = self._cow(
-                self._ks, self._vs, jnp.int32(src), jnp.int32(dst))
+            self._pools = self._cow(self._pools, jnp.int32(src),
+                                    jnp.int32(dst))
 
     def _admit(self):
         """Join-at-step: fill free slots from the queue while block
@@ -1091,10 +1107,10 @@ class SlotEngine:
     def export_prefix_blocks(self, prompt_ids):
         """Gather this engine's fully-written cached KV blocks covering
         `prompt_ids` into host numpy for migration. Returns a payload
-        dict (tokens / per-layer (k_rows, v_rows) of shape ``[n_blocks,
-        block_size, nh, hd]`` / geometry, the rows' axis order
-        included) or None when nothing is cached. The matched blocks
-        are pinned (incref) for the duration of the gather so a
+        dict (tokens / per-layer tuples of the layout's arrays' rows,
+        ``[n_blocks, block_size, *row]`` each / geometry, the rows'
+        axis order included) or None when nothing is cached. The
+        matched blocks are pinned (incref) for the duration of the gather so a
         concurrent reclaim cannot recycle them mid-copy; block tables
         were host-side all along, so only block payload bytes leave the
         engine.
@@ -1121,9 +1137,10 @@ class SlotEngine:
             # them before any later step's write
             idx = np.asarray(shared, np.int64)
             with self._pool_lock:
-                rows = [(k[idx], v[idx])
-                        for k, v in zip(self._ks, self._vs)]
-            layers = [(np.asarray(k), np.asarray(v)) for k, v in rows]
+                rows = [tuple(a[idx] for a in layer)
+                        for layer in self._pools]
+            layers = [tuple(np.asarray(a) for a in layer)
+                      for layer in rows]
         finally:
             for bid in shared:
                 self._alloc.decref(bid)
@@ -1131,7 +1148,7 @@ class SlotEngine:
             "tokens": [int(t) for t in ids[:n_shared]],
             "n_tokens": int(n_shared),
             "block_size": self.block_size,
-            "row_order": BLOCK_ROW_ORDER,
+            "row_order": self._layout.row_order,
             "layers": layers,
         }
 
@@ -1177,16 +1194,18 @@ class SlotEngine:
             return 0
         if payload.get("block_size") != self.block_size:
             return 0
-        if payload.get("row_order") != BLOCK_ROW_ORDER:
-            # head-major rows (an engine from before the pool went
-            # token-major): with nh == block_size the shapes agree and
-            # only this says the block is transposed
+        if payload.get("row_order") != self._layout.row_order:
+            # another model's kind of block, or head-major rows (an
+            # engine from before the pool went token-major): with nh ==
+            # block_size the shapes agree and only this says the block
+            # is transposed
             return 0
         layers = payload["layers"]
-        if len(layers) != len(self._ks):
+        if len(layers) != len(self._pools):
             return 0
         nb = int(layers[0][0].shape[0]) if layers else 0
-        if nb == 0 or layers[0][0].shape[1:] != self._ks[0].shape[1:]:
+        if nb == 0 or [a.shape[1:] for a in layers[0]] \
+                != [a.shape[1:] for a in self._pools[0]]:
             return 0
         if self._alloc.free_blocks < nb and self._cache is not None:
             self._cache.reclaim(nb - self._alloc.free_blocks)
@@ -1196,9 +1215,7 @@ class SlotEngine:
                 faults.fault_point("serving.kv_migrate", tag=self.name)
                 taken.append(self._alloc.alloc())
             idx = np.asarray(taken, np.int64)
-            for li, (krows, vrows) in enumerate(layers):
-                self._ks[li] = self._ks[li].at[idx].set(krows)
-                self._vs[li] = self._vs[li].at[idx].set(vrows)
+            self._write_blocks(idx, layers)
             n_tokens = nb * self.block_size
             self._cache.insert(payload["tokens"][:n_tokens], taken,
                                n_tokens)
@@ -1212,6 +1229,14 @@ class SlotEngine:
         for bid in taken:
             self._alloc.decref(bid)
         return nb * self.block_size
+
+    def _write_blocks(self, idx, layers):
+        """Write whole blocks' rows (one tuple of arrays a layer, as
+        exported or spilled) at block ids `idx`; on the loop's thread,
+        between steps."""
+        self._pools = [
+            tuple(a.at[idx].set(rows) for a, rows in zip(layer, new))
+            for layer, new in zip(self._pools, layers)]
 
     # -- persistent KV spill tier (ISSUE 18) --------------------------------
 
@@ -1228,8 +1253,8 @@ class SlotEngine:
             # with no loop running): no dispatch is in flight, so the
             # pools are whole; the block is still cache-referenced, so
             # its rows cannot be recycled before the hook returns
-            layers = [(np.asarray(k[bid]), np.asarray(v[bid]))
-                      for k, v in zip(self._ks, self._vs)]
+            layers = [tuple(np.asarray(a[bid]) for a in layer)
+                      for layer in self._pools]
             self.spill_store.append(key, self.weight_version, tokens,
                                     layers)
         except Exception:  # noqa: BLE001 — durability is best-effort
@@ -1278,8 +1303,9 @@ class SlotEngine:
                 break
             if (rec["generation"] != self.weight_version
                     or rec["block_size"] != bs
-                    or len(rec["layers"]) != len(self._ks)
-                    or rec["layers"][0][0].shape != self._ks[0].shape[1:]
+                    or len(rec["layers"]) != len(self._pools)
+                    or rec["layers"][0][0].shape
+                    != self._pools[0][0].shape[1:]
                     or not np.array_equal(rec["tokens"], ids[:m + bs])):
                 break
             recs.append(rec)
@@ -1294,11 +1320,10 @@ class SlotEngine:
                 faults.fault_point("serving.kv_restore", tag=self.name)
                 bids.append(self._alloc.alloc())
             idx = np.asarray(bids, np.int64)
-            for li in range(len(self._ks)):
-                krows = np.stack([r["layers"][li][0] for r in recs])
-                vrows = np.stack([r["layers"][li][1] for r in recs])
-                self._ks[li] = self._ks[li].at[idx].set(krows)
-                self._vs[li] = self._vs[li].at[idx].set(vrows)
+            self._write_blocks(idx, [
+                tuple(np.stack([r["layers"][li][ai] for r in recs])
+                      for ai in range(len(layer)))
+                for li, layer in enumerate(self._pools)])
             for bid in bids:
                 chain.append(bid)
                 cache.insert(ids[:n + bs], chain, n + bs)
@@ -1452,7 +1477,8 @@ class SlotEngine:
             return
         now = time.monotonic()
         tok = np.zeros((self.max_slots, self.prefill_chunk), np.int32)
-        nvalid = np.ones((self.max_slots,), np.int32)
+        # an idle slot has no valid column
+        nvalid = np.zeros((self.max_slots,), np.int32)
         live: list = []
         with observe.phase("sample", cat="serving"):
             prefill_tokens = self._consume_slots(now, tok, nvalid, live)
@@ -1460,10 +1486,11 @@ class SlotEngine:
             return
         n_pref = sum(1 for i in live
                      if self._slots[i].state == "prefill")
-        (logits,), t0, done = self._device_step(tok, nvalid)
+        (logits,), aux, t0, done = self._device_step(tok, nvalid)
         with observe.phase("commit", cat="serving"):
             self._observe_step_latency(done - t0, prefill_tokens,
                                        len(live) - n_pref)
+            self._count_computed(live, nvalid, aux)
             for i in live:
                 slot = self._slots[i]
                 self._pos[i] += slot.advance
@@ -1484,31 +1511,37 @@ class SlotEngine:
         logits; a speculative engine's verify logits after them) and
         the clock before the dispatch and after the read-back.
 
+        What the model's step counted (`aux`) comes back in the same
+        read-back as the logits: one blocking transfer.
+
         The step is handed the pools and updates them in place: the
-        lists that went in read `is_deleted()` afterwards, which
+        arrays that went in read `is_deleted()` afterwards, which
         `pool_inplace_steps` counts. A backend that copied instead
         leaves them alive and the counter behind `steps`."""
-        ks, vs = self._ks, self._vs
+        import jax
+
+        pools = self._pools
         t0 = time.monotonic()
         try:
             with profiler.RecordEvent("serving.step", cat="serving"):
                 with observe.phase("dispatch", cat="serving"):
-                    heads = self._dispatch_decode(tok, self._pos, nvalid)
+                    heads, aux = self._dispatch_decode(tok, self._pos,
+                                                       nvalid)
                     if self.w8a8:
                         self._absorb_act_amax(heads.pop())
                 with observe.phase("readback", cat="serving"):
-                    heads = [np.asarray(h) for h in heads]
+                    heads, aux = jax.device_get((heads, aux))
         except Exception:
-            if self._ks is not ks:
+            if self._pools is not pools:
                 # dispatched, and its logits cannot be read: what it
                 # left in place of the pools is no KV to serve from
-                for a in self._ks + self._vs:
+                for a in self._arrays(self._pools):
                     a.delete()
             raise
         done = time.monotonic()
-        if all(a.is_deleted() for a in ks + vs):
+        if all(a.is_deleted() for a in self._arrays(pools)):
             self.metrics.inc("pool_inplace_steps")
-        return heads, t0, done
+        return heads, aux, t0, done
 
     def _observe_step_latency(self, dt, prefill_tokens, n_decoding):
         """Attribute one device step, dispatch to the logits on the
@@ -1525,6 +1558,25 @@ class SlotEngine:
             self.metrics.observe_latency("prefill", dt)
         if n_decoding:
             self.metrics.observe_latency("decode", dt)
+
+    def _count_computed(self, live, nvalid, aux):
+        """What this step's REAL columns cost, before the commit moves
+        `_pos`: `computed_tokens` (prompt tokens computed, not hit,
+        plus tokens fed back) and `attn_context_tokens` (the keys each
+        of them attended: its position + 1); padding columns count
+        nothing. And what the model's own step counted (`aux`), into
+        `aux_totals` and a counter of the same name."""
+        computed = context = 0
+        for i in live:
+            n, at = int(nvalid[i]), int(self._pos[i])
+            computed += n
+            context += n * at + n * (n + 1) // 2
+        self.metrics.inc("computed_tokens", computed)
+        self.metrics.inc("attn_context_tokens", context)
+        for name, value in aux.items():
+            value = np.asarray(value, np.int64)
+            self.aux_totals[name] = self.aux_totals.get(name, 0) + value
+            self.metrics.inc(name, int(value.sum()))
 
     def _count_step(self, n_live, prefill_tokens):
         self.metrics.inc("steps")
@@ -1576,6 +1628,7 @@ class SlotEngine:
                 self._evict(i)
                 continue
             tok[i, 0] = nxt
+            nvalid[i] = 1
             slot.advance = 1
             live.append(i)
         return prefill_tokens
@@ -1599,7 +1652,7 @@ class SlotEngine:
             return
         now = time.monotonic()
         tok = np.zeros((self.max_slots, self.prefill_chunk), np.int32)
-        nvalid = np.ones((self.max_slots,), np.int32)
+        nvalid = np.zeros((self.max_slots,), np.int32)
         live: list = []
         plan: list = []   # (slot_idx, slot, next_token, s_i)
         with observe.phase("sample", cat="serving"):
@@ -1631,10 +1684,11 @@ class SlotEngine:
         faults.fault_point("serving.verify")
         n_pref = sum(1 for i in live
                      if self._slots[i].state == "prefill")
-        (lv, sv), t0, done = self._device_step(tok, nvalid)
+        (lv, sv), aux, t0, done = self._device_step(tok, nvalid)
         with observe.phase("commit", cat="serving"):
             self._observe_step_latency(done - t0, prefill_tokens,
                                        len(live) - n_pref)
+            self._count_computed(live, nvalid, aux)
             for i in live:
                 slot = self._slots[i]
                 if slot.state == "prefill":
@@ -1761,10 +1815,9 @@ class SlotEngine:
         thread ever reads them)."""
         import jax.numpy as jnp
 
-        lv, self._dks, self._dvs = self._draft(
+        lv, self._dpools = self._draft(
             self._dvalues, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(nvalid), jnp.asarray(self._bt), self._dks,
-            self._dvs)
+            jnp.asarray(nvalid), jnp.asarray(self._bt), self._dpools)
         return lv
 
     def _draft_pick(self, slot, qrow):

@@ -331,6 +331,13 @@ class ServingMetrics:
                     for s, (d, a) in sorted(spec_slots.items())},
                 "dequant_path": gauges.get("dequant_path", 0.0),
             }
+        model = {k: gauges[k] for k in ("kv_bytes_per_token",
+                                         "weight_bytes", "experts_held")
+                 if k in gauges}
+        if model:
+            # what the served model holds: cache bytes a token over all
+            # layers, weight bytes on the device, routed experts held
+            snap["model"] = model
         with self._lock:
             mesh, role = self._mesh, self._role
         if mesh is not None or role is not None \

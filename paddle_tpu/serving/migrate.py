@@ -7,10 +7,13 @@ tokens. In-process replicas share no device state (each engine owns its
 pool), so migration is an explicit export -> stream -> adopt pipeline:
 
   * export: the prefill engine gathers the prompt's cached prefix
-    blocks from its pool into host numpy (`export_prefix_blocks`),
-    rows ``[n_blocks, block_size, nh, hd]`` with their axis order named
-    in the payload (`paging.BLOCK_ROW_ORDER`): a payload in another
-    order is refused by the adopting engine, never adopted transposed;
+    blocks from its pools into host numpy (`export_prefix_blocks`),
+    one tuple of arrays a layer as the model's cache layout states
+    (K and V rows ``[n_blocks, block_size, nh, hd]``, or latent rows
+    ``[n_blocks, block_size, width]``) with their axis order named in
+    the payload (`CacheLayout.row_order`): a payload in another order
+    is refused by the adopting engine, never adopted transposed or as
+    another kind of block;
   * stream: the payload rides `KVMailbox`, an in-process loopback that
     mirrors the gang-layer ``dist.p2p_*`` mailbox contract exactly —
     `deadline_guard("dist.p2p_send")` before the enqueue and
@@ -80,7 +83,7 @@ class KVMailbox:
 
 
 def payload_bytes(payload):
-    return int(sum(k.nbytes + v.nbytes for k, v in payload["layers"]))
+    return int(sum(a.nbytes for layer in payload["layers"] for a in layer))
 
 
 def migrate_prefix(src_engine, dst_engine, ids, mailbox=None,

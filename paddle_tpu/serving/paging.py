@@ -5,11 +5,13 @@ cache that lets requests sharing a token prefix share physical blocks.
 Design (vLLM PagedAttention + SGLang RadixAttention, collapsed to the
 slot engine's needs):
 
-- The device pool is `[num_blocks, block_size, nh, hd]` per layer
-  (token-major: one block's rows are `[block_size, nh, hd]`,
-  `BLOCK_ROW_ORDER`); every logical sequence position `t` of a slot
-  maps through its block table to physical row
-  `(table[t // bs], t % bs)`, the pool's two leading axes. Block 0 is the
+- The device pools are `[num_blocks, block_size, *row]` arrays, as
+  many a layer and of the row shape the model's `CacheLayout` states
+  (dense attention: K and V with rows `[nh, hd]`, a block's rows
+  `[block_size, nh, hd]`, `BLOCK_ROW_ORDER`; latent attention: one
+  array with rows `[rank + rope_dim]`); every logical sequence
+  position `t` of a slot maps through its block table to physical row
+  `(table[t // bs], t % bs)`, the pools' two leading axes. Block 0 is the
   reserved *null block*: it is never allocated, free slots point every
   table entry at it, and all padding/garbage scatter writes land there
   — so the compiled step can always write `[max_slots, chunk]` rows
@@ -40,8 +42,9 @@ import numpy as np
 
 from ..framework import faults
 
-__all__ = ["BLOCK_ROW_ORDER", "NULL_BLOCK", "PoolExhausted",
-           "BlockAllocator", "PrefixCache", "positions_to_rows"]
+__all__ = ["BLOCK_ROW_ORDER", "NULL_BLOCK", "CacheLayout", "PoolExhausted",
+           "BlockAllocator", "PrefixCache", "positions_to_rows",
+           "stored_width"]
 
 #: physical block 0 — reserved scratch target for padding writes
 NULL_BLOCK = 0
@@ -55,6 +58,51 @@ NULL_BLOCK = 0
 BLOCK_ROW_ORDER = "thd"
 
 _ROOT = b"\x00root"
+
+
+class CacheLayout:
+    """What one block of one layer holds, as the MODEL declares it
+    (`model.cache_layout()`); the engine allocates, donates, copies and
+    recovers the arrays it names without knowing their rank, and the
+    allocator, the prefix cache and chunked prefill work on block ids.
+
+    `arrays` is ``((name, row_shape), ...)``: each array of a layer is a
+    pool ``[num_blocks, block_size, *row_shape]``, so a position's row
+    is always at the two leading axes. Dense multi-head attention keeps
+    ``("k", (nh, hd))`` and ``("v", (nh, hd))``; latent attention one
+    ``("latent", (rank + rope_dim,))`` with no head axis. `row_order`
+    names the axes of one block's rows (``"thd"``, ``"tc"``) wherever
+    they leave a pool: an exported or spilled block in an order its
+    reader does not know is refused by that name. `head_axis` is the
+    pool axis a mesh may shard over its model-parallel degree; None =
+    the pool has no head axis and is replicated."""
+
+    def __init__(self, row_order, arrays, layers, head_axis=None):
+        self.row_order = str(row_order)
+        self.arrays = tuple((str(n), tuple(int(d) for d in shape))
+                            for n, shape in arrays)
+        self.layers = int(layers)
+        self.head_axis = head_axis
+
+    def pool_shapes(self, num_blocks, block_size):
+        """The pools' shapes, one per array of a layer."""
+        return [(int(num_blocks), int(block_size)) + row
+                for _, row in self.arrays]
+
+    def bytes_per_token(self, itemsize):
+        """Cache bytes one token occupies over all layers."""
+        return int(self.layers * itemsize
+                   * sum(int(np.prod(row)) for _, row in self.arrays))
+
+
+def stored_width(columns):
+    """Columns a headless pool keeps for a row of `columns`: rounded up
+    to the 128 lanes of the chip's tiles. A 576-wide row occupies 640
+    lanes of a tile anyway; declared at 576, the TPU compiler avoids
+    that padding by laying the pool out block-index-minor (``{0,2,1}``)
+    and copies the whole pool in and out of every layer's scatter
+    (tests/test_v5e_compile.py)."""
+    return -(-int(columns) // 128) * 128
 
 
 def positions_to_rows(table, positions, block_size):

@@ -16,11 +16,13 @@ hybrid trainer derives from `Parameter.param_spec`:
 The serving mesh is a 2-axis (dp, mp) slice of the training topology
 (`distributed/topology.py` axis names), specified as ``dpD.mpM`` via
 `FLAGS_serving_mesh`. GSPMD pads uneven dimensions (e.g. a vocab of 97
-on mp=4), so no divisibility guard is needed on weights; the paged KV
-pool is sharded over attention heads only when the head count divides
-the mp degree — otherwise it stays replicated and the engine still
-serves (block tables are host-side numpy either way, so they remain
-replica-global; see `ShardingPlan.pool_sharding`).
+on mp=4), so no divisibility guard is needed on weights; the paged
+pools are sharded over the head axis their model's cache layout names,
+and only when the head count divides the mp degree — otherwise (and
+for a layout with no head axis, such as latent rows) they stay
+replicated and the engine still serves (block tables are host-side
+numpy either way, so they remain replica-global; see
+`ShardingPlan.pool_sharding`).
 """
 
 from __future__ import annotations
@@ -128,10 +130,11 @@ class ShardingPlan:
     Weights follow `rules` (default GPT_PARTITION_RULES); a spec naming
     an axis a tensor is too small or too low-rank for degrades to
     replicated rather than failing (GSPMD handles uneven *padding*, but
-    a rank-1 bias cannot take a rank-2 spec). The paged KV pool
-    ``[num_blocks, block_size, num_heads, head_dim]`` shards over the
-    head axis (axis 2) iff ``num_heads % mp == 0``; block tables /
-    allocator stay host-side numpy and therefore replica-global.
+    a rank-1 bias cannot take a rank-2 spec). A paged pool shards
+    over the head axis its layout names (axis 2 of ``[num_blocks,
+    block_size, num_heads, head_dim]``) iff ``num_heads % mp == 0``;
+    block tables / allocator stay host-side numpy and therefore
+    replica-global.
     """
 
     def __init__(self, mesh, rules=GPT_PARTITION_RULES):
@@ -176,10 +179,16 @@ class ShardingPlan:
         sh = self.values_shardings(values)
         return {k: jax.device_put(v, sh[k]) for k, v in values.items()}
 
-    def pool_sharding(self, num_heads):
-        """KV pool sharding: heads over mp when divisible, else
-        replicated (the engine still serves; it just stops saving KV
-        memory — same silent-guard stance as the overlap kernels)."""
-        if self.mp > 1 and num_heads % self.mp == 0:
-            return self._named(P(None, None, MP_AXIS, None))
+    def pool_sharding(self, layout, shape):
+        """Sharding of one pool of `shape` under the model's
+        `paging.CacheLayout`: the head axis the layout names over mp
+        when it divides it; a pool whose layout has no head axis
+        (latent rows) or whose heads do not divide mp is replicated
+        (the engine still serves; it just stops saving cache memory —
+        same silent-guard stance as the overlap kernels)."""
+        axis = layout.head_axis
+        if self.mp > 1 and axis is not None and shape[axis] % self.mp == 0:
+            spec = [None] * len(shape)
+            spec[axis] = MP_AXIS
+            return self._named(P(*spec))
         return self.replicated()

@@ -611,13 +611,13 @@ def test_warmup_leaves_live_pools(gpt):
     live, twice over."""
     e = serving.SlotEngine(gpt, max_slots=2, block_size=8,
                            prefill_chunk=8)
-    assert e._ks[0].shape == (e.num_blocks, 8, 4, 8)
-    built = e._ks + e._vs
+    assert e._pools[0][0].shape == (e.num_blocks, 8, 4, 8)
+    built = e._arrays(e._pools)
     e.warmup()
     assert all(_dead(built))
-    assert not any(_dead(e._ks + e._vs))
+    assert not any(_dead(e._arrays(e._pools)))
     e.warmup()                                # under no_retrace
-    assert not any(_dead(e._ks + e._vs))
+    assert not any(_dead(e._arrays(e._pools)))
     assert e.metrics.get("pool_inplace_steps") == 0   # no step yet
     p = _prompt(73, 6)
     out, _ = _drive(e, p, max_new=3)
@@ -631,20 +631,20 @@ def test_step_and_cow_update_the_pools_in_place(gpt, eng):
     eng.submit(np.asarray(a, np.int32), max_new_tokens=3, timeout=None)
     eng._admit()
     while eng.active:
-        before, n = eng._ks + eng._vs, eng.metrics.get("steps")
+        before, n = eng._arrays(eng._pools), eng.metrics.get("steps")
         eng._step()
         # (the last call only samples the last token: no dispatch)
         assert all(_dead(before)) == (eng.metrics.get("steps") > n)
-        assert not any(_dead(eng._ks + eng._vs))
+        assert not any(_dead(eng._arrays(eng._pools)))
     b = list(a)
     b[11] = 77                                # diverge inside block 2
     fut = eng.submit(np.asarray(b, np.int32), max_new_tokens=3,
                      timeout=None)
-    before = eng._ks + eng._vs
+    before = eng._arrays(eng._pools)
     eng._admit()
     assert eng.metrics.get("cow_splits") == 1
     assert all(_dead(before))
-    assert not any(_dead(eng._ks + eng._vs))
+    assert not any(_dead(eng._arrays(eng._pools)))
     while eng.active:
         eng._step()
     np.testing.assert_array_equal(fut.result(5), _ref_greedy(gpt, b, 3))
@@ -749,7 +749,7 @@ def test_step_that_raises_leaves_a_serving_engine(gpt, when):
         # the warm prompt's blocks were served from the index only
         # where the pools behind it survived
         assert (srv.metrics.get("prefix_hit_blocks") > 0) == (not lost)
-        assert not any(_dead(eng._ks + eng._vs))
+        assert not any(_dead(eng._arrays(eng._pools)))
     finally:
         srv.shutdown(drain=True)
 

@@ -20,6 +20,7 @@ from paddle_tpu.distributed.topology import MP_AXIS
 from paddle_tpu.engine import state_values
 from paddle_tpu.framework import faults
 from paddle_tpu.nlp.transformers import GPTConfig, GPTForPretraining
+from paddle_tpu.serving.paging import CacheLayout
 from paddle_tpu.serving.queueing import VersionRetiredError
 from paddle_tpu.serving.rollout import (
     RolloutController, WeightRegistry, WeightVersion, _digest_ids,
@@ -118,9 +119,15 @@ def test_sharding_plan_fits_and_degrades(gpt):
     # GSPMD only pads internal values)
     assert sh[emb].spec == P(None, None)
     assert sh[fc1].spec == P(None, MP_AXIS)
-    # pool shards over heads iff divisible; block tables stay host-side
-    assert plan.pool_sharding(4).spec == P(None, None, MP_AXIS, None)
-    assert plan.pool_sharding(3).spec == P()
+    # a pool shards over the head axis its layout names iff it divides
+    # mp; a pool with no head axis (latent rows) is replicated; block
+    # tables stay host-side
+    kv = CacheLayout("thd", (("k", (4, 8)), ("v", (4, 8))), 2, head_axis=2)
+    assert plan.pool_sharding(kv, (32, 8, 4, 8)).spec \
+        == P(None, None, MP_AXIS, None)
+    assert plan.pool_sharding(kv, (32, 8, 3, 8)).spec == P()
+    latent = CacheLayout("tc", (("latent", (24,)),), 2)
+    assert plan.pool_sharding(latent, (32, 8, 24)).spec == P()
 
 
 # ---------------------------------------------------------------------------
@@ -164,11 +171,11 @@ def test_meshed_step_keeps_head_sharded_pools_in_place(gpt, spec):
     arrays handed in are gone after it, every step counted."""
     eng = _engine(gpt, mesh=spec, prefix_cache=True)
     want = P(None, None, MP_AXIS, None)
-    assert eng._ks[0].shape == (32, 8, 4, 8)
-    built = eng._ks + eng._vs
+    assert eng._pools[0][0].shape == (32, 8, 4, 8)
+    built = eng._arrays(eng._pools)
     eng.warmup()
     assert all(a.is_deleted() for a in built)
-    assert all(a.sharding.spec == want for a in eng._ks + eng._vs)
+    assert all(a.sharding.spec == want for a in eng._arrays(eng._pools))
     a = np.arange(1, 18, dtype=np.int32)
     b = a.copy()
     b[11] = 77                                # CoW inside block 2
@@ -176,13 +183,13 @@ def test_meshed_step_keeps_head_sharded_pools_in_place(gpt, spec):
         fut = eng.submit(p, max_new_tokens=3)
         eng._admit()
         while eng.active:
-            before, n = eng._ks + eng._vs, eng.metrics.get("steps")
+            before, n = eng._arrays(eng._pools), eng.metrics.get("steps")
             eng._step()
             assert all(x.is_deleted() for x in before) \
                 == (eng.metrics.get("steps") > n)
         fut.result(5)
     assert eng.metrics.get("cow_splits") == 1
-    assert all(x.sharding.spec == want for x in eng._ks + eng._vs)
+    assert all(x.sharding.spec == want for x in eng._arrays(eng._pools))
     assert eng.metrics.get("pool_inplace_steps") == \
         eng.metrics.get("steps") > 0
     assert eng.compile_counts == {"decode": 1, "cow": 1}

@@ -218,8 +218,7 @@ def test_spec_bulk_scatter_writes_same_pool_rows(gpt):
         # final sampled token (never fed back)
         positions = np.arange(p.size + max_new - 1)
         blk, off = positions_to_rows(table, positions, eng.block_size)
-        return [np.asarray(ks)[blk, off] for ks in eng._ks] + \
-               [np.asarray(vs)[blk, off] for vs in eng._vs]
+        return [np.asarray(a)[blk, off] for a in eng._arrays(eng._pools)]
 
     rows_plain = pool_rows(_engine(gpt))
     rows_spec = pool_rows(_engine(gpt, spec_len=3))
@@ -235,9 +234,9 @@ def test_spec_step_and_draft_update_their_pools_in_place(gpt, draft_gpt):
     eng = serving.SlotEngine(gpt, max_slots=2, block_size=8,
                              prefill_chunk=8, spec_len=3,
                              draft_model=draft_gpt)
-    assert eng._ks[0].shape == (eng.num_blocks, 8, 4, 8)
-    assert eng._dks[0].shape == (eng.num_blocks, 8, 2, 8)
-    built = eng._ks + eng._vs + eng._dks + eng._dvs
+    assert eng._pools[0][0].shape == (eng.num_blocks, 8, 4, 8)
+    assert eng._dpools[0][0].shape == (eng.num_blocks, 8, 2, 8)
+    built = eng._arrays(eng._pools) + eng._arrays(eng._dpools)
     eng.warmup()
     assert all(a.is_deleted() for a in built)
     fut = eng.submit(_prompt(41, 7), max_new_tokens=8, timeout=None)
@@ -246,18 +245,18 @@ def test_spec_step_and_draft_update_their_pools_in_place(gpt, draft_gpt):
     real = eng._draft
 
     def watched(*args):
-        handed = list(args[5]) + list(args[6])
+        handed = eng._arrays(args[5])
         out = real(*args)
         drafts.append(all(a.is_deleted() for a in handed))
         return out
 
     eng._draft = watched
     while eng.active:
-        target, n = eng._ks + eng._vs, eng.metrics.get("steps")
+        target, n = eng._arrays(eng._pools), eng.metrics.get("steps")
         eng._step()
         assert all(a.is_deleted() for a in target) \
             == (eng.metrics.get("steps") > n)
-        live = eng._ks + eng._vs + eng._dks + eng._dvs
+        live = eng._arrays(eng._pools) + eng._arrays(eng._dpools)
         assert not any(a.is_deleted() for a in live)
     want, _ = _drive(_engine(gpt), _prompt(41, 7), max_new=8)
     np.testing.assert_array_equal(fut.result(5), want)
@@ -289,7 +288,7 @@ def test_draft_call_that_raises_after_dispatch_degrades_the_round(gpt):
     np.testing.assert_array_equal(got, want)
     assert spec.metrics.get("spec_draft_faults") == 1
     assert spec.metrics.get("step_errors") == 0
-    assert not any(a.is_deleted() for a in spec._dks + spec._dvs)
+    assert not any(a.is_deleted() for a in spec._arrays(spec._dpools))
     # drafting went on after the fault, on the rebuilt pools
     assert len(calls) > 3
     assert spec.metrics.snapshot()["speculative"]["acceptance_rate"] == 1.0

@@ -24,6 +24,9 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.nlp.transformers.gpt import GPTAttention
+from paddle_tpu.nlp.transformers.latent_moe import (
+    latent_attend_paged, latent_scatter,
+)
 
 # gpt3-1.3b behind benchmarks/configs/gpt3-1.3b.json: 16 heads of 128,
 # 16 slots x 16 tokens a step, context 2048 in blocks of 16, a bf16
@@ -127,3 +130,67 @@ def test_serving_kv_path_updates_donated_pools_in_place(one_chip,
     gathers = [i for i in instructions
                if i[2] == (TABLE * SLOTS, BS, NH, HD) and i[3] == "fusion"]
     assert len(gathers) == 2 * LAYERS
+
+
+# sarvam-105b behind benchmarks/configs/sarvam-105b.json: 64 heads over
+# a latent row of 512 + 64 columns stored 640 wide, 8 slots x 64 tokens
+# a step, context 5120 in blocks of 16, a bf16 pool of 16,385 blocks
+L_NB, L_SLOTS, L_CHUNK, L_NH, L_TABLE, L_RANK = 16385, 8, 64, 64, 320, 512
+
+
+def _latent_path(q_cat, rows, pos, tables, pools):
+    """The serving step's latent cache path, two layers of it:
+    `LatentAttention.forward_paged`'s scatter through the table and the
+    absorbed attention loop over the pool, the second layer fed by the
+    first."""
+    t_idx = pos[:, None] + jnp.arange(L_CHUNK)
+    out = []
+    for pool in pools:
+        pool = latent_scatter(pool, rows, tables, t_idx)
+        ctx = latent_attend_paged(q_cat, pool, tables, t_idx, L_RANK, 0.1)
+        q_cat = q_cat.at[..., :L_RANK].add(ctx.astype(q_cat.dtype))
+        out.append(pool)
+    return q_cat, out
+
+
+def _compile_latent_path(width, one_chip):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = spec((L_NB, BS, width), jnp.bfloat16)
+    return jax.jit(_latent_path, donate_argnums=(4,)).lower(
+        spec((L_SLOTS, L_CHUNK, L_NH, width), jnp.bfloat16),
+        spec((L_SLOTS, L_CHUNK, width), jnp.bfloat16),
+        spec((L_SLOTS,), jnp.int32), spec((L_SLOTS, L_TABLE), jnp.int32),
+        [pool] * LAYERS).compile()
+
+
+def _pool_copies(compiled, width):
+    pool_elems = L_NB * BS * width
+    return [(name, dims) for name, _, dims, op
+            in _entry_instructions(compiled.as_text())
+            if (op.startswith("copy") or name.startswith("copy"))
+            and int(np.prod(dims)) == pool_elems]
+
+
+def test_latent_pool_is_updated_in_place_at_its_stored_width(
+        one_chip, no_compile_cache):
+    """The latent pool `[16385, 16, 640]`, donated: scattered into and
+    read tile by tile inside the attention loop with no copy of a whole
+    pool, its bytes aliased, and no `[slots, heads x chunk, context]`
+    score tensor (the loop's tile is 512 keys)."""
+    compiled = _compile_latent_path(640, one_chip)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= LAYERS * L_NB * BS * 640 * 2
+    assert not _pool_copies(compiled, 640)
+    whole_scores = L_SLOTS * L_CHUNK * L_NH * L_TABLE * BS * 4
+    assert memory.temp_size_in_bytes < whole_scores / 4
+
+
+def test_latent_pool_at_its_logical_width_would_be_copied(
+        one_chip, no_compile_cache):
+    """Why the row is stored 640 wide: at 576 (not a multiple of the
+    128 lanes) the compiler lays the pool out block-index-minor and
+    copies all of it in and out of every layer's scatter."""
+    compiled = _compile_latent_path(576, one_chip)
+    assert len(_pool_copies(compiled, 576)) >= LAYERS
