@@ -18,7 +18,10 @@ TPU-native design:
 
 from __future__ import annotations
 
+import functools
 import math
+
+import jax
 
 from ... import nn
 from ...core.config import no_grad
@@ -69,6 +72,69 @@ def gpt_config(name, **overrides):
     cfg = dict(_PRESETS[name])
     cfg.update(overrides)
     return GPTConfig(**cfg)
+
+
+#: key positions one turn of the paged attention loop reads
+KEY_TILE = 256
+
+
+def key_tiling(blocks_per_slot, block_size):
+    """How `GPTAttention._attend_paged` walks a block table: ``(table
+    entries a tile, tiles that cover the table)``."""
+    per_tile = max(min(KEY_TILE // block_size, blocks_per_slot), 1)
+    return per_tile, -(-blocks_per_slot // per_tile)
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _attend_tiles(q, k_pool, v_pool, tables, t_idx, per_tile):
+    """Online-softmax attention of float32 queries ``[b, nh, s, hd]``,
+    column `c` of row `b` at position ``t_idx[b, c]``, over the pools
+    ``[num_blocks, block_size, nh, hd]`` read through `tables` in
+    tiles of `per_tile` entries: ``(out [b, nh, s, hd] float32, turns
+    run)``. Jitted on its own so that a model's layers share one trace
+    of the loop: inside a step's trace it is a call of that trace, not
+    a program of its own."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    b, nh, s_new, hd = q.shape
+    bs, mb = k_pool.shape[1], tables.shape[1]
+    tile = per_tile * bs
+    n_tiles_max = -(-mb // per_tile)
+    # a table that is no whole number of tiles ends in the null block
+    tiled = jnp.pad(tables, ((0, 0), (0, n_tiles_max * per_tile - mb)))
+    f32 = jnp.float32
+    scale = 1.0 / (hd ** 0.5)
+
+    def body(j, carry):
+        m, l, acc = carry
+        blocks = lax.dynamic_slice_in_dim(tiled, j * per_tile, per_tile,
+                                          axis=1)
+        k_tile = k_pool[blocks].reshape(b, tile, nh, hd)
+        v_tile = v_pool[blocks].reshape(b, tile, nh, hd)
+        sc = jnp.einsum("bhqd,bkhd->bhqk", q, k_tile.astype(f32)) * scale
+        k_pos = j * tile + jnp.arange(tile)
+        mask = k_pos[None, None, :] <= t_idx[:, :, None]
+        sc = jnp.where(mask[:, None], sc, -1e30)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(sc - m_new[..., None])
+        l = l * alpha + p.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhqk,bkhd->bhqd", p, v_tile.astype(f32))
+        return m_new, l, acc
+
+    # every column admits key 0, so after the first turn `m` is a real
+    # score and a wholly masked later tile adds exp(-1e30 - m). A column
+    # past the table is padding (its row went to the null block, its
+    # output is unread) and does not lengthen the loop
+    longest = jnp.max(jnp.where(t_idx < mb * bs, t_idx, 0))
+    n_tiles = (longest // tile + 1).astype(jnp.int32)
+    init = (jnp.full((b, nh, s_new), -1e30, f32),
+            jnp.zeros((b, nh, s_new), f32),
+            jnp.zeros((b, nh, s_new, hd), f32))
+    _, l, acc = lax.fori_loop(0, n_tiles, body, init)
+    return acc / l[..., None], n_tiles
 
 
 class GPTAttention(nn.Layer):
@@ -128,12 +194,14 @@ class GPTAttention(nn.Layer):
         pools ``[num_blocks, block_size, nh, hd]`` (token-major: the
         order the step writes and reads them in). Row b's logical
         position t lives at physical row ``(tables[b, t // bs],
-        t % bs)``; new KV scatters through the table and the logical
-        ``[b, max_seq, nh, hd]`` view is gathered back for the scores.
-        Padding rows (positions past the sequence / chunk) are routed
-        to reserved block 0, so the step shape never depends on how
-        many rows are real — the compile-once property survives
-        arbitrary chunked-prefill/decode mixes. The same overwrite-
+        t % bs)``; new KV scatters through the table, and the scores
+        read the pool back tile by tile through it, as far as the
+        batch's longest live row (`_attend_paged`; this function's
+        dense vector-`pos` branch is its reference). Padding rows
+        (positions past the sequence / chunk) are routed to reserved
+        block 0, so the step shape never depends on how many rows are
+        real — the compile-once property survives arbitrary
+        chunked-prefill/decode mixes. The same overwrite-
         before-attend invariant makes block recycling and whole-block
         copy-on-write safe without zeroing."""
         import jax
@@ -182,37 +250,55 @@ class GPTAttention(nn.Layer):
 
     def _attend_paged(self, qv, kv, vv, k_pool, v_pool, pos, tables):
         """Paged variant of the vector-pos branch: scatter the new KV
-        through per-row block tables into the physical pool, gather the
-        logical per-row view back, then the identical per-row causal
-        mask. Out-of-range rows (padding past max_seq) write into the
-        reserved null block 0; table entries past a slot's allocation
-        are 0 too, and both stay unattended because the mask only admits
-        keys <= each row's own position.
+        through per-row block tables into the physical pool, then attend
+        over the pool tile by tile through the table, as far as the
+        batch's longest live row. Out-of-range rows (padding past
+        max_seq) write into the reserved null block 0; table entries
+        past a slot's allocation are 0 too, and both stay unattended
+        because a key is admitted only at a position <= the column's
+        own.
+
+        The read (`_attend_tiles`, one trace shared by every layer) is
+        an online-softmax loop over tiles of `KEY_TILE` positions: turn
+        `j` takes its entries of `tables`, gathers their blocks of both
+        pools to ``[b, tile, nh, hd]``, scores them against the step's
+        queries and carries running max, sum and accumulator in
+        float32. The loop runs ``max(t_idx) // tile
+        + 1`` turns over the columns that lie inside the table, a
+        value of the trace and not a shape (one compiled program
+        whatever the batch holds): no ``[b, max_seq, nh, hd]`` view and
+        no ``[b, nh, chunk, max_seq]`` score tensor exists, and a step
+        costs what its longest row costs. Idle slots sit at position 0
+        (the engine's) or past the table (the draft's) and do not
+        lengthen it. The operands are those of the dense branch
+        (float32 queries and probabilities, cached rows widened to
+        float32), so the result differs from it by the order of float32
+        additions alone.
 
         Speculative decoding rides the same scatter: a verify step
         bulk-writes all k+1 staged columns (next token + proposals) in
         this one dispatch, and a rejected suffix's pool rows are just
-        more garbage-above-the-frontier — masked out by ``key_idx <=
-        t_idx`` now, overwritten by the next round's staging before the
-        coverage frontier reaches them.
+        more garbage-above-the-frontier — masked out by position now,
+        overwritten by the next round's staging before the coverage
+        frontier reaches them.
 
         The pool is ``[num_blocks, block_size, nh, hd]`` so that the
-        scatter indexes its two LEADING axes and the gathered view
-        feeds the contraction by reshape alone: a step that donates the
-        pools then updates them in place. A scatter over axes that are
-        not adjacent makes the TPU compiler relayout the whole pool
-        round it, donated or not, and a view in any other order costs a
-        transposing copy of every slot's whole context
-        (tests/test_v5e_compile.py holds the compiled step to this)."""
-        import jax
+        scatter indexes its two LEADING axes and a gathered tile feeds
+        the contraction by reshape alone: a step that donates the pools
+        then updates them in place. A scatter over axes that are not
+        adjacent makes the TPU compiler relayout the whole pool round
+        it, donated or not (tests/test_v5e_compile.py holds the
+        compiled step to this).
+
+        Returns ``(out, (k_pool, v_pool, (pos + s_new, tables),
+        key_tiles))``, `key_tiles` the int32 count of turns the loop
+        ran."""
         import jax.numpy as jnp
 
-        b, nh = qv.shape[0], qv.shape[1]
-        s_new = qv.shape[2]
+        b, s_new = qv.shape[0], qv.shape[2]
         bs = k_pool.shape[1]
         mb = tables.shape[1]
         s_max = mb * bs
-        hd = k_pool.shape[3]
         row = jnp.arange(b)[:, None]                  # [b, 1]
         t_idx = pos[:, None] + jnp.arange(s_new)      # [b, s_new]
         safe_t = jnp.minimum(t_idx, s_max - 1)
@@ -225,19 +311,12 @@ class GPTAttention(nn.Layer):
             jnp.swapaxes(kv, 1, 2).astype(k_pool.dtype))
         v_pool = v_pool.at[blk, off].set(
             jnp.swapaxes(vv, 1, 2).astype(v_pool.dtype))
-        # gather each row's logical [s_max, nh, hd] view for the scores
-        k_view = k_pool[tables].reshape(b, s_max, nh, hd)
-        v_view = v_pool[tables].reshape(b, s_max, nh, hd)
-        scale = 1.0 / (self.head_dim ** 0.5)
-        scores = jnp.einsum("bhqd,bkhd->bhqk", qv.astype(jnp.float32),
-                            k_view.astype(jnp.float32)) * scale
-        key_idx = jnp.arange(s_max)
-        mask = key_idx[None, None, :] <= t_idx[:, :, None]
-        scores = jnp.where(mask[:, None], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bhqd", p,
-                         v_view.astype(jnp.float32)).astype(qv.dtype)
-        return Tensor(out), (k_pool, v_pool, (pos + s_new, tables))
+
+        per_tile, _ = key_tiling(mb, bs)
+        out, n_tiles = _attend_tiles(
+            qv.astype(jnp.float32), k_pool, v_pool, tables, t_idx, per_tile)
+        out = out.astype(qv.dtype)
+        return Tensor(out), (k_pool, v_pool, (pos + s_new, tables), n_tiles)
 
 
 class GPTMLP(nn.Layer):
@@ -472,7 +551,11 @@ class GPTForPretraining(nn.Layer):
         columns at positions ``pos[b] + column``; `pools` one ``(k, v)``
         a layer, scattered into and attended through `tables`
         (`GPTAttention._attend_paged`). Returns ``(hidden, pools,
-        aux)``; this block has nothing to count, so aux is empty."""
+        aux)`` with aux ``{"attn_key_tiles": turns the attention loop
+        ran this step (an int32 scalar; every layer runs the same
+        count), "attn_key_tiles_max": turns that cover the whole table
+        (a plain int, known from the table's shape)}``: their ratio is
+        the share of the table the step read."""
         import jax.numpy as jnp
 
         # clamp padding rows' position ids into the embedding table;
@@ -483,7 +566,10 @@ class GPTForPretraining(nn.Layer):
         h, new_caches = self.gpt(Tensor(tok), Tensor(posmat),
                                  caches=caches)
         hv = h._value if isinstance(h, Tensor) else h
-        return hv, [(c[0], c[1]) for c in new_caches], {}
+        *_, key_tiles = new_caches[0]
+        _, tiles_max = key_tiling(tables.shape[1], pools[0][0].shape[1])
+        aux = {"attn_key_tiles": key_tiles, "attn_key_tiles_max": tiles_max}
+        return hv, [(c[0], c[1]) for c in new_caches], aux
 
     def logits(self, h):
         from ...core.dispatch import apply

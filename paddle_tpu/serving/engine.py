@@ -31,7 +31,13 @@ allocation and SGLang-style prefix sharing:
   reserved null block. The old per-rung prefill ladder — one compile
   per padded prompt length, each stalling the decode loop — is gone;
   the decode program compiles exactly once, certified by the trace-time
-  compile counters and `observe.no_retrace()`.
+  compile counters and `observe.no_retrace()`. What a step reads of
+  the cache follows what the batch holds, not the table's width: the
+  models' attention walks each slot's table in tiles of keys and stops
+  behind the longest live row (the bound is a value of the trace, so
+  the program is still one). The dense model returns the turns it ran
+  in `aux`: counters `attn_key_tiles` of `attn_key_tiles_max`, their
+  ratio the share of the table the steps read.
 - Prefix sharing: finished sequences index their fully written blocks
   in a radix `PrefixCache` keyed on cumulative token-prefix hashes.
   A new request reuses every matching block physically (refcounted),
@@ -232,7 +238,9 @@ class SlotEngine:
     `paged_forward(tok, pos, nvalid, tables, pools) -> (hidden, pools,
     aux)` (one step over the paged pools; `aux` a dict of int arrays
     the step counts, summed into `aux_totals` and counters of the same
-    names), `logits(hidden)`, and optionally `serving_gauges()`.
+    names; an entry that is a plain `int` counts the same every step
+    and is added on the host, not returned by the program),
+    `logits(hidden)`, and optionally `serving_gauges()`.
     `GPTForPretraining` and `LatentMoEForCausalLM` do. Requests
     carry `max_new_tokens`, optional `eos_token_id`, and sampling
     params; results are the full [prompt + generated] int32 id array,
@@ -400,6 +408,7 @@ class SlotEngine:
         self.kv_pool_bytes = self._pool_bytes(self._layout)
         # sums of what the model's step counts (`aux`), by name
         self.aux_totals: dict = {}
+        self._aux_const: dict = {}
         itemsize = jnp.dtype(self._pool_dtype).itemsize
         self.metrics.set_gauge("kv_bytes_per_token",
                                self._layout.bytes_per_token(itemsize))
@@ -511,6 +520,12 @@ class SlotEngine:
             def run(m):
                 hv, new_pools, aux = m.paged_forward(tok, pos, nvalid,
                                                      tables, pools)
+                # a plain int is the same every step: it stays on the
+                # host (noted here, at trace time) and out of the program
+                self._aux_const = {k: v for k, v in aux.items()
+                                   if isinstance(v, int)}
+                aux = {k: v for k, v in aux.items()
+                       if k not in self._aux_const}
                 # only each slot's last valid position feeds sampling:
                 # skip the full-vocab projection of the rest of the chunk
                 # (an idle slot has no valid column; its row is unread)
@@ -1564,8 +1579,9 @@ class SlotEngine:
         `_pos`: `computed_tokens` (prompt tokens computed, not hit,
         plus tokens fed back) and `attn_context_tokens` (the keys each
         of them attended: its position + 1); padding columns count
-        nothing. And what the model's own step counted (`aux`), into
-        `aux_totals` and a counter of the same name."""
+        nothing. And what the model's own step counted (`aux`, and
+        the constants it named at trace time), into `aux_totals` and a
+        counter of the same name."""
         computed = context = 0
         for i in live:
             n, at = int(nvalid[i]), int(self._pos[i])
@@ -1573,7 +1589,7 @@ class SlotEngine:
             context += n * at + n * (n + 1) // 2
         self.metrics.inc("computed_tokens", computed)
         self.metrics.inc("attn_context_tokens", context)
-        for name, value in aux.items():
+        for name, value in {**aux, **self._aux_const}.items():
             value = np.asarray(value, np.int64)
             self.aux_totals[name] = self.aux_totals.get(name, 0) + value
             self.metrics.inc(name, int(value.sum()))

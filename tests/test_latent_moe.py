@@ -348,7 +348,12 @@ def test_gpt_goes_through_the_same_seam():
     want = np.asarray(gpt.generate(paddle.to_tensor(prompt[None, :]),
                                    max_new_tokens=4)._value)[0]
     np.testing.assert_array_equal(answer, want)
-    assert eng.aux_totals == {} and eng.metrics.get("computed_tokens") == 14
+    assert eng.metrics.get("computed_tokens") == 14
+    # what this block's step counts: the turns of its attention loop,
+    # one a step where one tile covers the table of 64 positions
+    steps = eng.metrics.get("steps")
+    assert {k: int(v) for k, v in eng.aux_totals.items()} \
+        == {"attn_key_tiles": steps, "attn_key_tiles_max": steps}
 
 
 @pytest.mark.parametrize("path", ["migrate", "migrate_other_kind", "spill",
