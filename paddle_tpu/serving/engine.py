@@ -59,27 +59,15 @@ rows of speculative verify steps — see below).
 
 Fast decode (ISSUE 16) rides the same one-trace contract:
 
-- Speculative decoding (``spec_len`` / FLAGS_serving_spec_len = k > 0):
-  each decode round proposes up to k tokens per slot from a draft model
-  (self-draft when none is given) and verifies them IN the unified step
-  — the slot stages ``[next, d_1..d_k]`` across the chunk columns it
-  already owns, and the step additionally projects the first k+1
-  columns to logits so the host can run Leviathan-style accept /
-  residual-resample per slot. Accepted tokens were already scattered
-  into the paged pool in bulk by that same step; a rejected suffix
-  leaves garbage KV above the committed position, which the next
-  round's staging always overwrites before any row can attend it (the
-  per-row causal mask covers the degraded-round gap). The draft model
-  runs its own compiled micro-step over separate pools sharing THIS
-  engine's block tables; its cache trails the committed sequence
-  (per-slot ``dfill``) and self-heals by catch-up, so a faulted draft
-  phase simply degrades the round to plain decode. Compile counters
-  certify ``{decode: 1, draft: 1, cow: 1}`` for life; spec-disabled
-  engines build no draft trace at all and keep ``{decode: 1, cow: 1}``.
-  Greedy speculative decode is bitwise token-identical to plain greedy:
-  rejection hands the verify logits to the normal `_pick` path instead
-  of eagerly committing, so every emitted token is an argmax of the
-  same-valued logits row the plain engine would have produced.
+- Speculative decoding (``spec_len`` / FLAGS_serving_spec_len = k > 0)
+  lives behind one object, `speculation.Speculation`, which the engine
+  holds or not: each round it drafts up to k tokens a slot between the
+  engine's consume and its dispatch, the unified step verifies them in
+  the chunk columns the slot already owns (`out["verify"]`), and it
+  accepts or resamples after the engine's commit. A plain engine is
+  the case in which nothing was drafted. Compile counters certify
+  ``{decode: 1, draft: 1, cow: 1}`` for life; spec-disabled engines
+  build no draft trace at all and keep ``{decode: 1, cow: 1}``.
 - Int8 weight path (``quantize`` / FLAGS_serving_quantize): weights are
   frozen per-tensor to int8 + `@scale` companions
   (quantization.quantize_state_int8) and cross the jit boundary as
@@ -104,11 +92,9 @@ Fault sites: ``serving.step`` fires once per decode step (a `raise`
 action fails every in-flight request deterministically while the engine
 stays up); ``serving.alloc_block`` on every physical block allocation
 (deterministic pool exhaustion); ``serving.cow_split`` before every
-copy-on-write block copy; ``serving.draft`` before each speculative
-draft phase (raise = degrade that round to plain decode, slots survive
-with no lost or duplicated tokens); ``serving.verify`` before each
-speculative verify dispatch (raise = step error, fails in-flight
-requests like serving.step); ``serving.dequant`` once per step on an
+copy-on-write block copy; ``serving.draft`` and ``serving.verify`` on a
+speculative engine only (speculation.py); ``serving.dequant`` once per
+step on an
 int8-frozen engine; ``serving.kv_restore`` before each spilled-block
 restore (raise = restore abort, leak-free, the request re-prefills);
 ``serving.adapter_swap`` before each adapter-bank hot-swap mutates
@@ -157,42 +143,14 @@ from .queueing import (
     AdmissionQueue, CapacityExhaustedError, DeadlineExceededError, Request,
     RequestCancelled,
 )
+from .speculation import Speculation
 
-__all__ = ["SlotEngine", "speculative_accept"]
+__all__ = ["SlotEngine"]
 
 # a Request is stamped on time.monotonic(), as its arrival and deadline
 # are; the profiler ring is on time.perf_counter() (the same clock on
 # Linux, a fixed offset apart elsewhere)
 _RING_CLOCK_OFFSET = time.perf_counter() - time.monotonic()
-
-
-def speculative_accept(p_list, q_list, proposals, rng):
-    """Leviathan-style rejection sampling over one drafted chain.
-
-    `p_list[j]` / `q_list[j]` are the (identically warped) target and
-    draft probability vectors at the position of `proposals[j]`. Accept
-    d_j while ``u_j < min(1, p_j(d_j) / q_j(d_j))``; on first rejection
-    resample from the residual ``normalize(max(p - q, 0))``. Returns
-    ``(accepted_count, resampled_token_or_None)`` — None means every
-    proposal survived (the caller then samples the bonus token from the
-    verify step's final logits row, completing the k+1-per-round
-    upside). The emitted-token distribution equals sampling from p
-    directly — certified by the histogram test in
-    tests/test_serving_spec.py. Pure host-side numpy so the invariant
-    is testable without an engine."""
-    for j, d in enumerate(proposals):
-        p, q = p_list[j], q_list[j]
-        if rng.random_sample() < min(1.0, float(p[d]) / max(float(q[d]),
-                                                            1e-20)):
-            continue
-        residual = np.maximum(p - q, 0.0)
-        tot = residual.sum()
-        if tot <= 0.0:
-            # p == q exactly and still rejected (u landed on the
-            # boundary): any residual draw is p-distributed; use p
-            residual, tot = p, p.sum()
-        return j, int(rng.choice(residual.size, p=residual / tot))
-    return len(proposals), None
 
 
 class _Slot:
@@ -212,21 +170,9 @@ class _Slot:
         self.rng = None
         if req.gen.get("do_sample"):
             self.rng = np.random.RandomState(req.gen.get("seed", 0))
-        # speculative-decoding state (unused when spec_len == 0):
-        # the draft cache trails the committed sequence — positions
-        # [0, dfill) hold draft KV for tokens[0:dfill]; `fed` logs every
-        # token fed to it this round (committed catch-up AND proposals)
-        # so dfill advances exactly as far as the commit agreed with
-        # what was fed, whatever the round's outcome (accept, reject,
-        # degrade, mid-phase fault)
-        self.dfill = 0
-        self.fed: list = []
-        self.drafted: list = []   # this round's proposals d_1..d_s
-        self.qdists: list = []    # warped draft dists per proposal
-        self.spec_staged: list = []  # proposals actually staged
-        # a residual-resampled token is appended at commit but its KV is
-        # not yet written; the next consume must feed it, not re-pick
-        self.unfed = False
+        # a speculative engine's draft-side state for this slot
+        # (speculation._SlotDraft); None on a plain one
+        self.spec = None
 
 
 class SlotEngine:
@@ -455,18 +401,45 @@ class SlotEngine:
         self._warmed = False
         self._abort = threading.Event()
         self._thread = None
-        # KV adoptions (prefill->decode migration) land at step
-        # boundaries: callers enqueue here and the serve loop applies,
-        # so pool rebinds never race the compiled step's own updates
-        self._migrate_q: list = []
-        self._migrate_lock = threading.Lock()
-        # adapter-bank hot-swaps land at step boundaries too (same
-        # enqueue/drain contract as KV adoption), so a swap never races
-        # the compiled step's reads
-        self._adapter_q: list = []
+        # what rebinds a thing the compiled step reads (KV adoption
+        # after a prefill->decode migration, an adapter-bank hot-swap)
+        # lands at a step boundary: callers enqueue here and the serve
+        # loop applies, so a rebind never races the step's own updates
+        self._boundary_q: list = []
+        self._boundary_lock = threading.Lock()
 
-        def _count(key):
-            self._compiles[key] = self._compiles.get(key, 0) + 1
+        self._build_programs(self.spec_len + 1 if self.spec_len else 0)
+        # what only speculation knows: the draft model, its pools and
+        # program, and the accept rule. A plain engine holds None, builds
+        # no draft trace and keeps {decode: 1, cow: 1} exactly.
+        self._spec = None
+        if self.spec_len:
+            self._spec = Speculation(self, draft_model, self.spec_len)
+            self.kv_pool_bytes += self._spec.pool_bytes
+
+    # -- the compiled programs ----------------------------------------------
+
+    def _count_compile(self, key):
+        """Trace-time only: one more trace of program `key`."""
+        self._compiles[key] = self._compiles.get(key, 0) + 1
+
+    def _build_programs(self, verify_cols):
+        """Jit the unified step and the CoW copy. With `_stage`, this is
+        the one place that knows what the step takes and returns:
+
+            serving_step(values, batch, pools, extras) -> (out, pools)
+
+        `batch` holds the host-staged arrays (`tok`, `pos`, `nvalid`,
+        `tables`; `aid` on an engine with adapters), `extras` the
+        device-resident arguments that are not weights (`act_scale`
+        under w8a8; `lora_a`, `lora_b` with adapters; empty on a plain
+        engine), `out` what the host reads back: `logits`, `aux`,
+        `verify` on a speculative engine (`verify_cols` > 0: the first
+        k+1 columns' logits) and `amax` under w8a8. What an engine does
+        not have is absent from the trees, so each configuration has
+        exactly one signature and traces once. The pools are donated."""
+        import jax
+        import jax.numpy as jnp
 
         def _head(m, values, hrows, act_scale=None):
             """Project hidden rows (.., H) to f32 logits (.., V): the
@@ -504,10 +477,13 @@ class SlotEngine:
             out = out._value if isinstance(out, Tensor) else out
             return (out[:, 0, :] if squeeze else out).astype(jnp.float32)
 
-        def serving_step(values, tok, pos, nvalid, tables, pools,
-                         act_scale=None, aid=None, la=None, lb=None):
+        def serving_step(values, batch, pools, extras):
+            tok, pos, nvalid = batch["tok"], batch["pos"], batch["nvalid"]
+            tables, aid = batch["tables"], batch.get("aid")
+            act_scale = extras.get("act_scale")
+            la, lb = extras.get("lora_a"), extras.get("lora_b")
             # trace-time only: the compile counter + retrace registry
-            _count("decode")
+            self._count_compile("decode")
             observe.record_compile(
                 "serving.step",
                 signature=observe.signature_of(tok, pos, tables))
@@ -524,52 +500,46 @@ class SlotEngine:
                 # host (noted here, at trace time) and out of the program
                 self._aux_const = {k: v for k, v in aux.items()
                                    if isinstance(v, int)}
-                aux = {k: v for k, v in aux.items()
-                       if k not in self._aux_const}
+                out = {"aux": {k: v for k, v in aux.items()
+                               if k not in self._aux_const}}
                 # only each slot's last valid position feeds sampling:
                 # skip the full-vocab projection of the rest of the chunk
                 # (an idle slot has no valid column; its row is unread)
                 last = hv[jnp.arange(hv.shape[0]),
                           jnp.maximum(nvalid - 1, 0)]
-                lv = _head(m, values, last, act_scale)
-                # w8a8 calibration: this step's head-input abs-max
-                # rides the outputs so the host can fold it into the
-                # frozen activation scale without an extra device pass
-                # (taken BEFORE any adapter delta — the scale calibrates
-                # the shared trunk, not one tenant's adapter)
-                amax = jnp.max(jnp.abs(last.astype(jnp.float32))) \
-                    if act_scale is not None else None
+                out["logits"] = _head(m, values, last, act_scale)
+                if act_scale is not None:
+                    # w8a8 calibration: this step's head-input abs-max
+                    # rides the outputs so the host can fold it into the
+                    # frozen activation scale without an extra device
+                    # pass (taken BEFORE any adapter delta — the scale
+                    # calibrates the shared trunk, not one tenant's
+                    # adapter)
+                    out["amax"] = jnp.max(jnp.abs(last.astype(jnp.float32)))
+                if verify_cols:
+                    # speculative verify: the first k+1 chunk columns
+                    # ([next, d_1..d_k]) all feed accept/reject
+                    out["verify"] = _head(m, values, hv[:, :verify_cols],
+                                          act_scale)
                 if la is not None:
                     # batched LoRA head delta: gather each slot's
                     # adapter row by index inside the trace; row 0 is
                     # all-zero so base-model slots add exactly 0.0
                     from ..nlp.transformers.gpt import lora_logits_delta
 
-                    lv = lv + lora_logits_delta(last, aid, la, lb)
-                if self.spec_len:
-                    # speculative verify: the first k+1 chunk columns
-                    # ([next, d_1..d_k]) all feed accept/reject
-                    sv = _head(m, values, hv[:, :self.spec_len + 1],
-                               act_scale)
-                    if la is not None:
-                        sv = sv + lora_logits_delta(
-                            hv[:, :self.spec_len + 1], aid, la, lb)
-                    return (lv, sv, amax, aux), new_pools
-                return (lv, lv, amax, aux), new_pools
+                    out["logits"] = out["logits"] + lora_logits_delta(
+                        last, aid, la, lb)
+                    if verify_cols:
+                        out["verify"] = out["verify"] + lora_logits_delta(
+                            hv[:, :verify_cols], aid, la, lb)
+                return out, new_pools
 
-            (lv, sv, amax, aux), new_pools = functional_apply(
-                self.model, fvals, run, mesh=self.mesh)
-            # what sampling reads, then what the model counted, then
-            # the pools: (lv[, sv][, amax], aux, pools)
-            heads = (lv, sv) if self.spec_len else (lv,)
-            if act_scale is not None:
-                heads += (amax,)
-            return (*heads, aux, new_pools)
+            return functional_apply(self.model, fvals, run, mesh=self.mesh)
 
         def serving_cow(pools, src, dst):
             from jax import lax
 
-            _count("cow")
+            self._count_compile("cow")
             observe.record_compile("serving.cow", signature="(block, block)")
 
             def copy(pool):
@@ -579,89 +549,28 @@ class SlotEngine:
 
             return jax.tree_util.tree_map(copy, pools)
 
-        if self._plan is not None:
-            # explicit in/out shardings: host-staged step inputs are
-            # replicated, weights follow the partition rules, pools keep
-            # their head sharding through the step (GSPMD then has no
-            # freedom to reshard the hot loop between steps)
-            rep = self._plan.replicated()
-            vsh = self._plan.values_shardings(self._values)
-            pools = self._pool_shardings(self._layout)
-            # heads (logits[, verify logits][, abs-max]), aux, pools
-            n_heads = 1 + bool(self.spec_len) + bool(self.w8a8)
-            step_out = (rep,) * n_heads + (rep, pools)
-            if self.w8a8:
-                step_in = (vsh, rep, rep, rep, rep, pools, rep)
-            else:
-                step_in = (vsh, rep, rep, rep, rep, pools)
-                if self.max_adapters:
-                    # explicit act_scale=None slot (an empty pytree:
-                    # the leaf sharding applies to zero leaves)
-                    step_in = step_in + (rep,)
-            if self.max_adapters:
-                # per-slot adapter ids + replicated A/B banks
-                step_in = step_in + (rep, rep, rep)
-            self._decode = jax.jit(
-                serving_step,
-                in_shardings=step_in,
-                out_shardings=step_out,
-                donate_argnums=(5,))
-            self._cow = jax.jit(
-                serving_cow,
-                in_shardings=(pools, rep, rep),
-                out_shardings=pools,
-                donate_argnums=(0,))
-        else:
-            self._decode = jax.jit(serving_step, donate_argnums=(5,))
+        if self._plan is None:
+            self._decode = jax.jit(serving_step, donate_argnums=(2,))
             self._cow = jax.jit(serving_cow, donate_argnums=(0,))
-
-        # -- speculative draft trace (only when spec is on: a disabled
-        # engine keeps compile counters {decode: 1, cow: 1} exactly) --
-        if self.spec_len:
-            self.draft_model = draft_model if draft_model is not None \
-                else model
-            self.draft_model.eval()
-            dcfg = self.draft_model.config
-            if dcfg.vocab_size != cfg.vocab_size:
-                raise ValueError(
-                    f"draft vocab {dcfg.vocab_size} != target vocab "
-                    f"{cfg.vocab_size}")
-            if dcfg.max_seq_len < self.max_seq_len:
-                raise ValueError(
-                    f"draft max_seq_len {dcfg.max_seq_len} < engine "
-                    f"max_seq_len {self.max_seq_len}")
-            # draft weights stay float (the draft is the small model);
-            # separate per-layer pools share THIS engine's block tables
-            # and allocator, so one block id addresses both caches
-            self._dvalues = dict(state_values(self.draft_model)) \
-                if draft_model is not None else dict(self._values)
-            if is_quantized_state(self._dvalues):
-                self._dvalues = {
-                    k: v for k, v in self._dequantize_state(
-                        self._dvalues).items()}
-            self._dlayout = self.draft_model.cache_layout()
-            self._dpools = self._zero_pools(self._dlayout, place=False)
-            self.kv_pool_bytes += self._pool_bytes(self._dlayout)
-            self._draft_chunk = self.spec_len + 1
-
-            def serving_draft(dvalues, tok, pos, nvalid, tables, pools):
-                _count("draft")
-                observe.record_compile(
-                    "serving.draft",
-                    signature=observe.signature_of(tok, pos, tables))
-
-                def run(m):
-                    hv, new_pools, _aux = m.paged_forward(
-                        tok, pos, nvalid, tables, pools)
-                    last = hv[jnp.arange(hv.shape[0]), nvalid - 1]
-                    return m.logits(Tensor(last[:, None, :])), new_pools
-
-                logits, new_pools = functional_apply(
-                    self.draft_model, dvalues, run)
-                lv = jnp.asarray(logits)[:, 0, :].astype(jnp.float32)
-                return lv, new_pools
-
-            self._draft = jax.jit(serving_draft, donate_argnums=(5,))
+            return
+        # explicit in/out shardings: weights follow the partition rules,
+        # pools keep their head sharding through the step (GSPMD then has
+        # no freedom to reshard the hot loop between steps), and whatever
+        # `batch`, `extras` and `out` hold is replicated (one sharding
+        # stands for every leaf of its tree)
+        rep = self._plan.replicated()
+        pools = self._pool_shardings(self._layout)
+        self._decode = jax.jit(
+            serving_step,
+            in_shardings=(self._plan.values_shardings(self._values), rep,
+                          pools, rep),
+            out_shardings=(rep, pools),
+            donate_argnums=(2,))
+        self._cow = jax.jit(
+            serving_cow,
+            in_shardings=(pools, rep, rep),
+            out_shardings=pools,
+            donate_argnums=(0,))
 
     # -- introspection ------------------------------------------------------
 
@@ -777,33 +686,12 @@ class SlotEngine:
             self._pools = self._zero_pools(self._layout)
         self.metrics.inc("pool_rebuilds")
 
-    def _recover_draft_pools(self):
-        """The draft's twin of `_recover_pools`: a draft call that
-        raised after its dispatch leaves empty draft pools, and every
-        slot's draft cache starts over (`dfill` 0): the next round's
-        catch-up rewrites it, as after any degraded round."""
-        if not self._lost(self._arrays(self._dpools)):
-            return
-        self._dpools = self._zero_pools(self._dlayout, place=False)
-        for slot in self._slots:
-            if slot is not None:
-                slot.dfill, slot.fed = 0, []
-
     # -- w8a8 activation scale (frozen after a short calibration) -----------
 
     # warmup + this many real steps feed the running abs-max before the
     # activation scale freezes; until the first absorb lands the scale
     # is 0 and the in-trace lax.cond keeps the weights-only epilogue
     _W8A8_CALIB_STEPS = 8
-
-    def _act_arg(self):
-        """This step's activation-scale argument: 0 degrades the step
-        to the weights-only dequant path inside the same trace."""
-        import jax.numpy as jnp
-
-        if self._w8a8_degraded:
-            return jnp.zeros((), jnp.float32)
-        return self._act_scale
 
     def _absorb_act_amax(self, amax):
         """Fold one step's head-input abs-max into the frozen scale.
@@ -819,75 +707,91 @@ class SlotEngine:
         if self._act_calib > self._W8A8_CALIB_STEPS:
             self._act_frozen = True
 
-    # -- batched adapter bank (ISSUE 20) ------------------------------------
+    # -- the step's arguments -----------------------------------------------
 
-    def _dispatch_decode(self, tok, pos, nvalid):
-        """The ONE argument arity for the compiled decode step: every
-        call site (warmup, plain step, speculative verify) builds its
-        positional list here, so jax.jit sees exactly one signature per
-        engine configuration — the compile-once invariant survives any
-        mix of the w8a8 and adapter options. Rebinds `_pools` to the
-        step's outputs and returns the rest of them: ``(heads, aux)``,
-        heads the logits, then the verify logits of a speculative
-        engine, then w8a8's abs-max; aux what the model's step counted."""
+    def _stage(self, tok, pos, nvalid):
+        """The step's `batch` and `extras` (see `_build_programs`) for
+        one call: the host's arrays staged on the device, the block
+        tables with them. Warmup, the step and the tests all come
+        through here, so jax.jit sees exactly one signature per engine
+        configuration — the compile-once invariant survives any mix of
+        the w8a8 and adapter options."""
         import jax.numpy as jnp
 
-        args = [self._values, jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(nvalid), jnp.asarray(self._bt)]
-        tail = []
+        batch = {"tok": jnp.asarray(tok), "pos": jnp.asarray(pos),
+                 "nvalid": jnp.asarray(nvalid),
+                 "tables": jnp.asarray(self._bt)}
+        extras = {}
         if self.w8a8:
-            tail.append(self._act_arg())
-        elif self.max_adapters:
-            tail.append(None)   # act_scale slot stays positional
+            # 0 degrades the step to the weights-only dequant path
+            # inside the same trace
+            extras["act_scale"] = jnp.zeros((), jnp.float32) \
+                if self._w8a8_degraded else self._act_scale
         if self.max_adapters:
-            tail.extend((jnp.asarray(self._aid), self._lora_a,
-                         self._lora_b))
-        # the pools are donated: dead from the dispatch to the rebind
+            batch["aid"] = jnp.asarray(self._aid)
+            extras["lora_a"], extras["lora_b"] = self._lora_a, self._lora_b
+        return batch, extras
+
+    def _dispatch(self, tok, pos, nvalid):
+        """One call of the compiled step. The pools are donated: dead
+        from the dispatch to the rebind of its outputs, under
+        `_pool_lock`. Returns the step's `out`, still on the device
+        (w8a8's abs-max folded into the scale there, never read)."""
+        batch, extras = self._stage(tok, pos, nvalid)
         with self._pool_lock:
-            *heads, aux, self._pools = self._decode(
-                *args, self._pools, *tail)
-        return heads, aux
+            out, self._pools = self._decode(self._values, batch,
+                                            self._pools, extras)
+        if self.w8a8:
+            self._absorb_act_amax(out.pop("amax"))
+        return out
+
+    # -- batched adapter bank (ISSUE 20) ------------------------------------
+
+    def _at_step_boundary(self, call, what, timeout):
+        """`call()` where no step is in flight: on the loop's thread
+        between two steps when the serve loop is running, inline
+        otherwise. Returns its result or raises its error."""
+        if self._thread is None or not self._thread.is_alive():
+            return call()
+        done = threading.Event()
+        box: dict = {}
+        with self._boundary_lock:
+            self._boundary_q.append((call, done, box))
+        if not done.wait(timeout):
+            raise TimeoutError(
+                f"engine {self.name!r} did not reach a step boundary "
+                f"within {timeout:.3f}s to {what}")
+        if "error" in box:
+            raise box["error"]
+        return box["result"]
+
+    def _drain_boundary_calls(self):
+        while True:
+            with self._boundary_lock:
+                if not self._boundary_q:
+                    return
+                call, done, box = self._boundary_q.pop(0)
+            try:
+                box["result"] = call()
+            except Exception as e:  # noqa: BLE001 — caller re-raises
+                box["error"] = e
+            finally:
+                done.set()
 
     def swap_adapters(self, lora_a, lora_b, version=None, timeout=5.0):
-        """Hot-swap the stacked adapter bank (the rollout commit path).
-        Applied at a step boundary when the serve loop is running (the
-        bank rebind must not race the compiled step's reads), inline
-        otherwise. All-or-nothing: a fault (``serving.adapter_swap``) or
-        validation error leaves the OLD bank serving bitwise. Shapes
+        """Hot-swap the stacked adapter bank (the rollout commit path),
+        at a step boundary: the bank rebind must not race the compiled
+        step's reads. All-or-nothing: a fault (``serving.adapter_swap``)
+        or validation error leaves the OLD bank serving bitwise. Shapes
         are fixed by construction, so a swap never retraces. Returns
         the new adapter_version."""
         if not self.max_adapters:
             raise ValueError(
                 "engine built without adapters (max_adapters=0 / "
                 "FLAGS_serving_max_adapters)")
-        if self._thread is not None and self._thread.is_alive():
-            done = threading.Event()
-            box: dict = {}
-            with self._migrate_lock:
-                self._adapter_q.append((lora_a, lora_b, version, done,
-                                        box))
-            if not done.wait(timeout):
-                raise TimeoutError(
-                    f"engine {self.name!r} did not reach a step boundary "
-                    f"within {timeout:.3f}s to swap adapters")
-            if "error" in box:
-                raise box["error"]
-            return box["version"]
-        return self._apply_adapter_swap(lora_a, lora_b, version)
-
-    def _drain_adapter_swaps(self):
-        while True:
-            with self._migrate_lock:
-                if not self._adapter_q:
-                    return
-                la, lb, version, done, box = self._adapter_q.pop(0)
-            try:
-                box["version"] = self._apply_adapter_swap(la, lb,
-                                                          version)
-            except Exception as e:  # noqa: BLE001 — caller re-raises
-                box["error"] = e
-            finally:
-                done.set()
+        return self._at_step_boundary(
+            lambda: self._apply_adapter_swap(lora_a, lora_b, version),
+            "swap adapters", timeout)
 
     def _apply_adapter_swap(self, lora_a, lora_b, version):
         import jax.numpy as jnp
@@ -953,14 +857,10 @@ class SlotEngine:
                             jnp.int32)
             pos = jnp.zeros((self.max_slots,), jnp.int32)
             nvalid = jnp.ones((self.max_slots,), jnp.int32)
-            heads, _aux = self._dispatch_decode(tok, pos, nvalid)
-            if self.w8a8:
-                self._absorb_act_amax(heads[-1])
+            self._dispatch(tok, pos, nvalid)
             self._copy_block(NULL_BLOCK, NULL_BLOCK)
-            if self.spec_len:
-                dtok = jnp.zeros((self.max_slots, self._draft_chunk),
-                                 jnp.int32)
-                self._dispatch_draft(dtok, pos, nvalid)
+            if self._spec is not None:
+                self._spec.warmup(pos, nvalid)
         self._warmed = True
         return self.compile_counts
 
@@ -1169,40 +1069,16 @@ class SlotEngine:
 
     def adopt_prefix_blocks(self, payload, timeout=5.0):
         """Adopt migrated KV blocks into this engine's pool + prefix
-        cache. Applied at a step boundary when the serve loop is
-        running (pool rebinds must not race the compiled step, which is
-        handed the pools and deletes them), inline otherwise. Returns
-        the number of prompt tokens now served from cache (0 =
+        cache, at a step boundary: pool rebinds must not race the
+        compiled step, which is handed the pools and deletes them.
+        Returns the number of prompt tokens now served from cache (0 =
         incompatible payload: another block size, layer count, block
         shape or row order). All-or-nothing: any fault
         mid-adoption frees every block taken so far — the pool is
         leak-free and the request simply prefills from scratch."""
-        if self._thread is not None and self._thread.is_alive():
-            done = threading.Event()
-            box: dict = {}
-            with self._migrate_lock:
-                self._migrate_q.append((payload, done, box))
-            if not done.wait(timeout):
-                raise TimeoutError(
-                    f"engine {self.name!r} did not reach a step boundary "
-                    f"within {timeout:.3f}s to adopt migrated KV")
-            if "error" in box:
-                raise box["error"]
-            return box["adopted"]
-        return self._apply_adoption(payload)
-
-    def _drain_adoptions(self):
-        while True:
-            with self._migrate_lock:
-                if not self._migrate_q:
-                    return
-                payload, done, box = self._migrate_q.pop(0)
-            try:
-                box["adopted"] = self._apply_adoption(payload)
-            except Exception as e:  # noqa: BLE001 — caller re-raises
-                box["error"] = e
-            finally:
-                done.set()
+        return self._at_step_boundary(
+            lambda: self._apply_adoption(payload),
+            "adopt migrated KV", timeout)
 
     def _apply_adoption(self, payload):
         if self._cache is None or payload is None:
@@ -1458,6 +1334,13 @@ class SlotEngine:
                 self._evict(i, error)
 
     def _step(self):
+        """One continuous-batching iteration: consume each decoding
+        slot's pending logits (finishing slots that hit
+        EOS/max/deadline), stage the next chunk for prefilling slots,
+        then ONE batched step over the whole pool, then commit what it
+        computed. A speculative engine drafts between the consume and
+        the dispatch and accepts after the commit (speculation.py); a
+        plain one is the round in which nothing was drafted."""
         if self.mesh is not None:
             # raise here propagates to _loop like any step error: the
             # engine survives and the Router replays the in-flight work
@@ -1476,15 +1359,6 @@ class SlotEngine:
             except Exception:  # noqa: BLE001 — deterministic degrade
                 self._w8a8_degraded = True
                 self.metrics.inc("w8a8_degraded_steps")
-        if self.spec_len:
-            return self._step_spec()
-        return self._step_plain()
-
-    def _step_plain(self):
-        """One continuous-batching iteration: consume each decoding
-        slot's pending logits (finishing slots that hit
-        EOS/max/deadline), stage the next chunk for prefilling slots,
-        then ONE batched step over the whole pool."""
         try:
             faults.fault_point("serving.step")
         except Exception as e:  # noqa: BLE001 — deterministic mid-decode
@@ -1496,16 +1370,20 @@ class SlotEngine:
         nvalid = np.zeros((self.max_slots,), np.int32)
         live: list = []
         with observe.phase("sample", cat="serving"):
-            prefill_tokens = self._consume_slots(now, tok, nvalid, live)
+            prefill_tokens = self._consume(now, tok, nvalid, live)
         if not live:
             return
+        spec = self._spec
+        if spec is not None:
+            spec.propose(live, tok, nvalid)
         n_pref = sum(1 for i in live
                      if self._slots[i].state == "prefill")
-        (logits,), aux, t0, done = self._device_step(tok, nvalid)
+        out, t0, done = self._device_step(tok, nvalid)
         with observe.phase("commit", cat="serving"):
             self._observe_step_latency(done - t0, prefill_tokens,
                                        len(live) - n_pref)
-            self._count_computed(live, nvalid, aux)
+            self._count_computed(live, nvalid, out["aux"])
+            logits = out["logits"]
             for i in live:
                 slot = self._slots[i]
                 self._pos[i] += slot.advance
@@ -1518,16 +1396,22 @@ class SlotEngine:
                         self.metrics.inc("prefills")
                 else:
                     slot.next_logits = logits[i]
-            self._count_step(len(live), prefill_tokens)
+            if spec is not None:
+                spec.commit(out["verify"], done)
+            self.metrics.inc("steps")
+            if prefill_tokens:
+                self.metrics.inc("prefill_tokens", prefill_tokens)
+            self.metrics.observe_occupancy(len(live), self.max_slots)
+            self.metrics.observe_blocks(self._alloc.blocks_in_use,
+                                        self._alloc.usable)
 
     def _device_step(self, tok, nvalid):
         """The iteration's one dispatch of the compiled step and the
-        read-back of what sampling needs. Returns the host arrays (the
-        logits; a speculative engine's verify logits after them) and
-        the clock before the dispatch and after the read-back.
-
-        What the model's step counted (`aux`) comes back in the same
-        read-back as the logits: one blocking transfer.
+        read-back of what the host needs of it. Returns the step's
+        `out` as host arrays (the logits, what the model's step counted
+        in `aux`, a speculative engine's verify logits: one blocking
+        transfer) and the clock before the dispatch and after the
+        read-back.
 
         The step is handed the pools and updates them in place: the
         arrays that went in read `is_deleted()` afterwards, which
@@ -1540,12 +1424,9 @@ class SlotEngine:
         try:
             with profiler.RecordEvent("serving.step", cat="serving"):
                 with observe.phase("dispatch", cat="serving"):
-                    heads, aux = self._dispatch_decode(tok, self._pos,
-                                                       nvalid)
-                    if self.w8a8:
-                        self._absorb_act_amax(heads.pop())
+                    out = self._dispatch(tok, self._pos, nvalid)
                 with observe.phase("readback", cat="serving"):
-                    heads, aux = jax.device_get((heads, aux))
+                    out = jax.device_get(out)
         except Exception:
             if self._pools is not pools:
                 # dispatched, and its logits cannot be read: what it
@@ -1556,7 +1437,7 @@ class SlotEngine:
         done = time.monotonic()
         if all(a.is_deleted() for a in self._arrays(pools)):
             self.metrics.inc("pool_inplace_steps")
-        return heads, aux, t0, done
+        return out, t0, done
 
     def _observe_step_latency(self, dt, prefill_tokens, n_decoding):
         """Attribute one device step, dispatch to the logits on the
@@ -1594,15 +1475,7 @@ class SlotEngine:
             self.aux_totals[name] = self.aux_totals.get(name, 0) + value
             self.metrics.inc(name, int(value.sum()))
 
-    def _count_step(self, n_live, prefill_tokens):
-        self.metrics.inc("steps")
-        if prefill_tokens:
-            self.metrics.inc("prefill_tokens", prefill_tokens)
-        self.metrics.observe_occupancy(n_live, self.max_slots)
-        self.metrics.observe_blocks(self._alloc.blocks_in_use,
-                                    self._alloc.usable)
-
-    def _consume_slots(self, now, tok, nvalid, live):
+    def _consume(self, now, tok, nvalid, live):
         """Host-side half of a step: sample each decoding slot's pending
         logits (finish/evict on EOS/max/deadline/cancel), stage the next
         prompt chunk for prefilling slots, and fill the fixed
@@ -1632,308 +1505,28 @@ class SlotEngine:
                 prefill_tokens += n
                 live.append(i)
                 continue
-            nxt = self._pick(slot)
-            slot.tokens.append(nxt)
-            slot.produced += 1
-            req.token_times.append(now)
-            self.metrics.inc("tokens_out")
-            gen = req.gen
-            eos = gen.get("eos_token_id")
-            if (eos is not None and nxt == eos) or \
-                    slot.produced >= gen.get("max_new_tokens", 16):
-                self._evict(i)
-                continue
-            tok[i, 0] = nxt
-            nvalid[i] = 1
-            slot.advance = 1
-            live.append(i)
-        return prefill_tokens
-
-    # -- speculative decoding (spec_len > 0) --------------------------------
-
-    def _step_spec(self):
-        """One speculative iteration: pick each decoding slot's
-        committed next token, draft up to spec_len proposals per slot
-        with the compiled draft micro-step (catch-up + propose over the
-        shared block tables), stage ``[next, d_1..d_s]`` across the
-        chunk columns, run ONE verify dispatch on the unified decode
-        trace, then accept/commit host-side. A fault in the draft phase
-        degrades the round to plain decode: proposals are dropped, the
-        draft cache keeps whatever catch-up landed, and every slot
-        still commits exactly its picked token — no losses, no dups."""
-        try:
-            faults.fault_point("serving.step")
-        except Exception as e:  # noqa: BLE001 — deterministic mid-decode
-            self._fail_all_active(e)
-            return
-        now = time.monotonic()
-        tok = np.zeros((self.max_slots, self.prefill_chunk), np.int32)
-        nvalid = np.zeros((self.max_slots,), np.int32)
-        live: list = []
-        plan: list = []   # (slot_idx, slot, next_token, s_i)
-        with observe.phase("sample", cat="serving"):
-            prefill_tokens = self._consume_spec(now, tok, nvalid, live,
-                                                plan)
-        if not live:
-            return
-        # prefilling slots join the draft phase with s_i = 0 so the
-        # draft cache ingests their prompt alongside the target prefill
-        work = [(i, slot, s_i) for i, slot, _, s_i in plan]
-        work += [(i, self._slots[i], 0) for i in live
-                 if self._slots[i].state == "prefill"]
-        drafted_ok = True
-        try:
-            faults.fault_point("serving.draft")
-            with observe.phase("draft", cat="serving"):
-                self._run_draft(work)
-        except Exception:  # noqa: BLE001 — degrade to plain decode
-            drafted_ok = False
-            self.metrics.inc("spec_draft_faults")
-            self._recover_draft_pools()
-        for i, slot, nxt, s_i in plan:
-            props = slot.drafted[:s_i] if drafted_ok else []
-            slot.spec_staged = props
-            tok[i, 0] = nxt
-            if props:
-                tok[i, 1:1 + len(props)] = props
-            nvalid[i] = 1 + len(props)
-        faults.fault_point("serving.verify")
-        n_pref = sum(1 for i in live
-                     if self._slots[i].state == "prefill")
-        (lv, sv), aux, t0, done = self._device_step(tok, nvalid)
-        with observe.phase("commit", cat="serving"):
-            self._observe_step_latency(done - t0, prefill_tokens,
-                                       len(live) - n_pref)
-            self._count_computed(live, nvalid, aux)
-            for i in live:
-                slot = self._slots[i]
-                if slot.state == "prefill":
-                    slot.req.prefill_steps += 1
-                    self._pos[i] += slot.advance
-                    slot.fill += slot.advance
-                    self._advance_dfill(slot)
-                    if slot.fill >= slot.prompt_len:
-                        slot.state = "decode"
-                        slot.next_logits = lv[i]
-                        self.metrics.inc("prefills")
-                else:
-                    self._commit_spec(i, slot, lv[i], sv[i], done)
-            if plan:
-                self.metrics.inc("spec_rounds")
-            self._count_step(len(live), prefill_tokens)
-
-    def _consume_spec(self, now, tok, nvalid, live, plan):
-        """Speculative twin of `_consume_slots`: same cancel / deadline
-        / EOS handling and prefill staging, but decoding slots defer
-        their token-matrix staging until after the draft phase.  Caps
-        each slot's draft length at its remaining token budget so every
-        staged position stays inside its allocated blocks."""
-        prefill_tokens = 0
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            req = slot.req
-            if req.cancelled:
-                self.metrics.inc("cancelled")
-                self._evict(i, RequestCancelled(
-                    f"request {req.id} cancelled mid-decode"))
-                continue
-            if req.expired(now):
-                self.metrics.inc("timeouts")
-                self._evict(i, DeadlineExceededError(
-                    f"request {req.id} deadline exceeded mid-decode "
-                    f"after {slot.produced} tokens"))
-                continue
-            if slot.state == "prefill":
-                n = min(self.prefill_chunk, slot.prompt_len - slot.fill)
-                tok[i, :n] = slot.prompt[slot.fill:slot.fill + n]
-                nvalid[i] = n
-                slot.advance = n
-                prefill_tokens += n
-                live.append(i)
-                continue
-            gen = req.gen
-            if slot.unfed:
-                # a residual-resampled token: already committed and
-                # EOS-checked last round, its KV write happens now
+            if slot.next_logits is None:
+                # the last commit appended a token with no logits behind
+                # it (a speculative round's resample): it was counted
+                # and EOS-checked there, its KV write happens now
                 nxt = slot.tokens[-1]
-                slot.unfed = False
             else:
                 nxt = self._pick(slot)
                 slot.tokens.append(nxt)
                 slot.produced += 1
                 req.token_times.append(now)
                 self.metrics.inc("tokens_out")
+                gen = req.gen
                 eos = gen.get("eos_token_id")
                 if (eos is not None and nxt == eos) or \
                         slot.produced >= gen.get("max_new_tokens", 16):
                     self._evict(i)
                     continue
-            s_i = min(self.spec_len,
-                      gen.get("max_new_tokens", 16) - slot.produced)
-            plan.append((i, slot, nxt, s_i))
+            tok[i, 0] = nxt
+            nvalid[i] = 1
+            slot.advance = 1
             live.append(i)
         return prefill_tokens
-
-    def _run_draft(self, work):
-        """Drive the ONE compiled draft micro-step until every working
-        slot has caught its draft cache up to the committed sequence and
-        sampled its proposals. Each iteration batches one [max_slots,
-        spec_len+1] call: catch-up slots feed their next committed
-        segment, proposing slots feed their latest proposal; idle rows
-        route beyond the table so their writes land in the null block.
-        Successful feeds are logged to `slot.fed` AFTER the call
-        returns, so a mid-phase fault leaves bookkeeping consistent
-        with what actually landed in the draft pools."""
-        width = self._draft_chunk
-        idle_pos = self.blocks_per_slot * self.block_size
-        qlast: dict = {}
-        limit = -(-self.max_seq_len // width) + self.spec_len + 4
-        for _ in range(limit):
-            dtok = np.zeros((self.max_slots, width), np.int32)
-            dpos = np.full((self.max_slots,), idle_pos, np.int32)
-            dnval = np.ones((self.max_slots,), np.int32)
-            feeds: dict = {}
-            for i, slot, s_i in work:
-                base = slot.dfill + len(slot.fed)
-                target = slot.tokens
-                if base < len(target):
-                    n = min(width, len(target) - base)
-                    seg = target[base:base + n]
-                    dtok[i, :n] = seg
-                    dpos[i] = base
-                    dnval[i] = n
-                    feeds[i] = (slot, seg)
-                elif s_i and len(slot.drafted) < s_i:
-                    d = self._draft_pick(slot, qlast[i])
-                    slot.drafted.append(d)
-                    # the FINAL proposal is never fed back: no later
-                    # proposal conditions on it, verify recomputes p
-                    if len(slot.drafted) < s_i:
-                        dtok[i, 0] = d
-                        dpos[i] = base
-                        dnval[i] = 1
-                        feeds[i] = (slot, [d])
-            if not feeds:
-                return
-            with profiler.RecordEvent("serving.draft", cat="serving"):
-                lv = self._dispatch_draft(dtok, dpos, dnval)
-            lv = np.asarray(lv)
-            for i, (slot, seg) in feeds.items():
-                slot.fed.extend(int(t) for t in seg)
-                qlast[i] = lv[i]
-        raise RuntimeError(
-            f"draft catch-up did not converge in {limit} micro-steps")
-
-    def _dispatch_draft(self, tok, pos, nvalid):
-        """One call of the compiled draft micro-step; the draft pools
-        are donated to it and rebound to its outputs (only the loop's
-        thread ever reads them)."""
-        import jax.numpy as jnp
-
-        lv, self._dpools = self._draft(
-            self._dvalues, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(nvalid), jnp.asarray(self._bt), self._dpools)
-        return lv
-
-    def _draft_pick(self, slot, qrow):
-        """Sample one proposal from the draft distribution, recording
-        the warped probs (sampling requests) for accept/reject."""
-        gen = slot.req.gen
-        if not gen.get("do_sample"):
-            slot.qdists.append(None)
-            return int(qrow.argmax())
-        p = self._warp_probs(qrow, gen)
-        slot.qdists.append(p)
-        return int(slot.rng.choice(p.size, p=p))
-
-    def _advance_dfill(self, slot):
-        """Advance the draft-cache coverage mark exactly as far as this
-        round's feeds agree with the (post-commit) token sequence:
-        committed catch-up and ACCEPTED proposals advance it, a
-        rejected suffix or degraded round stops it — the next round's
-        catch-up rewrites from there. Clears the round scratch."""
-        base, fed, seq = slot.dfill, slot.fed, slot.tokens
-        j = 0
-        while j < len(fed) and base + j < len(seq) \
-                and fed[j] == seq[base + j]:
-            j += 1
-        slot.dfill = base + j
-        slot.fed = []
-        slot.drafted = []
-        slot.qdists = []
-
-    def _commit_spec(self, i, slot, lv_i, sv_i, now):
-        """Host-side accept/commit for one slot after a verify step;
-        every token it commits is stamped `now`, the read-back's end.
-        Greedy: accept the longest prefix of proposals that match the
-        verify argmaxes, then hand the first-mismatch logits row to the
-        NEXT round's `_pick` — every emitted token is an argmax of the
-        same logits the plain engine would compute, hence bitwise
-        parity. Sampling: Leviathan accept / residual-resample through
-        the identical `_warp_probs` transform (`speculative_accept`).
-        All staged positions were already scattered into the paged pool
-        in bulk by the verify step; `self._pos` advances only over the
-        committed prefix, and the garbage KV above it is overwritten by
-        the next round's staging before any row can attend it."""
-        props = slot.spec_staged
-        slot.spec_staged = []
-        gen = slot.req.gen
-        eos = gen.get("eos_token_id")
-        max_new = gen.get("max_new_tokens", 16)
-        s = len(props)
-        L = int(self._pos[i])   # position nxt was written at
-        if s == 0:
-            # plain-decode round (spec budget exhausted or degraded)
-            self._pos[i] = L + 1
-            slot.next_logits = lv_i
-            self._advance_dfill(slot)
-            return
-        if not gen.get("do_sample"):
-            a = 0
-            while a < s and int(sv_i[a].argmax()) == props[a]:
-                a += 1
-            resampled = None
-            # rejection: sv_i[a] is p(. | accepted prefix) — the next
-            # _pick's argmax IS the rejection token; all-accept: the
-            # bonus row
-            nl = sv_i[a] if a < s else sv_i[s]
-        else:
-            p_list = [self._warp_probs(sv_i[j], gen) for j in range(s)]
-            a, resampled = speculative_accept(p_list, slot.qdists[:s],
-                                              props, slot.rng)
-            nl = None if resampled is not None else sv_i[s]
-        self.metrics.observe_spec(i, s, a)
-        finished = False
-        m = 0
-        for t in props[:a]:
-            slot.tokens.append(int(t))
-            slot.produced += 1
-            slot.req.token_times.append(now)
-            self.metrics.inc("tokens_out")
-            m += 1
-            if (eos is not None and t == eos) or \
-                    slot.produced >= max_new:
-                finished = True
-                break
-        self._pos[i] = L + 1 + m
-        self._advance_dfill(slot)
-        if finished:
-            self._evict(i)
-            return
-        if resampled is not None:
-            slot.tokens.append(int(resampled))
-            slot.produced += 1
-            slot.req.token_times.append(now)
-            self.metrics.inc("tokens_out")
-            slot.next_logits = None
-            slot.unfed = True
-            if (eos is not None and resampled == eos) or \
-                    slot.produced >= max_new:
-                slot.unfed = False
-                self._evict(i)
-            return
-        slot.next_logits = nl
 
     # -- serve loop ---------------------------------------------------------
 
@@ -1965,8 +1558,7 @@ class SlotEngine:
         with guard:
             while True:
                 self._beat()
-                self._drain_adoptions()
-                self._drain_adapter_swaps()
+                self._drain_boundary_calls()
                 if self._abort.is_set():
                     self._fail_all_active(
                         self._abort_error or RequestCancelled(

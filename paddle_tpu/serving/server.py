@@ -87,11 +87,10 @@ class Server:
         self._warmup = warmup
         self.router = None
         self.tenancy = tenancy
-        if mode == "generate" and (replicas > 1 or fleet is not None):
+        if mode == "generate":
             if model is None:
                 raise ValueError("generate mode needs a GPT model")
-            from .fleet import Router
-
+            # every engine's options, the one engine's or each replica's
             engine_kw = dict(
                 max_slots=max_slots, max_seq_len=max_seq_len,
                 block_size=block_size, num_blocks=num_blocks,
@@ -101,6 +100,10 @@ class Server:
                 quantize=quantize, w8a8=w8a8, mesh=mesh,
                 spill_dir=spill_dir, max_adapters=max_adapters,
                 lora_rank=lora_rank)
+            self.batcher = None
+        if mode == "generate" and (replicas > 1 or fleet is not None):
+            from .fleet import Router
+
             fleet_kw = dict(fleet or {})
             if tenancy is not None:
                 fleet_kw.setdefault("tenancy", tenancy)
@@ -109,10 +112,7 @@ class Server:
                 metrics=self.metrics, queue_cap=queue_cap,
                 warmup=warmup, **fleet_kw)
             self.engine = None
-            self.batcher = None
         elif mode == "generate":
-            if model is None:
-                raise ValueError("generate mode needs a GPT model")
             from .queueing import AdmissionQueue, TenantFairQueue
 
             cap = queue_cap or flag("FLAGS_serving_queue_cap")
@@ -121,17 +121,8 @@ class Server:
                                         metrics=self.metrics)
             else:
                 queue = AdmissionQueue(cap, metrics=self.metrics)
-            self.engine = SlotEngine(
-                model, max_slots=max_slots, max_seq_len=max_seq_len,
-                block_size=block_size, num_blocks=num_blocks,
-                prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
-                cache_dtype=cache_dtype, metrics=self.metrics,
-                queue=queue, strict_shapes=strict_shapes,
-                spec_len=spec_len, draft_model=draft_model,
-                quantize=quantize, w8a8=w8a8, mesh=mesh,
-                spill_dir=spill_dir, max_adapters=max_adapters,
-                lora_rank=lora_rank)
-            self.batcher = None
+            self.engine = SlotEngine(model, metrics=self.metrics,
+                                     queue=queue, **engine_kw)
         elif mode == "batch":
             target = fn if fn is not None else model
             if target is None or not callable(target):

@@ -321,8 +321,8 @@ def test_named_scopes_reach_the_lowered_step(latent):
                              block_size=8, prefill_chunk=4)
     tok = jnp.zeros((2, 4), jnp.int32)
     vec = jnp.zeros((2,), jnp.int32)
-    text = eng._decode.lower(eng._values, tok, vec, vec,
-                             jnp.asarray(eng._bt), eng._pools) \
+    batch, extras = eng._stage(tok, vec, vec)
+    text = eng._decode.lower(eng._values, batch, eng._pools, extras) \
         .as_text(debug_info=True)
     for scope in ("latent.attend", "moe.route", "moe.experts",
                   "moe.shared"):
@@ -399,7 +399,7 @@ def test_side_paths_carry_a_latent_block_or_refuse_it_by_name(
         assert kv.export_prefix_blocks(prompt)["row_order"] == "thd"
     elif path == "draft":
         spec = serving.SlotEngine(model, spec_len=2, **kw)
-        assert spec._dlayout.row_order == "tc"
+        assert spec._spec.layout.row_order == "tc"
         spec.warmup()
         _, answer = _stepped(spec, prompt, 5)
         np.testing.assert_array_equal(answer, want)

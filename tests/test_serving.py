@@ -399,6 +399,64 @@ def test_slot_engine_compiles_exactly_once_total(server):
     assert not any(isinstance(k, tuple) for k in counts)
 
 
+# what each engine configuration's step takes and returns: the keys of
+# the staged `batch`, of `extras`, and of the returned `out`
+_BATCH = {"tok", "pos", "nvalid", "tables"}
+_SEAM = {
+    "plain": ({}, _BATCH, set(), {"logits", "aux"}),
+    "spec_k2_self_draft": ({"spec_len": 2}, _BATCH, set(),
+                           {"logits", "aux", "verify"}),
+    "adapters": ({"max_adapters": 3, "lora_rank": 2}, _BATCH | {"aid"},
+                 {"lora_a", "lora_b"}, {"logits", "aux"}),
+    "int8_w8a8": ({"quantize": True, "w8a8": True}, _BATCH,
+                  {"act_scale"}, {"logits", "aux", "amax"}),
+    "mesh_dp1_mp2": ({"mesh": "dp1.mp2"}, _BATCH, set(),
+                     {"logits", "aux"}),
+}
+
+
+@pytest.mark.parametrize("config", sorted(_SEAM))
+def test_step_contract_and_one_trace_a_program(gpt, config):
+    """Every engine configuration has ONE signature of the compiled
+    step: `_stage` names what goes in, the step names what comes out,
+    an option adds keys and moves nothing. `warmup()` and twenty mixed
+    steps (chunked prefill beside decode, greedy beside sampling) then
+    leave each program traced exactly once."""
+    import jax
+
+    kw, batch_keys, extras_keys, out_keys = _SEAM[config]
+    if "mesh" in kw and len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    eng = serving.SlotEngine(gpt, max_slots=2, block_size=8,
+                             prefill_chunk=8, **kw)
+    programs = {"decode": 1, "cow": 1}
+    if "spec_len" in kw:
+        programs["draft"] = 1
+    assert eng.warmup() == programs
+    with observe.no_retrace():
+        vec = np.zeros((2,), np.int32)
+        batch, extras = eng._stage(np.zeros((2, 8), np.int32), vec, vec)
+        assert set(batch) == batch_keys and set(extras) == extras_keys
+        out, eng._pools = eng._decode(eng._values, batch, eng._pools,
+                                      extras)
+        assert set(out) == out_keys
+        assert out["logits"].shape == (2, VOCAB)
+        futs = [eng.submit(_prompt(300 + n, n), max_new_tokens=m,
+                           timeout=None, do_sample=bool(n % 2), seed=n,
+                           adapter_id=n % 3 if "max_adapters" in kw else 0)
+                for n, m in ((5, 16), (19, 20), (11, 14), (26, 18))]
+        eng._admit()
+        while eng.active or eng.queue.depth:
+            eng._step()
+            eng._admit()
+        for f in futs:
+            assert f.result(5).size == f.payload.size \
+                + f.gen["max_new_tokens"]
+    assert eng.metrics.get("steps") >= 20
+    assert eng.metrics.get("step_errors") == 0
+    assert eng.compile_counts == programs
+
+
 def test_submit_validates_lengths(server):
     with pytest.raises(ValueError):
         server.submit(np.arange(60), max_new_tokens=10)  # > max_seq_len
@@ -734,7 +792,7 @@ def test_step_that_raises_leaves_a_serving_engine(gpt, when):
             out = real(*args)                 # dispatched: inputs gone
             if when == "after":
                 raise RuntimeError("device fell over")
-            return (_Unreadable(),) + tuple(out[1:])
+            return {**out[0], "logits": _Unreadable()}, out[1]
 
         eng._decode = broken
         fut = srv.submit(_prompt(91, 4), max_new_tokens=8, timeout=120)
@@ -1208,9 +1266,15 @@ def test_fleet_brownout_sheds_by_priority_and_clamps(gpt, fleet):
 def test_fleet_brownout_auto_enters_and_exits(gpt):
     """Hysteresis: load above brownout_high trips brownout
     automatically; drained load below brownout_low clears it."""
+    # the four requests land within a millisecond, before either
+    # engine's loop has moved one from its queue into its slot: each
+    # replica's queue has to hold both of its two, or admission (not
+    # brownout, which sheds nothing of priority 5) rejects the overflow
+    # with its fast 429. Capacity 2 x (1 slot + 2 queued) = 6, and 4 in
+    # flight is 0.67 of it
     router = Router(gpt, replicas=2,
                     engine_kw=dict(max_slots=1, block_size=8),
-                    hedge=False, queue_cap=1, tick_s=0.002,
+                    hedge=False, queue_cap=2, tick_s=0.002,
                     brownout_high=0.4, brownout_low=0.1,
                     liveness_timeout_s=30.0, name="bo").start()
     try:
@@ -1226,6 +1290,7 @@ def test_fleet_brownout_auto_enters_and_exits(gpt):
             assert router.metrics.get("brownout_entries") >= 1
             for f in futs:
                 f.result(120)
+            assert router.metrics.get("brownout_sheds") == 0
         deadline = time.monotonic() + 10
         while router.brownout_active and time.monotonic() < deadline:
             time.sleep(0.005)

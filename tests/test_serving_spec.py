@@ -22,6 +22,8 @@ from __future__ import annotations
 import json
 import subprocess
 import sys
+import threading
+import time
 from pathlib import Path
 
 import numpy as np
@@ -30,7 +32,7 @@ import pytest
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
-from paddle_tpu import observe, serving
+from paddle_tpu import observe, profiler, serving
 from paddle_tpu.framework import faults
 from paddle_tpu.nlp.transformers import GPTConfig, GPTForPretraining
 from paddle_tpu.ops import quant_ops
@@ -39,7 +41,7 @@ from paddle_tpu.quantization import (
     quantize_state_int8,
 )
 from paddle_tpu.serving import positions_to_rows
-from paddle_tpu.serving.engine import speculative_accept
+from paddle_tpu.serving.speculation import speculative_accept
 
 REPO = Path(__file__).resolve().parent.parent
 VOCAB = 97
@@ -235,14 +237,14 @@ def test_spec_step_and_draft_update_their_pools_in_place(gpt, draft_gpt):
                              prefill_chunk=8, spec_len=3,
                              draft_model=draft_gpt)
     assert eng._pools[0][0].shape == (eng.num_blocks, 8, 4, 8)
-    assert eng._dpools[0][0].shape == (eng.num_blocks, 8, 2, 8)
-    built = eng._arrays(eng._pools) + eng._arrays(eng._dpools)
+    assert eng._spec.pools[0][0].shape == (eng.num_blocks, 8, 2, 8)
+    built = eng._arrays(eng._pools) + eng._arrays(eng._spec.pools)
     eng.warmup()
     assert all(a.is_deleted() for a in built)
     fut = eng.submit(_prompt(41, 7), max_new_tokens=8, timeout=None)
     eng._admit()
     drafts = []
-    real = eng._draft
+    real = eng._spec._draft
 
     def watched(*args):
         handed = eng._arrays(args[5])
@@ -250,19 +252,39 @@ def test_spec_step_and_draft_update_their_pools_in_place(gpt, draft_gpt):
         drafts.append(all(a.is_deleted() for a in handed))
         return out
 
-    eng._draft = watched
+    eng._spec._draft = watched
     while eng.active:
         target, n = eng._arrays(eng._pools), eng.metrics.get("steps")
         eng._step()
         assert all(a.is_deleted() for a in target) \
             == (eng.metrics.get("steps") > n)
-        live = eng._arrays(eng._pools) + eng._arrays(eng._dpools)
+        live = eng._arrays(eng._pools) + eng._arrays(eng._spec.pools)
         assert not any(a.is_deleted() for a in live)
     want, _ = _drive(_engine(gpt), _prompt(41, 7), max_new=8)
     np.testing.assert_array_equal(fut.result(5), want)
     assert drafts and all(drafts)
     assert eng.metrics.get("pool_inplace_steps") == \
         eng.metrics.get("steps") > 0
+
+
+def test_plain_engine_passes_no_speculative_site_and_spans_no_draft(gpt):
+    """An engine that holds no speculation consumes no occurrence of
+    `serving.draft` / `serving.verify` (a schedule that raises at every
+    one of them never fires) and puts no `step.draft` / `serving.draft`
+    span in the ring; its steps still span sample and commit."""
+    plain = _engine(gpt)
+    since = time.perf_counter() * 1e6
+    with faults.ChaosSchedule("serving.draft@1-:raise",
+                              "serving.verify@1-:raise") as ch:
+        got, _ = _drive(plain, _prompt(47, 9), max_new=6)
+        assert ch.fired() == {"serving.draft": 0, "serving.verify": 0}
+    assert got.size == 15 and plain.metrics.get("step_errors") == 0
+    names = [e["name"] for e in profiler.events()
+             if e["ts"] >= since and e["tid"] == threading.get_ident()]
+    assert "step.draft" not in names and "serving.draft" not in names
+    # the last iteration samples, finishes its one slot and steps nothing
+    assert names.count("step.sample") - 1 == names.count("step.commit") \
+        == plain.metrics.get("steps") > 0
 
 
 def test_draft_call_that_raises_after_dispatch_degrades_the_round(gpt):
@@ -274,7 +296,7 @@ def test_draft_call_that_raises_after_dispatch_degrades_the_round(gpt):
     spec = _engine(gpt, spec_len=3)
     p = _prompt(43, 9)
     want, _ = _drive(plain, p, max_new=10)
-    real, calls = spec._draft, []
+    real, calls = spec._spec._draft, []
 
     def broken(*args):
         out = real(*args)
@@ -283,12 +305,12 @@ def test_draft_call_that_raises_after_dispatch_degrades_the_round(gpt):
             raise RuntimeError("device fell over")
         return out
 
-    spec._draft = broken
+    spec._spec._draft = broken
     got, _ = _drive(spec, p, max_new=10)
     np.testing.assert_array_equal(got, want)
     assert spec.metrics.get("spec_draft_faults") == 1
     assert spec.metrics.get("step_errors") == 0
-    assert not any(a.is_deleted() for a in spec._arrays(spec._dpools))
+    assert not any(a.is_deleted() for a in spec._arrays(spec._spec.pools))
     # drafting went on after the fault, on the rebuilt pools
     assert len(calls) > 3
     assert spec.metrics.snapshot()["speculative"]["acceptance_rate"] == 1.0

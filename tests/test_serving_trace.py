@@ -474,7 +474,7 @@ def test_jitted_programs_say_which_program_ran(gpt):
     eng = _engine(gpt, spec_len=2, prefill_chunk=8)
     assert eng._decode.__name__ == "serving_step"
     assert eng._cow.__name__ == "serving_cow"
-    assert eng._draft.__name__ == "serving_draft"
+    assert eng._spec._draft.__name__ == "serving_draft"
 
     from paddle_tpu import nn
     from paddle_tpu.engine import Engine
