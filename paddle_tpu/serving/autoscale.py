@@ -224,10 +224,13 @@ class Autoscaler:
             except Exception:  # noqa: BLE001 — fleet keeps serving
                 self.router.metrics.inc("scale_failures")
 
-        self._scale_thread = threading.Thread(
+        # published only once started: another thread that sees it may
+        # join it
+        thread = threading.Thread(
             target=build, name=f"{self.router.name}-scale-up",
             daemon=True)
-        self._scale_thread.start()
+        thread.start()
+        self._scale_thread = thread
 
     def _scale_down(self, now, sig):
         rs = self.router.replica_set

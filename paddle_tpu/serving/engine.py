@@ -38,6 +38,14 @@ allocation and SGLang-style prefix sharing:
   the program is still one). The dense model returns the turns it ran
   in `aux`: counters `attn_key_tiles` of `attn_key_tiles_max`, their
   ratio the share of the table the steps read.
+- Little crosses between host and device a step. Everything the host
+  says goes in as one int32 array (`_stage`); the step picks each
+  slot's greedy token itself (`out["pick"]`, after any adapter delta)
+  and the host reads back 4 bytes a slot and the model's counts. The
+  logits stay on the device: a slot carries a handle (`_LogitRow`)
+  that fetches its one row for a sampling request or a check. The
+  counters `device_picks`, `logit_rows_fetched` and `readback_bytes`
+  say what crossed.
 - Prefix sharing: finished sequences index their fully written blocks
   in a radix `PrefixCache` keyed on cumulative token-prefix hashes.
   A new request reuses every matching block physically (refcounted),
@@ -110,10 +118,10 @@ an iteration (`observe.span` / `observe.phase`: a `RecordEvent`, hence
 on a profiler capture's clock, plus a timeline aggregate):
 ``serving.loop`` round a working iteration, ``loop.idle`` round the
 wait when nothing is live or queued; inside an iteration ``step.admit``,
-``step.sample``, then ``serving.step`` round ``step.dispatch`` (host
-arrays staged, the jit call) and ``step.readback`` (the wait for the
-logits), then ``step.commit``. The dispatch -> read-back interval is
-the `decode` / `prefill` series' sample and the timeline's
+``step.sample``, then ``serving.step`` round ``step.dispatch`` (the
+host's one array built, the jit call) and ``step.readback`` (the wait
+for the step's picks), then ``step.commit``. The dispatch -> read-back
+interval is the `decode` / `prefill` series' sample and the timeline's
 ``device-step``. A request's own stamps (queueing.Request) are folded
 once, when it leaves its slot with an answer: into the series `ttft`,
 `prefill_req`, `itl`, and into the profiler ring as ``request.queue``,
@@ -166,13 +174,37 @@ class _Slot:
         self.state = "prefill" if fill < self.prompt_len else "decode"
         self.advance = 0        # positions this step will write
         self.produced = 0
-        self.next_logits = None  # np [V] feeding the next pick
+        # what the next pick reads: the step's own pick of this slot's
+        # row (the argmax, taken on the device) and a `_LogitRow` that
+        # fetches the row itself for whoever asks (a sampling request,
+        # a check). A speculative round may leave a host row with no
+        # pick behind it, or None (a resample: nothing to pick from)
+        self.next_token = None
+        self.next_logits = None
         self.rng = None
         if req.gen.get("do_sample"):
             self.rng = np.random.RandomState(req.gen.get("seed", 0))
         # a speculative engine's draft-side state for this slot
         # (speculation._SlotDraft); None on a plain one
         self.spec = None
+
+
+class _LogitRow:
+    """One slot's row of a step's logits, which stay on the device: a
+    new one a commit, so two steps' rows tell apart by identity.
+    `np.asarray(row)` brings that one row to the host (`[V]` float32,
+    what the step handed to sampling) and counts it in
+    `logit_rows_fetched`; nothing crosses until somebody asks."""
+
+    __slots__ = ("_logits", "_slot", "_metrics")
+
+    def __init__(self, logits, slot, metrics):
+        self._logits, self._slot, self._metrics = logits, slot, metrics
+
+    def __array__(self, dtype=None, copy=None):
+        row = np.asarray(self._logits[self._slot])
+        self._metrics.inc("logit_rows_fetched")
+        return row if dtype is None else row.astype(dtype, copy=False)
 
 
 class SlotEngine:
@@ -429,17 +461,35 @@ class SlotEngine:
 
             serving_step(values, batch, pools, extras) -> (out, pools)
 
-        `batch` holds the host-staged arrays (`tok`, `pos`, `nvalid`,
-        `tables`; `aid` on an engine with adapters), `extras` the
-        device-resident arguments that are not weights (`act_scale`
-        under w8a8; `lora_a`, `lora_b` with adapters; empty on a plain
-        engine), `out` what the host reads back: `logits`, `aux`,
-        `verify` on a speculative engine (`verify_cols` > 0: the first
-        k+1 columns' logits) and `amax` under w8a8. What an engine does
+        `batch` is everything the host says a step: ONE int32 array, a
+        row a slot, whose columns (`_batch_cols`) are the token chunk,
+        `pos`, `nvalid`, the slot's block table and, on an engine with
+        adapters, `aid`; the step slices it apart in its first lines.
+        `extras` holds the device-resident arguments that are not
+        weights (`act_scale` under w8a8; `lora_a`, `lora_b` with
+        adapters; empty on a plain engine). `out` is what the step
+        leaves on the device: `pick` (int32 `[max_slots]`, the argmax
+        of each slot's logits row, adapter delta included) and `aux`,
+        which the host reads back every step, `verify` on a speculative
+        engine (`verify_cols` > 0: the first k+1 columns' logits, read
+        back too), `logits` (float32 `[max_slots, V]`, never read back
+        whole: a `_LogitRow` fetches one row when somebody asks) and
+        `amax` under w8a8 (folded on the device). What an engine does
         not have is absent from the trees, so each configuration has
         exactly one signature and traces once. The pools are donated."""
         import jax
         import jax.numpy as jnp
+
+        # where `_stage` puts, and the step finds, each of the host's
+        # arguments in a slot's row of `batch`
+        chunk = self.prefill_chunk
+        end = chunk + 2 + self.blocks_per_slot
+        self._batch_cols = {"tok": slice(0, chunk), "pos": chunk,
+                            "nvalid": chunk + 1,
+                            "tables": slice(chunk + 2, end)}
+        if self.max_adapters:
+            self._batch_cols["aid"] = end
+        self._batch_width = end + bool(self.max_adapters)
 
         def _head(m, values, hrows, act_scale=None):
             """Project hidden rows (.., H) to f32 logits (.., V): the
@@ -478,8 +528,10 @@ class SlotEngine:
             return (out[:, 0, :] if squeeze else out).astype(jnp.float32)
 
         def serving_step(values, batch, pools, extras):
-            tok, pos, nvalid = batch["tok"], batch["pos"], batch["nvalid"]
-            tables, aid = batch["tables"], batch.get("aid")
+            cols = {name: batch[:, at]
+                    for name, at in self._batch_cols.items()}
+            tok, pos, nvalid = cols["tok"], cols["pos"], cols["nvalid"]
+            tables, aid = cols["tables"], cols.get("aid")
             act_scale = extras.get("act_scale")
             la, lb = extras.get("lora_a"), extras.get("lora_b")
             # trace-time only: the compile counter + retrace registry
@@ -532,6 +584,13 @@ class SlotEngine:
                     if verify_cols:
                         out["verify"] = out["verify"] + lora_logits_delta(
                             hv[:, :verify_cols], aid, la, lb)
+                # the greedy token of every slot, taken here so that 4
+                # bytes a slot cross to the host and not a row of V
+                # floats; after the adapter delta, so a tenant's adapter
+                # still decides its token. The first maximum, as
+                # np.argmax on the host took it
+                out["pick"] = jnp.argmax(out["logits"], axis=-1) \
+                    .astype(jnp.int32)
                 return out, new_pools
 
             return functional_apply(self.model, fvals, run, mesh=self.mesh)
@@ -711,16 +770,23 @@ class SlotEngine:
 
     def _stage(self, tok, pos, nvalid):
         """The step's `batch` and `extras` (see `_build_programs`) for
-        one call: the host's arrays staged on the device, the block
-        tables with them. Warmup, the step and the tests all come
-        through here, so jax.jit sees exactly one signature per engine
-        configuration — the compile-once invariant survives any mix of
-        the w8a8 and adapter options."""
+        one call: the host's arrays, the block tables and the adapter
+        rows with them, written into ONE fresh int32 array, which the
+        jit call takes as it is (numpy) and moves to the device in one
+        transfer of its own. (Staged here with `jnp.asarray` it costs
+        0.2 ms a step more on the v5e, four arrays 0.6-0.8 ms more:
+        PERF.md, PR 32.) Fresh each call, so nothing the host writes
+        later can reach a step in flight. Warmup, the step and the
+        tests all come through here, so jax.jit sees exactly one
+        signature per engine configuration — the compile-once
+        invariant survives any mix of the w8a8 and adapter options."""
         import jax.numpy as jnp
 
-        batch = {"tok": jnp.asarray(tok), "pos": jnp.asarray(pos),
-                 "nvalid": jnp.asarray(nvalid),
-                 "tables": jnp.asarray(self._bt)}
+        said = {"tok": tok, "pos": pos, "nvalid": nvalid,
+                "tables": self._bt, "aid": self._aid}
+        batch = np.empty((self.max_slots, self._batch_width), np.int32)
+        for name, at in self._batch_cols.items():
+            batch[:, at] = said[name]
         extras = {}
         if self.w8a8:
             # 0 degrades the step to the weights-only dequant path
@@ -728,7 +794,6 @@ class SlotEngine:
             extras["act_scale"] = jnp.zeros((), jnp.float32) \
                 if self._w8a8_degraded else self._act_scale
         if self.max_adapters:
-            batch["aid"] = jnp.asarray(self._aid)
             extras["lora_a"], extras["lora_b"] = self._lora_a, self._lora_b
         return batch, extras
 
@@ -827,7 +892,7 @@ class SlotEngine:
     def warmup(self, mesh=None):
         """Trace the unified step and the CoW copy before traffic so the
         hot path never compiles. All tables point at the null block, so
-        the dummy step's writes land in reserved scratch; the logits are
+        the dummy step's writes land in reserved scratch; its outputs are
         discarded, the pools (donated, like in any step) rebound.
         Returns `compile_counts`.
 
@@ -838,8 +903,6 @@ class SlotEngine:
         under `observe.no_retrace()`: same shapes + same mesh = zero new
         compiles for engine life."""
         import contextlib
-
-        import jax.numpy as jnp
 
         if mesh is not None:
             from .sharding import mesh_spec_of, resolve_mesh
@@ -853,10 +916,9 @@ class SlotEngine:
         guard = observe.no_retrace() if self._warmed \
             else contextlib.nullcontext()
         with guard:
-            tok = jnp.zeros((self.max_slots, self.prefill_chunk),
-                            jnp.int32)
-            pos = jnp.zeros((self.max_slots,), jnp.int32)
-            nvalid = jnp.ones((self.max_slots,), jnp.int32)
+            tok = np.zeros((self.max_slots, self.prefill_chunk), np.int32)
+            pos = np.zeros((self.max_slots,), np.int32)
+            nvalid = np.ones((self.max_slots,), np.int32)
             self._dispatch(tok, pos, nvalid)
             self._copy_block(NULL_BLOCK, NULL_BLOCK)
             if self._spec is not None:
@@ -1264,13 +1326,20 @@ class SlotEngine:
         return p
 
     def _pick(self, slot: _Slot):
-        """Next token from the slot's pending logits (host-side so each
-        request carries its own sampling config)."""
-        logits = slot.next_logits
+        """The slot's next token. A greedy request takes the step's
+        own pick of its row (`next_token`, counted in `device_picks`):
+        nothing of the row crosses to the host. A sampling request
+        fetches its row through the handle and samples here, so each
+        request carries its own sampling config and rng stream. A row
+        that speculation handed over is on the host already and has no
+        pick behind it: its argmax is taken here."""
         gen = slot.req.gen
         if not gen.get("do_sample"):
-            return int(logits.argmax())
-        p = self._warp_probs(logits, gen)
+            if slot.next_token is None:
+                return int(slot.next_logits.argmax())
+            self.metrics.inc("device_picks")
+            return slot.next_token
+        p = self._warp_probs(np.asarray(slot.next_logits), gen)
         return int(slot.rng.choice(p.size, p=p))
 
     def _evict(self, idx, error=None):
@@ -1383,19 +1452,19 @@ class SlotEngine:
             self._observe_step_latency(done - t0, prefill_tokens,
                                        len(live) - n_pref)
             self._count_computed(live, nvalid, out["aux"])
-            logits = out["logits"]
+            logits, pick = out["logits"], out["pick"]
             for i in live:
                 slot = self._slots[i]
                 self._pos[i] += slot.advance
                 if slot.state == "prefill":
                     slot.req.prefill_steps += 1
                     slot.fill += slot.advance
-                    if slot.fill >= slot.prompt_len:
-                        slot.state = "decode"
-                        slot.next_logits = logits[i]
-                        self.metrics.inc("prefills")
-                else:
-                    slot.next_logits = logits[i]
+                    if slot.fill < slot.prompt_len:
+                        continue
+                    slot.state = "decode"
+                    self.metrics.inc("prefills")
+                slot.next_token = int(pick[i])
+                slot.next_logits = _LogitRow(logits, i, self.metrics)
             if spec is not None:
                 spec.commit(out["verify"], done)
             self.metrics.inc("steps")
@@ -1407,11 +1476,12 @@ class SlotEngine:
 
     def _device_step(self, tok, nvalid):
         """The iteration's one dispatch of the compiled step and the
-        read-back of what the host needs of it. Returns the step's
-        `out` as host arrays (the logits, what the model's step counted
-        in `aux`, a speculative engine's verify logits: one blocking
-        transfer) and the clock before the dispatch and after the
-        read-back.
+        read-back of what the host needs of it: each slot's `pick`,
+        what the model's step counted in `aux`, a speculative engine's
+        verify logits (one blocking transfer, its size counted in
+        `readback_bytes`). Returns the step's `out` as host arrays but
+        for `logits`, which stay the device's, and the clock before the
+        dispatch and after the read-back.
 
         The step is handed the pools and updates them in place: the
         arrays that went in read `is_deleted()` afterwards, which
@@ -1426,10 +1496,11 @@ class SlotEngine:
                 with observe.phase("dispatch", cat="serving"):
                     out = self._dispatch(tok, self._pos, nvalid)
                 with observe.phase("readback", cat="serving"):
+                    logits = out.pop("logits")
                     out = jax.device_get(out)
         except Exception:
             if self._pools is not pools:
-                # dispatched, and its logits cannot be read: what it
+                # dispatched, and its picks cannot be read: what it
                 # left in place of the pools is no KV to serve from
                 for a in self._arrays(self._pools):
                     a.delete()
@@ -1437,10 +1508,13 @@ class SlotEngine:
         done = time.monotonic()
         if all(a.is_deleted() for a in self._arrays(pools)):
             self.metrics.inc("pool_inplace_steps")
+        self.metrics.inc("readback_bytes", sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(out)))
+        out["logits"] = logits
         return out, t0, done
 
     def _observe_step_latency(self, dt, prefill_tokens, n_decoding):
-        """Attribute one device step, dispatch to the logits on the
+        """Attribute one device step, dispatch to its picks on the
         host, to the phase-latency series: a step staging prompt tokens
         is a 'prefill' sample, a step advancing at least one decoding
         slot is a 'decode' sample (a mixed colocated step is honestly
@@ -1476,9 +1550,9 @@ class SlotEngine:
             self.metrics.inc(name, int(value.sum()))
 
     def _consume(self, now, tok, nvalid, live):
-        """Host-side half of a step: sample each decoding slot's pending
-        logits (finish/evict on EOS/max/deadline/cancel), stage the next
-        prompt chunk for prefilling slots, and fill the fixed
+        """Host-side half of a step: take each decoding slot's next
+        token (`_pick`; finish/evict on EOS/max/deadline/cancel), stage
+        the next prompt chunk for prefilling slots, and fill the fixed
         [max_slots, chunk] token matrix for the unified dispatch.
         Returns the number of prompt tokens staged this step."""
         prefill_tokens = 0

@@ -45,7 +45,12 @@ class ServingMetrics:
     whose block demand exceeds the pool), `pool_inplace_steps` (steps
     whose donated KV pools were updated in place: equals `steps`) and
     `pool_rebuilds` (a program raised after it was handed the pools;
-    the engine went on with empty ones), and the fast-decode set:
+    the engine went on with empty ones), what crosses from the device
+    a step: `device_picks` (tokens committed from the compiled step's
+    own pick, no logits read), `logit_rows_fetched` (rows of a step's
+    logits brought to the host, one at a time, for a sampling request
+    or a check) and `readback_bytes` (bytes of the step's outputs the
+    host read, summed over steps), and the fast-decode set:
     `spec_drafted_tokens` / `spec_accepted_tokens` /
     `spec_rejected_tokens` / `spec_rounds` / `spec_draft_faults`
     (speculative decoding, fed via `observe_spec`, surfaced under

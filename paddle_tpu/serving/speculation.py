@@ -296,17 +296,18 @@ class Speculation:
     def _accept(self, i, slot, sv_i, now):
         """Host-side accept for one slot after a verify step; the
         engine's commit already moved it past its picked token and
-        handed it the last column's logits. Every token committed here
-        is stamped `now`, the read-back's end.
+        handed it the last column's pick and logits. Every token
+        committed here is stamped `now`, the read-back's end.
         Greedy: accept the longest prefix of proposals that match the
-        verify argmaxes, then hand the first-mismatch logits row to the
-        NEXT round's `_pick` — every emitted token is an argmax of the
-        same logits the plain engine would compute, hence bitwise
-        parity. Sampling: Leviathan accept / residual-resample through
-        the identical `_warp_probs` transform (`speculative_accept`); a
-        resampled token is committed with no logits behind it
-        (`next_logits` None), which the engine's consume feeds instead
-        of picking.
+        verify argmaxes, then hand the first-mismatch logits row (a
+        host row of `verify`, with no pick behind it: `next_token`
+        None) to the NEXT round's `_pick` — every emitted token is an
+        argmax of the same logits the plain engine would compute,
+        hence bitwise parity. Sampling: Leviathan accept /
+        residual-resample through the identical `_warp_probs`
+        transform (`speculative_accept`); a resampled token is
+        committed with no logits behind it (`next_logits` None), which
+        the engine's consume feeds instead of picking.
         All staged positions were already scattered into the paged pool
         in bulk by the verify step; the engine's `_pos` advances only
         over the committed prefix, and the garbage KV above it is
@@ -360,9 +361,9 @@ class Speculation:
             slot.produced += 1
             slot.req.token_times.append(now)
             eng.metrics.inc("tokens_out")
-            slot.next_logits = None
+            slot.next_logits = slot.next_token = None
             if (eos is not None and resampled == eos) or \
                     slot.produced >= max_new:
                 eng._evict(i)
             return
-        slot.next_logits = nl
+        slot.next_logits, slot.next_token = nl, None

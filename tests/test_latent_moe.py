@@ -147,6 +147,45 @@ def test_prefix_hit_and_copy_on_write_on_latent_blocks(latent):
     assert eng.compile_counts == {"decode": 1, "cow": 1}
 
 
+def test_the_steps_pick_is_the_argmax_of_the_row_it_leaves_behind(latent):
+    """The latent model comes through the same step as GPT, so its
+    greedy token is picked on the device too: at every step of a run
+    in which one slot prefills 40 tokens in chunks while another
+    decodes, the token committed is the first maximum of the row the
+    slot's handle fetches, no row crosses unasked, a step reads back 4
+    bytes a slot and the experts' counts, and the answers are the
+    reference's greedy chains."""
+    cfg, model = latent
+    eng = serving.SlotEngine(model, max_slots=2, max_seq_len=128,
+                             block_size=8, prefill_chunk=16)
+    eng.warmup()
+    prompts, new = [_tokens(4, 7), _tokens(5, 40)], [12, 5]
+    futs = [eng.submit(p, max_new_tokens=n, timeout=None)
+            for p, n in zip(prompts, new)]
+    mixed = checked = 0
+    while eng.active or eng.queue.depth:
+        eng._admit()
+        live = [s for s in eng._slots if s is not None]
+        mixed += {s.state for s in live} == {"prefill", "decode"}
+        want = [(s, len(s.tokens), int(np.argmax(np.asarray(s.next_logits))))
+                for s in live if s.state == "decode"]
+        fetched = eng.metrics.get("logit_rows_fetched")
+        eng._step()
+        assert eng.metrics.get("logit_rows_fetched") == fetched
+        for s, at, token in want:
+            assert s.tokens[at] == token
+            checked += 1
+    assert mixed >= 1 and checked == sum(new)
+    for p, fut in zip(prompts, futs):
+        answer = np.asarray(fut.result(10))
+        chain = _reference(model, cfg, answer[:-1]).argmax(-1)
+        np.testing.assert_array_equal(answer[p.size:], chain[p.size - 1:])
+    m = eng.metrics
+    assert m.get("device_picks") == m.get("tokens_out") == sum(new)
+    # two slots' picks and `expert_rows` [2 expert layers, 4 held]
+    assert m.get("readback_bytes") == m.get("steps") * (2 * 4 + 2 * 4 * 4)
+
+
 def test_absorbed_attention_equals_expanded(latent):
     """One layer's attention: the expanded form over the whole sequence
     (`forward`) against the absorbed form over a paged pool

@@ -399,27 +399,30 @@ def test_slot_engine_compiles_exactly_once_total(server):
     assert not any(isinstance(k, tuple) for k in counts)
 
 
-# what each engine configuration's step takes and returns: the keys of
-# the staged `batch`, of `extras`, and of the returned `out`
+# what each engine configuration's step takes and returns: the columns
+# of a slot's row of the one staged `batch` array (beside the chunk's 8
+# token columns and the table's 8), the keys of `extras`, and the keys
+# of the returned `out`
 _BATCH = {"tok", "pos", "nvalid", "tables"}
+_OUT = {"pick", "logits", "aux"}
 _SEAM = {
-    "plain": ({}, _BATCH, set(), {"logits", "aux"}),
+    "plain": ({}, _BATCH, set(), _OUT),
     "spec_k2_self_draft": ({"spec_len": 2}, _BATCH, set(),
-                           {"logits", "aux", "verify"}),
+                           _OUT | {"verify"}),
     "adapters": ({"max_adapters": 3, "lora_rank": 2}, _BATCH | {"aid"},
-                 {"lora_a", "lora_b"}, {"logits", "aux"}),
+                 {"lora_a", "lora_b"}, _OUT),
     "int8_w8a8": ({"quantize": True, "w8a8": True}, _BATCH,
-                  {"act_scale"}, {"logits", "aux", "amax"}),
-    "mesh_dp1_mp2": ({"mesh": "dp1.mp2"}, _BATCH, set(),
-                     {"logits", "aux"}),
+                  {"act_scale"}, _OUT | {"amax"}),
+    "mesh_dp1_mp2": ({"mesh": "dp1.mp2"}, _BATCH, set(), _OUT),
 }
 
 
 @pytest.mark.parametrize("config", sorted(_SEAM))
 def test_step_contract_and_one_trace_a_program(gpt, config):
     """Every engine configuration has ONE signature of the compiled
-    step: `_stage` names what goes in, the step names what comes out,
-    an option adds keys and moves nothing. `warmup()` and twenty mixed
+    step: `_stage` puts everything the host says into one int32 array,
+    a row a slot, the step names what comes out, an option adds a
+    column or a key and moves nothing. `warmup()` and twenty mixed
     steps (chunked prefill beside decode, greedy beside sampling) then
     leave each program traced exactly once."""
     import jax
@@ -435,12 +438,22 @@ def test_step_contract_and_one_trace_a_program(gpt, config):
     assert eng.warmup() == programs
     with observe.no_retrace():
         vec = np.zeros((2,), np.int32)
-        batch, extras = eng._stage(np.zeros((2, 8), np.int32), vec, vec)
-        assert set(batch) == batch_keys and set(extras) == extras_keys
+        batch, extras = eng._stage(np.full((2, 8), 7, np.int32), vec + 5,
+                                   vec + 3)
+        assert set(eng._batch_cols) == batch_keys
+        assert set(extras) == extras_keys
+        # tok | pos | nvalid | tables (| aid), the tables null so far
+        assert batch.dtype == np.int32
+        assert batch.shape == (2, 8 + 2 + 8 + ("aid" in batch_keys))
+        np.testing.assert_array_equal(
+            batch[0, :18], [7] * 8 + [5, 3] + [NULL_BLOCK] * 8)
         out, eng._pools = eng._decode(eng._values, batch, eng._pools,
                                       extras)
         assert set(out) == out_keys
         assert out["logits"].shape == (2, VOCAB)
+        assert out["pick"].shape == (2,) and out["pick"].dtype == np.int32
+        np.testing.assert_array_equal(
+            out["pick"], np.asarray(out["logits"]).argmax(-1))
         futs = [eng.submit(_prompt(300 + n, n), max_new_tokens=m,
                            timeout=None, do_sample=bool(n % 2), seed=n,
                            adapter_id=n % 3 if "max_adapters" in kw else 0)
@@ -663,6 +676,186 @@ def test_paged_engine_matches_dense_generate_over_mixed_steps(gpt, eng):
         eng.metrics.get("steps") > 0
 
 
+# -- what crosses between the host and the device a step ----------------------
+
+
+def _parent_pick(row, gen, rng):
+    """`_pick` as it read before the step picked: the whole row on the
+    host, argmax for a greedy request, the warped draw from the
+    request's own stream for a sampling one."""
+    if not gen.get("do_sample"):
+        return int(row.argmax())
+    scaled = row / max(gen.get("temperature", 1.0), 1e-6)
+    top_k = gen.get("top_k", 0)
+    if top_k:
+        kth = np.sort(scaled)[-min(top_k, scaled.size)]
+        scaled = np.where(scaled < kth, -np.inf, scaled)
+    p = np.exp(scaled - scaled.max())
+    p /= p.sum()
+    return int(rng.choice(p.size, p=p))
+
+
+def _run_against_the_host_path(eng, futs):
+    """Step an idle engine through `futs`. Before every step, take each
+    decoding slot's pending row through its handle and pick from it as
+    the parent's host path did (a stream of our own for a sampling
+    request, seeded like the slot's); after the step, the token the
+    engine committed has to be that one. Returns how many tokens were
+    checked, by kind of request, and how many steps mixed a prefilling
+    slot with a decoding one."""
+    rngs, checked, mixed = {}, {"greedy": 0, "sampled": 0}, 0
+    while eng.active or eng.queue.depth:
+        eng._admit()
+        live = [s for s in eng._slots if s is not None]
+        mixed += {s.state for s in live} == {"prefill", "decode"}
+        want = []
+        for s in live:
+            if s.state != "decode" or s.next_logits is None:
+                continue
+            gen = s.req.gen
+            rng = rngs.setdefault(
+                s.req.id, np.random.RandomState(gen.get("seed", 0)))
+            row = np.asarray(s.next_logits)
+            assert row.shape == (VOCAB,) and row.dtype == np.float32
+            want.append((s, len(s.tokens), _parent_pick(row, gen, rng)))
+        eng._step()
+        for s, at, token in want:
+            assert s.tokens[at] == token
+            checked["sampled" if s.req.gen.get("do_sample")
+                    else "greedy"] += 1
+    for f in futs:
+        assert f.result(5).size == f.payload.size \
+            + f.gen["max_new_tokens"]
+    return checked, mixed
+
+
+def test_greedy_token_is_the_argmax_of_the_row_at_every_step(gpt, eng):
+    """The step's own pick, read back as 4 bytes a slot, is the first
+    maximum of the row the host used to read whole: at every step of a
+    run that mixes chunked prefill with decode and recycles a slot,
+    and so the whole answers are the no-cache reference's."""
+    prompts = [_prompt(170, 19), _prompt(171, 5), _prompt(172, 11)]
+    new = [6, 9, 4]
+    futs = [eng.submit(p, max_new_tokens=n, timeout=None)
+            for p, n in zip(prompts, new)]
+    checked, mixed = _run_against_the_host_path(eng, futs)
+    assert mixed >= 1 and checked == {"greedy": sum(new), "sampled": 0}
+    for p, n, fut in zip(prompts, new, futs):
+        np.testing.assert_array_equal(fut.result(5), _ref_greedy(gpt, p, n))
+    assert eng.metrics.get("device_picks") == sum(new) \
+        == eng.metrics.get("tokens_out")
+
+
+@pytest.mark.parametrize("gen", [
+    {"seed": 5}, {"seed": 6, "temperature": 0.7, "top_k": 12}],
+    ids=["plain", "warped"])
+def test_seeded_sampling_beside_greedy_slots_is_the_host_paths(gpt, gen):
+    """A sampling request fetches its row through the handle, warps it
+    and draws from its own stream exactly as when the whole batch's
+    logits came to the host: the same tokens for the same seed, with a
+    greedy request decoding in the slot beside it, whose tokens stay
+    the reference's."""
+    eng = serving.SlotEngine(gpt, max_slots=3, block_size=8,
+                             prefill_chunk=8)
+    eng.warmup()
+    greedy = [_prompt(180, 13), _prompt(181, 4)]
+    futs = [eng.submit(p, max_new_tokens=10, timeout=None) for p in greedy]
+    futs.append(eng.submit(_prompt(182, 9), max_new_tokens=12,
+                           timeout=None, do_sample=True, **gen))
+    checked, _ = _run_against_the_host_path(eng, futs)
+    assert checked == {"greedy": 20, "sampled": 12}
+    for p, fut in zip(greedy, futs):
+        np.testing.assert_array_equal(fut.result(5), _ref_greedy(gpt, p, 10))
+    # a second engine, nobody looking at its rows: the same answer
+    other = serving.SlotEngine(gpt, max_slots=3, block_size=8,
+                               prefill_chunk=8)
+    again = other.submit(_prompt(182, 9), max_new_tokens=12, timeout=None,
+                         do_sample=True, **gen)
+    other._admit()
+    while other.active:
+        other._step()
+    np.testing.assert_array_equal(again.result(5), futs[-1].result(5))
+    assert other.metrics.get("logit_rows_fetched") == 12
+    assert other.metrics.get("device_picks") == 0
+
+
+def test_next_logits_is_a_handle_on_a_row_of_the_devices_logits(gpt, eng):
+    """What a slot carries between steps is no host row but a handle:
+    a new object every commit, converted with and without a dtype, the
+    same numbers each time, each conversion one row fetched; and the
+    token beside it is that row's argmax."""
+    fut = eng.submit(_prompt(190, 11), max_new_tokens=5, timeout=None)
+    eng._admit()
+    handles, rows = [], []
+    while eng.active:
+        eng._step()
+        for s in eng._slots:
+            if s is not None and s.state == "decode":
+                h = s.next_logits
+                assert h is not None and not isinstance(h, np.ndarray)
+                assert all(h is not seen for seen in handles)
+                before = eng.metrics.get("logit_rows_fetched")
+                row = np.asarray(h)
+                assert row.shape == (VOCAB,) and row.dtype == np.float32
+                np.testing.assert_array_equal(
+                    np.asarray(h, np.float32), row)
+                assert np.asarray(h, np.float64).dtype == np.float64
+                kept = np.asarray(h).copy()
+                kept[0] += 1.0                # a copy is the caller's
+                np.testing.assert_array_equal(np.asarray(h), row)
+                assert eng.metrics.get("logit_rows_fetched") == before + 5
+                assert s.next_token == int(row.argmax())
+                handles.append(h)
+                rows.append(row)
+    assert len(handles) == 5
+    answer = fut.result(5)
+    np.testing.assert_array_equal(
+        answer[11:], [int(r.argmax()) for r in rows])
+
+
+def test_counters_say_what_crossed(gpt):
+    """`device_picks`, `logit_rows_fetched` and `readback_bytes` on a
+    greedy run (every token the step's own pick, no row fetched, a
+    step's read-back the picks and the model's one count) and on a run
+    with a sampling request (a row a sampled token, nothing more)."""
+    eng = serving.SlotEngine(gpt, max_slots=4, block_size=8,
+                             prefill_chunk=8)
+    eng.warmup()
+    assert eng.metrics.get("readback_bytes") == 0     # warm-up reads none
+    m = eng.metrics
+
+    def run(**gen):
+        futs = [eng.submit(_prompt(200 + n, n), max_new_tokens=7,
+                           timeout=None) for n in (3, 12)]
+        futs.append(eng.submit(_prompt(210, 6), max_new_tokens=9,
+                               timeout=None, **gen))
+        was = {k: m.get(k) for k in (
+            "device_picks", "logit_rows_fetched", "readback_bytes",
+            "steps", "tokens_out")}
+        eng._admit()
+        while eng.active:
+            eng._step()
+        for f in futs:
+            f.result(5)
+        return {k: m.get(k) - v for k, v in was.items()}
+
+    # 4 slots' picks and GPT's one int32 count (`attn_key_tiles`)
+    a_step = 4 * 4 + 4
+    greedy = run()
+    assert greedy["device_picks"] == greedy["tokens_out"] == 23
+    assert greedy["logit_rows_fetched"] == 0
+    assert greedy["readback_bytes"] == a_step * greedy["steps"] > 0
+    mixed = run(do_sample=True, seed=3)
+    assert mixed["tokens_out"] == 23
+    assert mixed["device_picks"] == 14 and mixed["logit_rows_fetched"] == 9
+    assert mixed["readback_bytes"] == a_step * mixed["steps"]
+    counters = m.snapshot()["counters"]
+    assert counters["device_picks"] == 37
+    text = observe.prometheus_text(serving=m)
+    for name in ("device_picks", "logit_rows_fetched", "readback_bytes"):
+        assert f"paddle_serving_{name}_total {counters[name]}" in text
+
+
 def test_warmup_leaves_live_pools(gpt):
     """`warmup()` hands the pools to the step and to the CoW copy like
     any caller: what it was built with is gone, what it holds after is
@@ -770,7 +963,7 @@ class _Unreadable:
 
 @pytest.mark.parametrize("when", ["before", "after", "readback"])
 def test_step_that_raises_leaves_a_serving_engine(gpt, when):
-    """A step that raises once its inputs were donated (or whose logits
+    """A step that raises once its inputs were donated (or whose picks
     cannot be read) leaves no pool: the engine rebuilds empty ones,
     drops the prefix index, fails the live slots and serves the next
     request. One that raises before its dispatch donated nothing: the
@@ -792,7 +985,7 @@ def test_step_that_raises_leaves_a_serving_engine(gpt, when):
             out = real(*args)                 # dispatched: inputs gone
             if when == "after":
                 raise RuntimeError("device fell over")
-            return {**out[0], "logits": _Unreadable()}, out[1]
+            return {**out[0], "pick": _Unreadable()}, out[1]
 
         eng._decode = broken
         fut = srv.submit(_prompt(91, 4), max_new_tokens=8, timeout=120)

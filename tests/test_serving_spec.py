@@ -161,6 +161,44 @@ def test_spec_sampling_self_draft_accepts_everything(gpt):
     assert all(v == 1.0 for v in snap["per_slot_acceptance"].values())
 
 
+def test_what_a_verify_round_leaves_for_the_next_pick(gpt, draft_gpt):
+    """After a step a decoding slot carries one of three things. The
+    step's own pick with a handle on its row, where nothing was drafted
+    for it (the step after its prefill). A host row of `verify` with no
+    pick behind it, after a round with proposals: the next token is its
+    argmax, taken on the host, and no `device_picks` is counted for it.
+    Or nothing at all, after a sampling round's resample: the token is
+    committed already and the next step only writes its KV."""
+    spec = _engine(gpt, spec_len=3, draft_model=draft_gpt)
+    futs = [spec.submit(_prompt(61, 7), max_new_tokens=12, timeout=None),
+            spec.submit(_prompt(62, 5), max_new_tokens=12, timeout=None,
+                        do_sample=True, seed=8, temperature=0.02)]
+    spec._admit()
+    seen = {"handle": 0, "host_row": 0, "none": 0}
+    while spec.active:
+        spec._step()
+        for s in spec._slots:
+            if s is None or s.state != "decode":
+                continue
+            if s.next_logits is None:
+                assert s.req.gen.get("do_sample") and s.next_token is None
+                seen["none"] += 1
+            elif isinstance(s.next_logits, np.ndarray):
+                assert s.next_token is None
+                assert s.next_logits.shape == (VOCAB,)
+                seen["host_row"] += 1
+            else:
+                row = np.asarray(s.next_logits)
+                assert s.next_token == int(row.argmax())
+                seen["handle"] += 1
+    assert all(seen.values()), seen
+    want, _ = _drive(_engine(gpt), _prompt(61, 7), max_new=12)
+    np.testing.assert_array_equal(futs[0].result(5), want)
+    assert futs[1].result(5).shape == (17,)
+    # the greedy slot's first token is the only one a step picked
+    assert spec.metrics.get("device_picks") == 1
+
+
 def test_spec_len_widens_chunk_and_validates():
     paddle.seed(13)
     cfg = GPTConfig(vocab_size=31, hidden_size=16, num_layers=1,

@@ -279,6 +279,47 @@ def test_mixed_adapter_wave_bitwise_vs_single_adapter(adapter_engine,
     assert not np.array_equal(refs[1][0], refs[2][0])
 
 
+def test_the_steps_pick_follows_each_slots_adapter_delta(gpt):
+    """The step picks AFTER the adapter delta is added: three slots
+    hold the same prompt under the base model and two adapters whose
+    deltas, at the prompt's last position, lift one token each far
+    above the base logits. Each slot's first token is the one its own
+    adapter says, and at every step the token committed is the first
+    maximum of the row (delta included) behind the slot's handle."""
+    prompt = _prompt(40, 9)
+    hidden = np.asarray(gpt.gpt(paddle.to_tensor(prompt[None, :]))._value,
+                        np.float32)[0, -1]
+    base = int(np.asarray(
+        gpt(paddle.to_tensor(prompt[None, :]))._value)[0, -1].argmax())
+    lifted = {1: (base + 3) % VOCAB, 2: (base + 17) % VOCAB}
+    la = np.zeros((N_ADAPTERS, RANK, HIDDEN), np.float32)
+    lb = np.zeros((N_ADAPTERS, VOCAB, RANK), np.float32)
+    for aid, token in lifted.items():
+        la[aid, 0] = hidden / (hidden @ hidden)   # reads 1.0 at this h
+        lb[aid, token, 0] = 50.0
+    eng = SlotEngine(gpt, max_slots=3, block_size=8,
+                     max_adapters=N_ADAPTERS, lora_rank=RANK)
+    eng.warmup()
+    eng.swap_adapters(la, lb)
+    futs = [eng.submit(prompt, max_new_tokens=5, adapter_id=aid,
+                       timeout=None) for aid in range(3)]
+    eng._admit()
+    checked = 0
+    while eng.active:
+        want = [(s, len(s.tokens), int(np.argmax(np.asarray(s.next_logits))))
+                for s in eng._slots
+                if s is not None and s.state == "decode"]
+        eng._step()
+        for s, at, token in want:
+            assert s.tokens[at] == token
+            checked += 1
+    assert checked == 15
+    firsts = [int(f.result(5)[prompt.size]) for f in futs]
+    assert firsts == [base, lifted[1], lifted[2]]
+    assert eng.metrics.get("device_picks") == 15
+    assert eng.compile_counts == {"decode": 1, "cow": 1}
+
+
 def test_adapter_swap_zero_retrace(adapter_engine):
     """Hot-swapping banks and serving every adapter must never retrace:
     compile_counts stays {decode: 1, cow: 1} for engine life."""

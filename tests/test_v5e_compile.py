@@ -215,3 +215,122 @@ def test_latent_pool_at_its_logical_width_would_be_copied(
     copies all of it in and out of every layer's scatter."""
     compiled = _compile_latent_path(576, one_chip)
     assert len(_pool_copies(compiled, 576)) >= LAYERS
+
+
+# -- a cell's whole serving step ----------------------------------------------
+
+# The step of a benchmark cell, built from the cell's own configuration
+# file at its published widths and compiled whole. At the cell's real
+# depth the weights have to exist on the host first (5.3 GB of float32
+# for gpt3-1.3b, 10.9 GB of bfloat16 and an 18 GB peak for sarvam-105b,
+# a minute or two to draw them): those two cases are `slow`. Tier-1
+# compiles the same step two layers deep, where everything the host and
+# the step hand each other is the same: the one staged array, the pick,
+# the logits. Per case: (configuration, layers or None for the file's
+# own, the most bytes of temporaries the compile may report).
+_MB = 1 << 20
+_CELL_STEPS = [
+    pytest.param("gpt3-1.3b", 2, 4 * _MB, id="gpt3-1.3b-2-layers"),
+    pytest.param("sarvam-105b", 2, 107 * _MB, id="sarvam-105b-2-layers"),
+    pytest.param("gpt3-1.3b", None, 32 * _MB, id="gpt3-1.3b",
+                 marks=pytest.mark.slow),
+    pytest.param("sarvam-105b", None, 107 * _MB, id="sarvam-105b",
+                 marks=pytest.mark.slow),
+]
+
+
+def _cell_engine(name, layers):
+    """The cell's `SlotEngine` as its runner builds it, but for the
+    pools: two blocks here, the real ones are handed to the compile as
+    shapes."""
+    import json
+    from pathlib import Path
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.nlp import transformers
+
+    config = json.loads((Path(__file__).resolve().parent.parent
+                         / "benchmarks" / "configs" / f"{name}.json")
+                        .read_text())
+    dep, sizes = config["serving"], dict(config["model"])
+    if layers is not None:
+        sizes["num_layers"] = layers
+    paddle.seed(1)
+    if config["family"] == "gpt":
+        model = transformers.GPTForPretraining(
+            transformers.GPTConfig(use_parallel=False, **sizes))
+    else:
+        was = paddle.get_default_dtype()
+        paddle.set_default_dtype(dep["weight_dtype"])
+        try:
+            model = transformers.LatentMoEForCausalLM(
+                transformers.LatentMoEConfig(**sizes))
+        finally:
+            paddle.set_default_dtype(was)
+    eng = serving.SlotEngine(
+        model, max_slots=dep["max_slots"], max_seq_len=dep["max_seq_len"],
+        num_blocks=2, prefill_chunk=dep.get("prefill_chunk"),
+        cache_dtype=jnp.dtype(dep["cache_dtype"]))
+    return eng, dep["num_blocks"], model.config.vocab_size
+
+
+@pytest.mark.parametrize("name, layers, temp_limit", _CELL_STEPS)
+def test_cell_step_returns_the_pick_beside_logits_that_are_not_copied(
+        name, layers, temp_limit, one_chip, no_compile_cache):
+    """`serving_step` as `_stage` feeds it: the host's arguments are one
+    int32 array; the results are `pick` (`s32[slots]`), the logits
+    (`f32[slots, V]`, still a result for the handle to fetch rows of)
+    and the pools, every byte of them aliased to the arguments and none
+    copied. The argmax reads the logits where the head's product left
+    them, in fast memory, and they go out to their result buffer by one
+    asynchronous copy beside it: no second one, and no relayout `copy`.
+    What the program needs besides its arguments stays far from the
+    size of a pool. (At the cells' real depth, `-m slow`: arguments
+    9.293 GB, 4.030 GB aliased and 23.1 MB of temporaries for
+    gpt3-1.3b, where the step before the pick had none; 12.935 GB,
+    2.013 GB and 105.8 MB for sarvam-105b, which had 105.7 MB.)"""
+    eng, num_blocks, vocab = _cell_engine(name, layers)
+    slots = eng.max_slots
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    vec = np.zeros((slots,), np.int32)
+    batch, extras = eng._stage(
+        np.zeros((slots, eng.prefill_chunk), np.int32), vec, vec)
+    assert not extras and batch.dtype == np.int32
+    assert batch.shape == (slots,
+                           eng.prefill_chunk + 2 + eng.blocks_per_slot)
+    shapes = eng._layout.pool_shapes(num_blocks, eng.block_size)
+    pools = [tuple(jax.ShapeDtypeStruct(s, eng._pool_dtype,
+                                        sharding=one_chip) for s in shapes)
+             for _ in range(eng._layout.layers)]
+    values = jax.tree_util.tree_map(spec, eng._values)
+    compiled = eng._decode.lower(values, spec(batch), pools, {}).compile()
+
+    memory = compiled.memory_analysis()
+    pool_bytes = eng._layout.layers * sum(
+        int(np.prod(s)) for s in shapes) * eng._pool_dtype.itemsize
+    held = sum(v.nbytes for v in eng._values.values()) + batch.nbytes
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert abs(memory.argument_size_in_bytes - held - pool_bytes) < 1 << 16
+    assert memory.temp_size_in_bytes < temp_limit
+
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("ENTRY"):]
+    root = next(line for line in entry.splitlines()
+                if line.lstrip().startswith("ROOT"))
+    results = set(re.findall(r"(\w+)\[([\d,]*)\]",
+                             root[:root.index("tuple(")]))
+    assert ("s32", f"{slots}") in results, root[:400]
+    assert ("f32", f"{slots},{vocab}") in results, root[:400]
+    instructions = _entry_instructions(hlo)
+    logits = [(op, name) for name, dtype, dims, op in instructions
+              if dims == (slots, vocab) and op != "bitcast"]
+    assert sorted(op for op, _ in logits) in (
+        ["fusion"], ["copy-done", "fusion"]), logits
+    moved = [line.split(" = ")[0].strip() for line in entry.splitlines()
+             if re.search(r" copy(-start)?\(", line)
+             and any(f"[{','.join(map(str, s))}]" in line for s in shapes)]
+    assert not moved, f"pool-sized copies are back: {moved}"
