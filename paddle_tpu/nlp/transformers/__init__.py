@@ -11,3 +11,7 @@ from .latent_moe import (  # noqa: F401
     HeldExperts, LatentAttention, LatentMoEConfig, LatentMoEDecoderLayer,
     LatentMoEForCausalLM, LatentMoEModel,
 )
+from .hybrid_linear import (  # noqa: F401
+    GatedDeltaNet, HybridDecoderLayer, HybridFullAttention,
+    HybridLinearConfig, HybridLinearForCausalLM, HybridLinearModel,
+)

@@ -51,9 +51,10 @@ _GOODPUT_CATS = {
 # it out of the goodput denominator
 _OVERLAPPED = {"checkpoint-write-async"}
 # spans that lie inside (dispatch, readback: the two halves of serving's
-# device-step) or round (serving.loop) phases counted above: counting
+# device-step; snapshot: state snapshots taken inside commit and restored
+# inside admit) or round (serving.loop) phases counted above: counting
 # them too would count their seconds twice
-_NESTED = {"serving.loop", "dispatch", "readback"}
+_NESTED = {"serving.loop", "dispatch", "readback", "snapshot"}
 
 
 def goodput(aggregates=None):

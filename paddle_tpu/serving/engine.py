@@ -17,8 +17,37 @@ allocation and SGLang-style prefix sharing:
   *total tokens in flight*, not
   ``max_slots * max_seq`` — short requests no longer pay for long ones
   and concurrency scales with the pool, not the worst case.
-- The pools are DONATED to every program that returns them (the step,
-  the CoW copy, the draft micro-step) and updated in place: the arrays
+- A layout may declare a second kind of array (`CacheLayout.state`):
+  what one SLOT keeps of each state-holding layer whatever its context
+  (a recurrent layer's state, a filter's tail). The engine allocates
+  each as ``[max_slots + snapshot_entries + 1, *shape]``: a row a slot,
+  which the step is handed and hands back with the pools (`_held`); a
+  row an entry of the SNAPSHOT pool; and a last row that stays zero.
+  One compiled row copy (`serving_snapshot`, traced once, beside the
+  CoW copy) does all that moves a state: a slot admitted without a hit
+  is reset from the zero row (`state_resets`; what a freed slot left
+  never reaches its next occupant); whenever a live slot's position
+  lands exactly on a block boundary at the end of a step its state is
+  copied into the request's one working entry, over the older copy
+  (`_take_snapshots`, no host read-back); at `_evict` the prefix cache
+  records that entry on the block that ends at its depth, and a later
+  request's match is cut at the deepest snapshot on its chain
+  (`PrefixCache.match_snapshot`: K and V rows of a prefix are shared
+  block by block, the state at a boundary cannot be rebuilt from
+  them), the slot's state restored from it (`state_snapshot_hits`),
+  deeper matched blocks computed again
+  (`prefix_tokens_lost_to_state`) and no copy-on-write inside a deeper
+  block. An entry lives as long as the block it is recorded on;
+  recording a deeper one on a chain frees the shallower, and a request
+  that finds no free working entry takes the least recently used
+  recorded one (`state_snapshot_evictions`). Speculation, KV export /
+  adoption / migration and the spill tier carry K/V blocks only and
+  refuse such a layout by name (`_refuse_state_arrays`). A layout
+  that declares no state takes none of this: no arrays, no entries,
+  no third program.
+- The pools (and the state arrays) are DONATED to every program that
+  returns them (the step, the CoW copy, the row copy, the draft
+  micro-step) and updated in place: the arrays
   handed in are dead after the call and `_pools` is rebound to its
   outputs under `_pool_lock`. Everything else that reads or
   rebinds a pool runs on the loop's thread between steps, or takes
@@ -132,6 +161,7 @@ the request's `id`. A request that fails folds nothing.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 
@@ -146,6 +176,7 @@ from . import kvstore
 from .metrics import ServingMetrics
 from .paging import (
     BLOCK_ROW_ORDER, NULL_BLOCK, BlockAllocator, PoolExhausted, PrefixCache,
+    SnapshotEntries,
 )
 from .queueing import (
     AdmissionQueue, CapacityExhaustedError, DeadlineExceededError, Request,
@@ -187,6 +218,11 @@ class _Slot:
         # a speculative engine's draft-side state for this slot
         # (speculation._SlotDraft); None on a plain one
         self.spec = None
+        # a layout with state arrays: the request's one working entry
+        # of the snapshot pool (None = none was free) and the position
+        # its state was last copied there at (0 = never)
+        self.entry = None
+        self.snap_depth = 0
 
 
 class _LogitRow:
@@ -212,14 +248,23 @@ class SlotEngine:
 
     `model` is any layer that offers the serving seam (eval mode is
     forced): `config` (`vocab_size`, `hidden_size`, `max_seq_len`),
-    `cache_layout()` (what a block of a layer holds),
+    `cache_layout()` (what a block of a layer holds, and what a slot
+    keeps of a state-holding layer),
     `paged_forward(tok, pos, nvalid, tables, pools) -> (hidden, pools,
     aux)` (one step over the paged pools; `aux` a dict of int arrays
     the step counts, summed into `aux_totals` and counters of the same
     names; an entry that is a plain `int` counts the same every step
-    and is added on the host, not returned by the program),
+    and is added on the host, not returned by the program); a model
+    whose layout declares state arrays takes the slots' rows of them as
+    a sixth argument and returns them after the pools:
+    `paged_forward(tok, pos, nvalid, tables, pools, state) -> (hidden,
+    pools, state, aux)`;
     `logits(hidden)`, and optionally `serving_gauges()`.
-    `GPTForPretraining` and `LatentMoEForCausalLM` do. Requests
+    `GPTForPretraining`, `LatentMoEForCausalLM` and
+    `HybridLinearForCausalLM` do. `snapshot_entries` sizes the state
+    snapshot pool of a layout with state arrays (default three a
+    slot: a working entry a live request, a recorded one a resting
+    session, and slack). Requests
     carry `max_new_tokens`, optional `eos_token_id`, and sampling
     params; results are the full [prompt + generated] int32 id array,
     token-identical to `generate()` / full re-forwarding for greedy.
@@ -238,7 +283,7 @@ class SlotEngine:
                  supervised=False, values=None, weight_version=0,
                  draft_model=None, spec_len=None, quantize=None,
                  w8a8=None, mesh=None, spill_dir=None,
-                 max_adapters=None, lora_rank=None):
+                 max_adapters=None, lora_rank=None, snapshot_entries=None):
         import jax
         import jax.numpy as jnp
 
@@ -376,6 +421,23 @@ class SlotEngine:
         # thread's gather (`export_prefix_blocks`)
         self._pool_lock = threading.Lock()
         self._pools = self._zero_pools(self._layout)
+        # the layout's second kind of array: what a SLOT keeps of each
+        # state-holding layer, whatever its context. One allocation an
+        # array, `[max_slots + snapshot_entries + 1, *shape]`: a row a
+        # slot (the step reads and writes those), a row an entry of the
+        # snapshot pool, and a last row that stays zero, so that a
+        # snapshot taken, a snapshot restored and a slot reset are all
+        # the one compiled row copy (`_copy_state`). No state declared:
+        # no arrays, no entries, and the code below takes none of it.
+        self._state: list = []
+        self._snapshots = None
+        self.snapshot_entries = self._state_rows = 0
+        if self._layout.state:
+            self.snapshot_entries = 3 * self.max_slots \
+                if snapshot_entries is None else int(snapshot_entries)
+            self._snapshots = SnapshotEntries(self.snapshot_entries)
+            self._state_rows = self.max_slots + self.snapshot_entries + 1
+            self._state = self._zero_state()
         if self._plan is not None:
             # weights by partition rule, KV pools over the head axis
             # (replicated when heads don't divide mp); block tables and
@@ -384,6 +446,8 @@ class SlotEngine:
             self.metrics.set_gauge("mesh_devices", float(self.mesh.size))
             self.metrics.note_mesh(self.mesh_spec, int(self.mesh.size))
         self.kv_pool_bytes = self._pool_bytes(self._layout)
+        self.state_bytes = self._state_rows \
+            * self._layout.state_bytes_per_slot()
         # sums of what the model's step counts (`aux`), by name
         self.aux_totals: dict = {}
         self._aux_const: dict = {}
@@ -392,14 +456,23 @@ class SlotEngine:
                                self._layout.bytes_per_token(itemsize))
         self.metrics.set_gauge("weight_bytes", sum(
             int(getattr(v, "nbytes", 0)) for v in self._values.values()))
+        if self._layout.state:
+            self.metrics.set_gauge("state_bytes_per_slot",
+                                   self._layout.state_bytes_per_slot())
+            self.metrics.set_gauge("snapshot_entries",
+                                   self.snapshot_entries)
         for gauge, value in getattr(model, "serving_gauges",
                                     dict)().items():
             self.metrics.set_gauge(gauge, value)
         self._alloc = BlockAllocator(self.num_blocks)
         if prefix_cache is None:
             prefix_cache = flag("FLAGS_serving_prefix_cache")
-        self._cache = PrefixCache(self._alloc, self.block_size) \
+        self._cache = PrefixCache(self._alloc, self.block_size,
+                                  snapshots=self._snapshots) \
             if prefix_cache else None
+        if self._cache is not None and self._layout.state:
+            self._cache.snapshot_evicted_hook = \
+                lambda: self.metrics.inc("state_snapshot_evictions")
         # persistent KV spill tier (ISSUE 18): one shared store per
         # spill directory, so every replica of the process spills into
         # — and can resume from — the same tier. None = disabled.
@@ -407,6 +480,7 @@ class SlotEngine:
             spill_dir, metrics=self.metrics) \
             if self._cache is not None else None
         if self.spill_store is not None:
+            self._refuse_state_arrays("the KV spill tier")
             if self._layout.row_order != BLOCK_ROW_ORDER:
                 # a spill record is K and V rows of [block_size, nh,
                 # hd]: any other block is refused, never written as one
@@ -468,7 +542,11 @@ class SlotEngine:
         `extras` holds the device-resident arguments that are not
         weights (`act_scale` under w8a8; `lora_a`, `lora_b` with
         adapters; empty on a plain engine). `out` is what the step
-        leaves on the device: `pick` (int32 `[max_slots]`, the argmax
+        leaves on the device, `pools` what it is handed donated and
+        hands back (`_held`: the layers' pools, or for a layout with
+        state arrays `{"blocks": pools, "state": state arrays}`, of
+        which the model sees the slots' rows). `out` holds: `pick`
+        (int32 `[max_slots]`, the argmax
         of each slot's logits row, adapter delta included) and `aux`,
         which the host reads back every step, `verify` on a speculative
         engine (`verify_cols` > 0: the first k+1 columns' logits, read
@@ -546,8 +624,23 @@ class SlotEngine:
                 else values
 
             def run(m):
-                hv, new_pools, aux = m.paged_forward(tok, pos, nvalid,
-                                                     tables, pools)
+                if self._layout.state:
+                    # a layout with state arrays: the step is handed
+                    # {"blocks": pools, "state": state arrays}, the
+                    # model the slots' rows of the state, and what it
+                    # returns goes back into those rows in place
+                    slots = self.max_slots
+                    hv, blocks, rows, aux = m.paged_forward(
+                        tok, pos, nvalid, tables, pools["blocks"],
+                        [tuple(a[:slots] for a in layer)
+                         for layer in pools["state"]])
+                    new_pools = {"blocks": blocks, "state": [
+                        tuple(a.at[:slots].set(r.astype(a.dtype))
+                              for a, r in zip(layer, new))
+                        for layer, new in zip(pools["state"], rows)]}
+                else:
+                    hv, new_pools, aux = m.paged_forward(
+                        tok, pos, nvalid, tables, pools)
                 # a plain int is the same every step: it stays on the
                 # host (noted here, at trace time) and out of the program
                 self._aux_const = {k: v for k, v in aux.items()
@@ -608,9 +701,26 @@ class SlotEngine:
 
             return jax.tree_util.tree_map(copy, pools)
 
+        def serving_snapshot(state, src, dst):
+            """Row `src` of every state array copied over row `dst`:
+            a snapshot taken (slot -> entry), restored (entry -> slot)
+            or a slot reset (the zero row -> slot)."""
+            from jax import lax
+
+            self._count_compile("snapshot")
+            observe.record_compile("serving.snapshot",
+                                   signature="(row, row)")
+
+            def copy(a):
+                row = lax.dynamic_slice_in_dim(a, src, 1, axis=0)
+                return lax.dynamic_update_slice_in_dim(a, row, dst, axis=0)
+
+            return jax.tree_util.tree_map(copy, state)
+
         if self._plan is None:
             self._decode = jax.jit(serving_step, donate_argnums=(2,))
             self._cow = jax.jit(serving_cow, donate_argnums=(0,))
+            self._snapshot = jax.jit(serving_snapshot, donate_argnums=(0,))
             return
         # explicit in/out shardings: weights follow the partition rules,
         # pools keep their head sharding through the step (GSPMD then has
@@ -619,12 +729,19 @@ class SlotEngine:
         # stands for every leaf of its tree)
         rep = self._plan.replicated()
         pools = self._pool_shardings(self._layout)
+        state = self._state_shardings()
+        held = {"blocks": pools, "state": state} if state else pools
         self._decode = jax.jit(
             serving_step,
             in_shardings=(self._plan.values_shardings(self._values), rep,
-                          pools, rep),
-            out_shardings=(rep, pools),
+                          held, rep),
+            out_shardings=(rep, held),
             donate_argnums=(2,))
+        self._snapshot = jax.jit(
+            serving_snapshot,
+            in_shardings=(state, rep, rep),
+            out_shardings=state,
+            donate_argnums=(0,))
         self._cow = jax.jit(
             serving_cow,
             in_shardings=(pools, rep, rep),
@@ -706,6 +823,61 @@ class SlotEngine:
         return [tuple(pool(sh, sd) for sh, sd in zip(shapes, shardings))
                 for _ in range(layout.layers)]
 
+    def _state_shardings(self):
+        """One tuple of the layout's state arrays' shardings a
+        state-holding layer (none for a layout without)."""
+        layout = self._layout
+        return [tuple(
+            self._plan.state_sharding(layout, name, (self._state_rows,)
+                                      + shape)
+            for name, shape, _ in layout.state)] * layout.state_layers
+
+    def _zero_state(self):
+        """Fresh zeroed state arrays, ``[(array, ...), ...]``: one tuple
+        of the layout's state arrays a state-holding layer, each
+        ``[max_slots + snapshot_entries + 1, *shape]``. At construction
+        and, with the pools, in `_recover_pools`."""
+        import jax
+        import jax.numpy as jnp
+
+        layout = self._layout
+        shardings = self._state_shardings()[0] \
+            if self._plan is not None else [None] * len(layout.state)
+
+        def array(shape, dtype, sharding):
+            zeros = jnp.zeros((self._state_rows,) + shape, dtype)
+            return zeros if sharding is None \
+                else jax.device_put(zeros, sharding)
+
+        return [tuple(array(shape, dtype, sd)
+                      for (_, shape, dtype), sd in zip(layout.state,
+                                                       shardings))
+                for _ in range(layout.state_layers)]
+
+    def _held(self):
+        """What a step is handed, donated, and hands back: the pools,
+        and with them the state arrays of a layout that declares
+        some."""
+        return {"blocks": self._pools, "state": self._state} \
+            if self._state else self._pools
+
+    def _rebind(self, held):
+        if self._state:
+            self._pools, self._state = held["blocks"], held["state"]
+        else:
+            self._pools = held
+
+    def _refuse_state_arrays(self, what):
+        """Blocks of a layout with state arrays are only usable with
+        the snapshot of that state, which `what` does not carry."""
+        if self._layout.state:
+            raise ValueError(
+                f"{what} carries K/V blocks only; this model's cache "
+                f"layout also keeps per-slot state arrays "
+                f"{[name for name, _, _ in self._layout.state]}, and a "
+                f"block without the state snapshot taken at its end is "
+                f"no prefix to resume from")
+
     def _pool_bytes(self, layout):
         import jax.numpy as jnp
 
@@ -736,13 +908,15 @@ class SlotEngine:
         with `error`, the prefix index is dropped without spilling (the
         rows it names no longer exist) and the engine goes on with empty
         pools."""
-        if not self._lost(self._arrays(self._pools)):
+        if not self._lost(self._arrays(self._pools + self._state)):
             return
         self._fail_all_active(error)
         if self._cache is not None:
             self._cache.clear(spill=False)
         with self._pool_lock:
             self._pools = self._zero_pools(self._layout)
+            if self._state:
+                self._state = self._zero_state()
         self.metrics.inc("pool_rebuilds")
 
     # -- w8a8 activation scale (frozen after a short calibration) -----------
@@ -804,8 +978,9 @@ class SlotEngine:
         (w8a8's abs-max folded into the scale there, never read)."""
         batch, extras = self._stage(tok, pos, nvalid)
         with self._pool_lock:
-            out, self._pools = self._decode(self._values, batch,
-                                            self._pools, extras)
+            out, held = self._decode(self._values, batch, self._held(),
+                                     extras)
+            self._rebind(held)
         if self.w8a8:
             self._absorb_act_amax(out.pop("amax"))
         return out
@@ -902,8 +1077,6 @@ class SlotEngine:
         warmup (re-entering the serve path after a shard restart) runs
         under `observe.no_retrace()`: same shapes + same mesh = zero new
         compiles for engine life."""
-        import contextlib
-
         if mesh is not None:
             from .sharding import mesh_spec_of, resolve_mesh
 
@@ -921,6 +1094,11 @@ class SlotEngine:
             nvalid = np.ones((self.max_slots,), np.int32)
             self._dispatch(tok, pos, nvalid)
             self._copy_block(NULL_BLOCK, NULL_BLOCK)
+            if self._state:
+                # the dummy step wrote every slot's state; admission
+                # resets a slot before its first real step
+                self._copy_state(self._state_rows - 1,
+                                 self._state_rows - 1)
             if self._spec is not None:
                 self._spec.warmup(pos, nvalid)
         self._warmed = True
@@ -970,10 +1148,17 @@ class SlotEngine:
         """Reserve the physical blocks for one admission: reuse every
         prefix-cached block, allocate the rest, copy-on-write when the
         divergence falls inside a cached block. Returns
-        ``(blocks, fill)`` or raises (`PoolExhausted` = wait and retry;
-        anything else = fail the request). All-or-nothing: partial
-        reservations are rolled back."""
-        shared, n_shared, cow = [], 0, None
+        ``(blocks, fill, entry)`` or raises (`PoolExhausted` = wait and
+        retry; anything else = fail the request). All-or-nothing:
+        partial reservations are rolled back.
+
+        A layout with state arrays cuts the match at the deepest state
+        snapshot recorded on the matched chain (`match_snapshot`):
+        `entry` is that snapshot's, to restore the slot's state from
+        (None = the state starts from zero), blocks that matched deeper
+        are computed again (`prefix_tokens_lost_to_state`), and there
+        is no copy-on-write inside a deeper block."""
+        shared, n_shared, cow, entry = [], 0, None, None
         if self._cache is not None:
             if self.spill_store is not None:
                 # session resume: pull spilled records extending the
@@ -982,7 +1167,14 @@ class SlotEngine:
                 self._maybe_restore(ids)
             # always leave >= 1 prompt token to compute: the last
             # token's logits seed decode
-            shared, n_shared, cow = self._cache.match(ids, ids.size - 1)
+            if self._layout.state:
+                shared, n_shared, entry, matched = \
+                    self._cache.match_snapshot(ids, ids.size - 1)
+                self.metrics.inc("prefix_tokens_lost_to_state",
+                                 matched - n_shared)
+            else:
+                shared, n_shared, cow = self._cache.match(ids,
+                                                          ids.size - 1)
             self.metrics.inc("prefix_lookups")
             self.metrics.inc("prompt_tokens", int(ids.size))
             hit_tokens = n_shared + (cow[1] if cow else 0)
@@ -1033,7 +1225,7 @@ class SlotEngine:
             raise
         if pinned_src is not None:
             self._alloc.decref(pinned_src)
-        return taken + new, fill
+        return taken + new, fill, entry
 
     def _copy_block(self, src, dst):
         """The compiled copy-on-write copy, every layer's pools at
@@ -1043,6 +1235,67 @@ class SlotEngine:
         with self._pool_lock:
             self._pools = self._cow(self._pools, jnp.int32(src),
                                     jnp.int32(dst))
+
+    def _copy_state(self, src, dst):
+        """The compiled row copy over every state array at once (row
+        `src` over row `dst`); they are donated to it like to the
+        step."""
+        # numpy scalars: the jit call moves them itself, more cheaply
+        # than staged with `jnp.int32` first (PERF.md, PR 32 and 33)
+        with self._pool_lock:
+            self._state = self._snapshot(self._state, np.int32(src),
+                                         np.int32(dst))
+
+    def _seed_state(self, slot, entry):
+        """A slot just admitted: its state restored from the snapshot
+        `entry` its prefix hit ends at, or reset to zero without one
+        (whatever the slot's last occupant left never reaches it), and
+        a working entry of its own to snapshot into, if one is free or
+        the least recently used recorded one can go."""
+        with self._snapshot_span():
+            if entry is None:
+                self._copy_state(self._state_rows - 1, slot)
+                self.metrics.inc("state_resets")
+            else:
+                self._copy_state(self.max_slots + entry, slot)
+                self.metrics.inc("state_snapshot_hits")
+        mine = self._snapshots.alloc()
+        if mine is None and self._cache is not None \
+                and self._cache.evict_lru_snapshot():
+            mine = self._snapshots.alloc()
+        self._slots[slot].entry = mine
+
+    @contextlib.contextmanager
+    def _snapshot_span(self):
+        """Span `serving.snapshot`, phase `snapshot`: round every take
+        and restore."""
+        t0 = time.perf_counter()
+        with profiler.RecordEvent("serving.snapshot", cat="serving"):
+            yield
+        observe.timeline.add("snapshot", time.perf_counter() - t0)
+
+    def _take_snapshots(self, live):
+        """After a step's commit: every live slot whose position now
+        lies exactly on a block boundary has its state copied into its
+        working entry, over the older copy; no host read-back. A slot
+        that goes on prefilling whole chunks skips it: it lands on a
+        deeper boundary before it can end."""
+        due = []
+        for i in live:
+            slot, at = self._slots[i], int(self._pos[i])
+            if slot.entry is None or at % self.block_size:
+                continue
+            if slot.prompt_len - slot.fill >= self.prefill_chunk \
+                    and self.prefill_chunk % self.block_size == 0:
+                continue
+            due.append((i, slot, at))
+        if not due:
+            return
+        with self._snapshot_span():
+            for i, slot, at in due:
+                self._copy_state(i, self.max_slots + slot.entry)
+                slot.snap_depth = at
+        self.metrics.inc("state_snapshots_taken", len(due))
 
     def _admit(self):
         """Join-at-step: fill free slots from the queue while block
@@ -1057,7 +1310,7 @@ class SlotEngine:
             need = self._blocks_needed(
                 ids.size + req.gen.get("max_new_tokens", 16))
             try:
-                blocks, fill = self._stage_blocks(ids, need)
+                blocks, fill, entry = self._stage_blocks(ids, need)
             except PoolExhausted:
                 # FIFO head-of-line wait: blocks free at step boundaries
                 self.queue.requeue(req)
@@ -1073,6 +1326,8 @@ class SlotEngine:
             self._pos[slot] = fill
             self._aid[slot] = int(req.gen.get("adapter_id", 0) or 0)
             self._slots[slot] = _Slot(req, ids, fill, blocks)
+            if self._state:
+                self._seed_state(slot, entry)
             req.admitted = time.monotonic()
             req.queue_wait = req.admitted - req.arrival
             req.prefix_hit_tokens = fill
@@ -1098,6 +1353,7 @@ class SlotEngine:
         device-side gathers are enqueued under `_pool_lock`, which the
         loop holds from a dispatch to the rebind of its outputs; the
         copies to the host wait outside it."""
+        self._refuse_state_arrays("export_prefix_blocks")
         if self._cache is None:
             return None
         ids = np.asarray(prompt_ids, np.int32).reshape(-1)
@@ -1138,6 +1394,7 @@ class SlotEngine:
         shape or row order). All-or-nothing: any fault
         mid-adoption frees every block taken so far — the pool is
         leak-free and the request simply prefills from scratch."""
+        self._refuse_state_arrays("adopt_prefix_blocks")
         return self._at_step_boundary(
             lambda: self._apply_adoption(payload),
             "adopt migrated KV", timeout)
@@ -1347,10 +1604,17 @@ class SlotEngine:
         self._slots[idx] = None
         self._free.append(idx)
         written = int(self._pos[idx])
+        snapshot = None if slot.entry is None \
+            else (slot.entry, slot.snap_depth)
         if error is None and self._cache is not None:
             # donate fully written blocks to the prefix index before
-            # releasing our references — shared system prompts survive
-            self._cache.insert(slot.tokens, slot.blocks, written)
+            # releasing our references — shared system prompts survive;
+            # with them the request's state snapshot, recorded on the
+            # block that ends at its depth
+            self._cache.insert(slot.tokens, slot.blocks, written,
+                               snapshot=snapshot)
+        elif snapshot is not None:
+            self._snapshots.free(slot.entry)
         for bid in slot.blocks:
             self._alloc.decref(bid)
         self._bt[idx, :] = NULL_BLOCK
@@ -1467,6 +1731,8 @@ class SlotEngine:
                 slot.next_logits = _LogitRow(logits, i, self.metrics)
             if spec is not None:
                 spec.commit(out["verify"], done)
+            if self._state:
+                self._take_snapshots(live)
             self.metrics.inc("steps")
             if prefill_tokens:
                 self.metrics.inc("prefill_tokens", prefill_tokens)
@@ -1489,7 +1755,7 @@ class SlotEngine:
         leaves them alive and the counter behind `steps`."""
         import jax
 
-        pools = self._pools
+        pools, handed = self._pools, self._pools + self._state
         t0 = time.monotonic()
         try:
             with profiler.RecordEvent("serving.step", cat="serving"):
@@ -1502,11 +1768,11 @@ class SlotEngine:
             if self._pools is not pools:
                 # dispatched, and its picks cannot be read: what it
                 # left in place of the pools is no KV to serve from
-                for a in self._arrays(self._pools):
+                for a in self._arrays(self._pools + self._state):
                     a.delete()
             raise
         done = time.monotonic()
-        if all(a.is_deleted() for a in self._arrays(pools)):
+        if all(a.is_deleted() for a in self._arrays(handed)):
             self.metrics.inc("pool_inplace_steps")
         self.metrics.inc("readback_bytes", sum(
             a.nbytes for a in jax.tree_util.tree_leaves(out)))
@@ -1625,8 +1891,6 @@ class SlotEngine:
         self.last_beat = time.monotonic()
 
     def _loop(self):
-        import contextlib
-
         guard = observe.no_retrace() if self._strict and self._warmed \
             else contextlib.nullcontext()
         with guard:

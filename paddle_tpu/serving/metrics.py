@@ -42,7 +42,15 @@ class ServingMetrics:
     (prompt positions written by chunked prefill), `prompt_tokens` /
     `prefix_lookups` / `prefix_hit_blocks` / `prefix_hit_tokens` /
     `cow_splits` (prefix-cache traffic), `rejected_capacity` (429 sheds
-    whose block demand exceeds the pool), `pool_inplace_steps` (steps
+    whose block demand exceeds the pool), the recurrent-state set of a
+    layout with per-slot state arrays: `state_snapshots_taken` (a live
+    slot's state copied into its entry at a block boundary),
+    `state_snapshot_hits` (admissions restored from a recorded
+    snapshot), `state_resets` (admissions that started from zero),
+    `state_snapshot_evictions` (recorded snapshots dropped by reclaim
+    or for want of a free entry) and `prefix_tokens_lost_to_state`
+    (tokens whose blocks matched but lay deeper than any snapshot, so
+    were computed again), `pool_inplace_steps` (steps
     whose donated KV pools were updated in place: equals `steps`) and
     `pool_rebuilds` (a program raised after it was handed the pools;
     the engine went on with empty ones), what crosses from the device
@@ -337,11 +345,15 @@ class ServingMetrics:
                 "dequant_path": gauges.get("dequant_path", 0.0),
             }
         model = {k: gauges[k] for k in ("kv_bytes_per_token",
-                                         "weight_bytes", "experts_held")
+                                         "weight_bytes", "experts_held",
+                                         "state_bytes_per_slot",
+                                         "snapshot_entries")
                  if k in gauges}
         if model:
             # what the served model holds: cache bytes a token over all
-            # layers, weight bytes on the device, routed experts held
+            # layers, weight bytes on the device, routed experts held,
+            # and for a layout with per-slot state arrays the bytes of
+            # one slot's state and the entries of the snapshot pool
             snap["model"] = model
         with self._lock:
             mesh, role = self._mesh, self._role

@@ -27,6 +27,15 @@ slot engine's needs):
   copy-on-write candidate: the caller copies the physical block and
   overwrites the divergent tail. Entries are evicted leaf-first in LRU
   order when the allocator runs dry (`reclaim`).
+- A layout may also declare per-slot STATE arrays (`CacheLayout.state`:
+  a recurrent layer keeps a fixed-size state a slot, not rows a token).
+  K and V rows of a prefix are shared block by block; the state at a
+  block boundary cannot be rebuilt from them. So the prefix cache of
+  such a layout records, with a block, the SNAPSHOT of the state taken
+  exactly at that block's end (an entry of `SnapshotEntries`), and
+  `match_snapshot` returns blocks no deeper than the deepest snapshot
+  on the matched chain: a hit is only usable as deep as a snapshot
+  exists. An entry lives as long as the block it is recorded on.
 
 Fault sites: ``serving.alloc_block`` fires on every physical block
 allocation (a `raise` action is deterministic pool exhaustion mid-
@@ -43,8 +52,8 @@ import numpy as np
 from ..framework import faults
 
 __all__ = ["BLOCK_ROW_ORDER", "NULL_BLOCK", "CacheLayout", "PoolExhausted",
-           "BlockAllocator", "PrefixCache", "positions_to_rows",
-           "stored_width"]
+           "BlockAllocator", "PrefixCache", "SnapshotEntries",
+           "positions_to_rows", "stored_width"]
 
 #: physical block 0 — reserved scratch target for padding writes
 NULL_BLOCK = 0
@@ -58,6 +67,8 @@ NULL_BLOCK = 0
 BLOCK_ROW_ORDER = "thd"
 
 _ROOT = b"\x00root"
+# numpy has no bfloat16: its itemsize is float16's
+_NUMPY_NAME = {"bfloat16": "float16"}
 
 
 class CacheLayout:
@@ -75,14 +86,29 @@ class CacheLayout:
     they leave a pool: an exported or spilled block in an order its
     reader does not know is refused by that name. `head_axis` is the
     pool axis a mesh may shard over its model-parallel degree; None =
-    the pool has no head axis and is replicated."""
+    the pool has no head axis and is replicated.
 
-    def __init__(self, row_order, arrays, layers, head_axis=None):
+    The second kind: `state` is ``((name, shape, dtype), ...)``, the
+    arrays one SLOT keeps of each of `state_layers` state-holding
+    layers, whatever its context (a recurrent state, a filter's tail).
+    The engine allocates ``[rows, *shape]`` of each, a row a slot and a
+    row a snapshot entry. `state_head_axis` maps an array's name to the
+    axis of that allocation a mesh may shard over mp; an array it does
+    not name is replicated. A layout that declares no state (dense and
+    latent attention) is carried exactly as before."""
+
+    def __init__(self, row_order, arrays, layers, head_axis=None,
+                 state=(), state_layers=0, state_head_axis=None):
         self.row_order = str(row_order)
         self.arrays = tuple((str(n), tuple(int(d) for d in shape))
                             for n, shape in arrays)
         self.layers = int(layers)
         self.head_axis = head_axis
+        self.state_layers = int(state_layers)
+        self.state = tuple((str(n), tuple(int(d) for d in shape), str(dt))
+                           for n, shape, dt in state) \
+            if self.state_layers else ()
+        self.state_head_axis = dict(state_head_axis or {})
 
     def pool_shapes(self, num_blocks, block_size):
         """The pools' shapes, one per array of a layer."""
@@ -93,6 +119,18 @@ class CacheLayout:
         """Cache bytes one token occupies over all layers."""
         return int(self.layers * itemsize
                    * sum(int(np.prod(row)) for _, row in self.arrays))
+
+    def state_shapes(self, rows):
+        """The state arrays' shapes with `rows` leading rows, one per
+        array of a state-holding layer."""
+        return [(int(rows),) + shape for _, shape, _ in self.state]
+
+    def state_bytes_per_slot(self):
+        """Bytes one slot's state occupies over all state layers; as
+        much again a snapshot entry."""
+        return int(self.state_layers * sum(
+            int(np.prod(shape)) * np.dtype(_NUMPY_NAME.get(dt, dt)).itemsize
+            for _, shape, dt in self.state))
 
 
 def stored_width(columns):
@@ -185,6 +223,31 @@ class BlockAllocator:
         return int(self._ref[bid])
 
 
+class SnapshotEntries:
+    """Free list over the `n` entries of a state snapshot pool: each
+    holds one slot's whole state as it was at a block boundary. An
+    entry belongs to a live request (its working entry, overwritten at
+    every boundary it lands on) or to the prefix cache (recorded on a
+    block), never to both."""
+
+    def __init__(self, n):
+        self.n = int(n)
+        self._free = list(range(self.n - 1, -1, -1))
+
+    @property
+    def free_entries(self):
+        return len(self._free)
+
+    def alloc(self):
+        """A free entry, or None when every one is held."""
+        return self._free.pop() if self._free else None
+
+    def free(self, entry):
+        if entry in self._free or not 0 <= entry < self.n:
+            raise ValueError(f"free of snapshot entry {entry}, not held")
+        self._free.append(entry)
+
+
 class PrefixCache:
     """Radix prefix index over fully written KV blocks.
 
@@ -195,9 +258,18 @@ class PrefixCache:
     prefix (`match` -> the caller increfs per consuming slot).
     """
 
-    def __init__(self, allocator: BlockAllocator, block_size):
+    def __init__(self, allocator: BlockAllocator, block_size,
+                 snapshots: SnapshotEntries = None):
         self._alloc = allocator
         self.block_size = block_size
+        #: the entries of the state snapshot pool, for a layout with
+        #: state arrays (None otherwise); `_snap` maps a key to the
+        #: entry holding the state exactly at that block's end
+        self.snapshots = snapshots
+        self._snap: dict = {}
+        #: called once for every recorded snapshot that eviction (not a
+        #: deeper snapshot on its chain) dropped
+        self.snapshot_evicted_hook = None
         self._blocks: dict = {}     # key -> block id
         self._chunks: dict = {}     # key -> np.int32 chunk tokens
         self._parent: dict = {}     # key -> parent key
@@ -260,17 +332,89 @@ class PrefixCache:
                 self._touch(best_key)
         return blocks, n, cow
 
-    def insert(self, tokens, blocks, written):
+    def match_snapshot(self, ids, limit):
+        """`match` for a layout with state arrays: the longest indexed
+        prefix of ``ids[:limit]``, cut at the deepest block on it that
+        a state snapshot is recorded on.
+
+        Returns ``(blocks, n_tokens, entry, n_matched)``: the shared
+        blocks and the tokens they cover as far as that snapshot (none
+        and 0 without one), the snapshot entry to restore the slot's
+        state from (None = start from zero), and how many tokens'
+        blocks matched in all; ``n_matched - n_tokens`` of them lie
+        deeper than any snapshot and have to be computed again. No
+        copy-on-write candidate: a state cannot be cut inside a
+        block."""
+        bs = self.block_size
+        blocks, n, keys = [], 0, []
+        while n + bs <= limit:
+            key = self._digest(ids[:n + bs])
+            bid = self._blocks.get(key)
+            if bid is None:
+                break
+            blocks.append(bid)
+            keys.append(key)
+            n += bs
+        deep = max((i for i, key in enumerate(keys) if key in self._snap),
+                   default=-1) + 1
+        for key in keys[:deep]:
+            self._touch(key)
+        entry = self._snap[keys[deep - 1]] if deep else None
+        return blocks[:deep], deep * bs, entry, n
+
+    def _record_snapshot(self, key, entry):
+        """`entry` holds the state at the end of `key`'s block: record
+        it there and free what it supersedes, an older entry on the
+        same key and every shallower one on its chain (the sequence
+        that left it resumes from the deepest)."""
+        old = self._snap.get(key)
+        if old is not None:
+            self.snapshots.free(old)
+        self._snap[key] = entry
+        parent = self._parent[key]
+        while parent != _ROOT:
+            shallower = self._snap.pop(parent, None)
+            if shallower is not None:
+                self.snapshots.free(shallower)
+            parent = self._parent[parent]
+
+    def evict_lru_snapshot(self):
+        """Free the least recently used recorded snapshot (its blocks
+        stay indexed, unusable until a sequence records one on them
+        again). Returns whether there was one."""
+        if not self._snap:
+            return False
+        key = min(self._snap, key=lambda k: self._lru[k])
+        self._drop_snapshot(key)
+        return True
+
+    def _drop_snapshot(self, key):
+        entry = self._snap.pop(key, None)
+        if entry is not None:
+            self.snapshots.free(entry)
+            if self.snapshot_evicted_hook is not None:
+                self.snapshot_evicted_hook()
+
+    def insert(self, tokens, blocks, written, snapshot=None):
         """Index every fully written block of a finished sequence.
 
         `tokens` is the full id sequence, `blocks` its physical block
         list (table order), `written` how many positions hold real KV
         (the last sampled token is never written). Newly indexed blocks
         gain one allocator reference (the cache's own); already-indexed
-        prefixes are just LRU-refreshed. Returns #new entries."""
+        prefixes are just LRU-refreshed. Returns #new entries.
+
+        `snapshot` ``(entry, depth)`` hands over the snapshot entry that
+        holds the sequence's state after exactly `depth` tokens: it is
+        recorded on the block that ends there, or freed when no block
+        does."""
         bs = self.block_size
         tokens = np.asarray(tokens, np.int32)
         parent, added = _ROOT, 0
+        if snapshot is not None:
+            entry, depth = snapshot
+            at = self._digest(tokens[:depth]) \
+                if 0 < depth <= written and depth % bs == 0 else None
         for k in range(1, written // bs + 1):
             key = self._digest(tokens[:k * bs])
             if key not in self._blocks:
@@ -283,6 +427,11 @@ class PrefixCache:
                 added += 1
             self._touch(key)
             parent = key
+        if snapshot is not None:
+            if at is None:
+                self.snapshots.free(entry)
+            else:
+                self._record_snapshot(at, entry)
         return added
 
     def prefix_tokens(self, key):
@@ -303,6 +452,7 @@ class PrefixCache:
             # still exists — the decref below frees it for reuse
             self.spill_hook(key, self.prefix_tokens(key), bid,
                             len(self._chunks[key]))
+        self._drop_snapshot(key)
         self._children.get(self._parent[key], set()).discard(key)
         self._children.pop(key, None)
         bid = self._blocks.pop(key)

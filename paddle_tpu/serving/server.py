@@ -66,6 +66,10 @@ class Server:
     (FLAGS_serving_prefix_affinity or
     ``fleet=dict(prefix_affinity=...)``).
 
+    A model whose cache layout keeps per-slot state arrays (recurrent
+    layers) gets ``snapshot_entries=`` entries of state snapshot pool
+    (default three a slot): what lets a session's next turn resume.
+
     Multi-tenant serving: ``max_adapters=N`` gives every engine an
     N-row batched LoRA adapter bank (``submit(..., adapter_id=k)``;
     row 0 = base model) and ``tenancy=TenantDirectory(...)`` switches
@@ -81,7 +85,7 @@ class Server:
                  warmup=True, replicas=1, fleet=None, spec_len=None,
                  draft_model=None, quantize=None, w8a8=None, mesh=None,
                  spill_dir=None, max_adapters=None, lora_rank=None,
-                 tenancy=None):
+                 tenancy=None, snapshot_entries=None):
         self.mode = mode
         self.metrics = ServingMetrics()
         self._warmup = warmup
@@ -99,7 +103,7 @@ class Server:
                 spec_len=spec_len, draft_model=draft_model,
                 quantize=quantize, w8a8=w8a8, mesh=mesh,
                 spill_dir=spill_dir, max_adapters=max_adapters,
-                lora_rank=lora_rank)
+                lora_rank=lora_rank, snapshot_entries=snapshot_entries)
             self.batcher = None
         if mode == "generate" and (replicas > 1 or fleet is not None):
             from .fleet import Router

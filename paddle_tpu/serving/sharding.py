@@ -22,7 +22,9 @@ and only when the head count divides the mp degree — otherwise (and
 for a layout with no head axis, such as latent rows) they stay
 replicated and the engine still serves (block tables are host-side
 numpy either way, so they remain replica-global; see
-`ShardingPlan.pool_sharding`).
+`ShardingPlan.pool_sharding`). The per-slot state arrays of a layout
+that declares them follow the same rule over the head axis the layout
+names for each (`ShardingPlan.state_sharding`).
 """
 
 from __future__ import annotations
@@ -186,7 +188,16 @@ class ShardingPlan:
         (latent rows) or whose heads do not divide mp is replicated
         (the engine still serves; it just stops saving cache memory —
         same silent-guard stance as the overlap kernels)."""
-        axis = layout.head_axis
+        return self._over_heads(layout.head_axis, shape)
+
+    def state_sharding(self, layout, name, shape):
+        """Sharding of the per-slot state array `name` of `shape`
+        ``[rows, ...]``: over the head axis the layout names for it
+        when mp divides it, else replicated, as `pool_sharding` does
+        for pools."""
+        return self._over_heads(layout.state_head_axis.get(name), shape)
+
+    def _over_heads(self, axis, shape):
         if self.mp > 1 and axis is not None and shape[axis] % self.mp == 0:
             spec = [None] * len(shape)
             spec[axis] = MP_AXIS

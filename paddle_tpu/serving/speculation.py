@@ -110,6 +110,15 @@ class Speculation:
         if is_quantized_state(self.values):
             self.values = dict(dequantize_state(self.values))
         self.layout = self.model.cache_layout()
+        # a rejected draft is rolled out of a KV pool by position (its
+        # rows are masked, then overwritten); out of a recurrent state
+        # it cannot be
+        eng._refuse_state_arrays("speculation (spec_len > 0)")
+        if self.layout.state:
+            raise ValueError(
+                "speculation: the draft model's cache layout keeps "
+                "per-slot state arrays, which a rejected draft cannot "
+                "be rolled out of")
         self.pools = eng._zero_pools(self.layout, place=False)
         self.pool_bytes = eng._pool_bytes(self.layout)
         # the draft trace is a separate, narrower program
