@@ -7,9 +7,12 @@ The runtime's engines wrap each stage of a step in a named phase::
     compile              a step call that traces/compiles a new program
     device-step          training: the compiled step's dispatch (the
                          enqueue; closing it would need a sync).
-                         serving: dispatch -> logits on the host, the
-                         interval the `decode`/`prefill` series get
-                         (folded in with `add`, it has no span of its own)
+                         serving: the time a step held the device as
+                         the host sees it, later of (its dispatch, the
+                         previous step's landing) -> its picks on the
+                         host, the interval the `decode`/`prefill`
+                         series get (folded in with `add`, it has no
+                         span of its own)
     anomaly-readback     the guard's host sync at step boundaries
     checkpoint-snapshot  device->host state copy on the step thread
     checkpoint-write     synchronous checkpoint serialization + commit
@@ -24,7 +27,9 @@ and the serving loop's thread spans every part of an iteration::
     sample               host-side token sampling + slot bookkeeping
     draft                the speculative draft phase
     dispatch             staging the step's host arrays + the jit call
-    readback             the wait for the logits (`np.asarray`)
+    readback             the wait for a step's picks (`device_get`):
+                         the step just dispatched, or the one before it
+                         when the loop keeps a step in flight
     commit               the post-step slot loop + the metrics calls
 
 and the input pipeline's consumer (parent process only)::

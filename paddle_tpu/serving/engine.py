@@ -75,6 +75,45 @@ allocation and SGLang-style prefix sharing:
   that fetches its one row for a sampling request or a check. The
   counters `device_picks`, `logit_rows_fetched` and `readback_bytes`
   say what crossed.
+- The loop keeps ONE STEP IN FLIGHT. A step has two halves: `_launch`
+  (the sweep over the slots, the one array staged, the dispatch, and
+  every piece of bookkeeping that positions alone decide) and `_land`
+  (the read-back of `pick` and `aux`, the tokens, the counts). `_step()`
+  is one whole step, launch then land; `_iterate` orders the halves the
+  other way, `new = launch(); land(old)`, so the host reads step n
+  while step n+1 runs. The token a decoding row feeds back then never
+  visits the host first: the row's column 0 says `_FROM_PICK` and the
+  compiled step takes it from the previous step's `pick`, which it is
+  handed as a device array (`extras["prev_pick"]`). One program, the
+  same in both orders. The loop lands a step before it launches the
+  next whenever only the host can make the next token (`_host_draws`:
+  a live `do_sample` row, a speculative engine), so no knob says which.
+  A step lands with its tokens taken at once when a later launch has
+  swept it (every one of its rows is then staged behind its pick, or
+  was not staged because the pick is its last); a step landed by hand
+  or in order leaves `next_token` to the next sweep, as it always did.
+  What the host then learns a step late, and why each case is safe:
+  (a) `max_new_tokens` is known at launch, counting the pick in
+  flight, and such a row is not staged again: no wasted column. (b)
+  EOS, a cancel and a deadline are seen with one more column of the
+  row in flight; its pick is dropped, never appended
+  (`columns_wasted`). (c) A pick lands on the `_Slot` it was launched
+  for, not on the slot's index, which may hold another request by
+  then. (d) The blocks an eviction frees may be written once more by
+  the step in flight, at the position `written` itself: beyond every
+  row `PrefixCache.insert` donates, and a later owner of the block
+  writes its own rows (behind that step, in device order) before its
+  mask admits them. (e) The row copies of a layout with state arrays
+  (`_seed_state`, `_take_snapshots`) are enqueued between the same two
+  steps as when the host waited, since positions alone decide them; a
+  snapshot taken behind a wasted column lies deeper than what the
+  request wrote and is freed, not recorded. (f) A step that failed is
+  learnt of at `_land`: the step launched on its outputs is dropped
+  with it, every live request fails and the pools are rebuilt, as
+  before. Whatever must see the engine between two steps (a boundary
+  call, an abort, an idle or drained loop) lands the step in flight
+  first (`_settle`). Counters `steps_launched_ahead` (of `steps`) and
+  `columns_wasted`.
 - Prefix sharing: finished sequences index their fully written blocks
   in a radix `PrefixCache` keyed on cumulative token-prefix hashes.
   A new request reuses every matching block physically (refcounted),
@@ -149,9 +188,13 @@ on a profiler capture's clock, plus a timeline aggregate):
 wait when nothing is live or queued; inside an iteration ``step.admit``,
 ``step.sample``, then ``serving.step`` round ``step.dispatch`` (the
 host's one array built, the jit call) and ``step.readback`` (the wait
-for the step's picks), then ``step.commit``. The dispatch -> read-back
-interval is the `decode` / `prefill` series' sample and the timeline's
-``device-step``. A request's own stamps (queueing.Request) are folded
+for a step's picks: the step just dispatched when driven by hand or in
+order, the one before it when the loop runs ahead), then
+``step.commit``. A step's sample in the `decode` / `prefill` series
+and the timeline's ``device-step`` is the time it held the device as
+the host sees it: from the later of its own dispatch and the previous
+step's landing to its own landing. A request's own stamps
+(queueing.Request) are folded
 once, when it leaves its slot with an answer: into the series `ttft`,
 `prefill_req`, `itl`, and into the profiler ring as ``request.queue``,
 ``request.prefill`` (with `steps` and `prefix_hit_tokens`) and
@@ -162,6 +205,7 @@ the request's `id`. A request that fails folds nothing.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import threading
 import time
 
@@ -212,6 +256,10 @@ class _Slot:
         # pick behind it, or None (a resample: nothing to pick from)
         self.next_token = None
         self.next_logits = None
+        # this slot's picks still on the device: columns launched whose
+        # pick has not landed (two between a launch and the landing of
+        # the step before it, else at most one)
+        self.flying = 0
         self.rng = None
         if req.gen.get("do_sample"):
             self.rng = np.random.RandomState(req.gen.get("seed", 0))
@@ -241,6 +289,57 @@ class _LogitRow:
         row = np.asarray(self._logits[self._slot])
         self._metrics.inc("logit_rows_fetched")
         return row if dtype is None else row.astype(dtype, copy=False)
+
+
+# column 0 of a row whose last token the host has not seen: the step
+# takes it from the previous step's `pick` (no token id is negative)
+_FROM_PICK = -1
+
+
+@dataclasses.dataclass(eq=False)
+class _Flight:
+    """A step from its launch to its landing: what it left on the
+    device (`out`), when it was dispatched (`at`), what `_launch`
+    counted for `_land` to report, and the slots whose next token is
+    among its picks (`rows`, by `_Slot` and not by index alone).
+    `swept` says that a later launch has swept the slots since."""
+
+    out: dict
+    at: float
+    ahead: bool
+    inplace: bool
+    live: int
+    decoding: int
+    prefill_tokens: int
+    computed: int
+    context: int
+    rows: list = dataclasses.field(default_factory=list)
+    swept: bool = False
+
+
+class _StepSpan:
+    """The span `serving.step`: round the dispatch and the read-back of
+    one call of `_step()` or one iteration of the loop, which share it.
+    Whichever half comes first opens it; the read-back closes it."""
+
+    def __init__(self):
+        self._event = None
+
+    def open(self):
+        if self._event is None:
+            self._event = profiler.RecordEvent("serving.step",
+                                               cat="serving").__enter__()
+
+    def close(self):
+        if self._event is not None:
+            self._event.__exit__(None, None, None)
+            self._event = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
 
 
 class SlotEngine:
@@ -513,6 +612,13 @@ class SlotEngine:
         # loop applies, so a rebind never races the step's own updates
         self._boundary_q: list = []
         self._boundary_lock = threading.Lock()
+        # the step the loop has launched and not yet landed (`_Flight`;
+        # None when driven by hand, between `_step()` calls), the clock
+        # at the last landing, and the last dispatched step's `pick`,
+        # which the next is handed on the device
+        self._flight = None
+        self._landed = 0.0
+        self._prev_pick = self._no_pick()
 
         self._build_programs(self.spec_len + 1 if self.spec_len else 0)
         # what only speculation knows: the draft model, its pools and
@@ -540,8 +646,12 @@ class SlotEngine:
         `pos`, `nvalid`, the slot's block table and, on an engine with
         adapters, `aid`; the step slices it apart in its first lines.
         `extras` holds the device-resident arguments that are not
-        weights (`act_scale` under w8a8; `lora_a`, `lora_b` with
-        adapters; empty on a plain engine). `out` is what the step
+        weights: `prev_pick` (the `pick` of the step dispatched before
+        this one, never read by the host for this purpose: a row whose
+        column 0 of the token chunk says `_FROM_PICK` takes its entry
+        of it for that column, every other row computes what the host
+        wrote), `act_scale` under w8a8, `lora_a` and `lora_b` with
+        adapters. `out` is what the step
         leaves on the device, `pools` what it is handed donated and
         hands back (`_held`: the layers' pools, or for a layout with
         state arrays `{"blocks": pools, "state": state arrays}`, of
@@ -610,6 +720,9 @@ class SlotEngine:
                     for name, at in self._batch_cols.items()}
             tok, pos, nvalid = cols["tok"], cols["pos"], cols["nvalid"]
             tables, aid = cols["tables"], cols.get("aid")
+            # the token a row fed back while the host had not seen it
+            tok = jnp.where(tok == _FROM_PICK,
+                            extras["prev_pick"][:, None], tok)
             act_scale = extras.get("act_scale")
             la, lb = extras.get("lora_a"), extras.get("lora_b")
             # trace-time only: the compile counter + retrace registry
@@ -854,6 +967,16 @@ class SlotEngine:
                                                        shardings))
                 for _ in range(layout.state_layers)]
 
+    def _no_pick(self):
+        """`prev_pick` for a step with no step before it (the first,
+        and the first after the pools were rebuilt): no row reads it."""
+        import jax
+        import jax.numpy as jnp
+
+        zeros = jnp.zeros((self.max_slots,), jnp.int32)
+        return zeros if self._plan is None \
+            else jax.device_put(zeros, self._plan.replicated())
+
     def _held(self):
         """What a step is handed, donated, and hands back: the pools,
         and with them the state arrays of a layout that declares
@@ -917,6 +1040,8 @@ class SlotEngine:
             self._pools = self._zero_pools(self._layout)
             if self._state:
                 self._state = self._zero_state()
+        # the lost step's pick went with it
+        self._prev_pick = self._no_pick()
         self.metrics.inc("pool_rebuilds")
 
     # -- w8a8 activation scale (frozen after a short calibration) -----------
@@ -961,7 +1086,7 @@ class SlotEngine:
         batch = np.empty((self.max_slots, self._batch_width), np.int32)
         for name, at in self._batch_cols.items():
             batch[:, at] = said[name]
-        extras = {}
+        extras = {"prev_pick": self._prev_pick}
         if self.w8a8:
             # 0 degrades the step to the weights-only dequant path
             # inside the same trace
@@ -981,6 +1106,7 @@ class SlotEngine:
             out, held = self._decode(self._values, batch, self._held(),
                                      extras)
             self._rebind(held)
+        self._prev_pick = out["pick"]
         if self.w8a8:
             self._absorb_act_amax(out.pop("amax"))
         return out
@@ -1011,6 +1137,7 @@ class SlotEngine:
                 if not self._boundary_q:
                     return
                 call, done, box = self._boundary_q.pop(0)
+            self._settle()
             try:
                 box["result"] = call()
             except Exception as e:  # noqa: BLE001 — caller re-raises
@@ -1459,10 +1586,12 @@ class SlotEngine:
         if n_rows != self.block_size:
             return
         try:
-            # the cache evicts on the loop's thread, between steps (or
-            # with no loop running): no dispatch is in flight, so the
-            # pools are whole; the block is still cache-referenced, so
-            # its rows cannot be recycled before the hook returns
+            # the cache evicts on the loop's thread, outside a dispatch
+            # (or with no loop running), so the pools are whole: a step
+            # in flight has left its outputs in their place, and the
+            # read below waits for it; the block is still
+            # cache-referenced, so its rows cannot be recycled before
+            # the hook returns
             layers = [tuple(np.asarray(a[bid]) for a in layer)
                       for layer in self._pools]
             self.spill_store.append(key, self.weight_version, tokens,
@@ -1603,7 +1732,16 @@ class SlotEngine:
         slot = self._slots[idx]
         self._slots[idx] = None
         self._free.append(idx)
-        written = int(self._pos[idx])
+        # a column of this slot still in flight is wasted: its pick is
+        # dropped when it lands, and what it writes (one row, at the
+        # position `written` itself, in a block freed below) lies beyond
+        # every row the prefix index is given; whoever owns the block
+        # next writes its own rows behind that step, in device order,
+        # before its mask admits them. A state snapshot taken behind
+        # such a column is deeper than `written`: `insert` frees it
+        if slot.flying:
+            self.metrics.inc("columns_wasted", slot.flying)
+        written = int(self._pos[idx]) - slot.flying
         snapshot = None if slot.entry is None \
             else (slot.entry, slot.snap_depth)
         if error is None and self._cache is not None:
@@ -1667,13 +1805,26 @@ class SlotEngine:
                 self._evict(i, error)
 
     def _step(self):
-        """One continuous-batching iteration: consume each decoding
-        slot's pending logits (finishing slots that hit
-        EOS/max/deadline), stage the next chunk for prefilling slots,
-        then ONE batched step over the whole pool, then commit what it
-        computed. A speculative engine drafts between the consume and
-        the dispatch and accepts after the commit (speculation.py); a
-        plain one is the round in which nothing was drafted."""
+        """One whole continuous-batching step, by hand: launch it, then
+        land it. (The loop orders the halves the other way, `_iterate`.)
+        A speculative engine drafts inside the launch and accepts
+        inside the landing (speculation.py); a plain one is the round
+        in which nothing was drafted."""
+        with _StepSpan() as span:
+            flight = self._launch(span)
+            if flight is not None:
+                self._land(flight, span)
+
+    def _launch(self, span):
+        """The first half of a step, all the host can do before the
+        step's picks exist: take each decoding slot's landed token
+        (finishing slots that hit EOS/max/deadline), stage the next
+        chunk for prefilling slots and `_FROM_PICK` for a slot whose
+        token is still on the device, ONE batched dispatch over the
+        whole pool, then what positions alone decide: `_pos`, `fill`,
+        prefill -> decode, the handle on the step's logits, state
+        snapshots. Returns the `_Flight` to land, or None when nothing
+        was dispatched (nothing live, or a fault at ``serving.step``)."""
         if self.mesh is not None:
             # raise here propagates to _loop like any step error: the
             # engine survives and the Router replays the in-flight work
@@ -1696,7 +1847,7 @@ class SlotEngine:
             faults.fault_point("serving.step")
         except Exception as e:  # noqa: BLE001 — deterministic mid-decode
             self._fail_all_active(e)
-            return
+            return None
         now = time.monotonic()
         tok = np.zeros((self.max_slots, self.prefill_chunk), np.int32)
         # an idle slot has no valid column
@@ -1704,123 +1855,173 @@ class SlotEngine:
         live: list = []
         with observe.phase("sample", cat="serving"):
             prefill_tokens = self._consume(now, tok, nvalid, live)
+        if self._flight is not None:
+            # of the rows of the step in flight, each is by now staged
+            # behind its pick, evicted, or waiting for its last token
+            self._flight.swept = True
         if not live:
-            return
-        spec = self._spec
-        if spec is not None:
-            spec.propose(live, tok, nvalid)
-        n_pref = sum(1 for i in live
-                     if self._slots[i].state == "prefill")
-        out, t0, done = self._device_step(tok, nvalid)
+            return None
+        if self._spec is not None:
+            self._spec.propose(live, tok, nvalid)
+        computed, context = self._columns(live, nvalid)
+        decoding = sum(1 for i in live if self._slots[i].state == "decode")
+        handed = self._arrays(self._pools + self._state)
+        at = time.monotonic()
+        span.open()
+        with observe.phase("dispatch", cat="serving"):
+            out = self._dispatch(tok, self._pos, nvalid)
+        logits = out.pop("logits")
+        # the step is handed the pools and updates them in place: the
+        # arrays that went in read `is_deleted()` from the dispatch on.
+        # A backend that copied instead leaves them alive, and
+        # `pool_inplace_steps` behind `steps`
+        flight = _Flight(
+            out, at, ahead=self._flight is not None,
+            inplace=all(a.is_deleted() for a in handed), live=len(live),
+            decoding=decoding, prefill_tokens=prefill_tokens,
+            computed=computed, context=context)
+        for i in live:
+            slot = self._slots[i]
+            self._pos[i] += slot.advance
+            if slot.state == "prefill":
+                slot.req.prefill_steps += 1
+                slot.fill += slot.advance
+                if slot.fill < slot.prompt_len:
+                    continue
+                slot.state = "decode"
+                self.metrics.inc("prefills")
+            slot.flying += 1
+            slot.next_logits = _LogitRow(logits, i, self.metrics)
+            flight.rows.append((i, slot))
+        if self._state:
+            self._take_snapshots(live)
+        return flight
+
+    def _land(self, flight, span):
+        """The second half of a step: the read-back of what the host
+        needs of it (each slot's `pick`, what the model's step counted
+        in `aux`, a speculative engine's verify logits: one blocking
+        transfer, its size counted in `readback_bytes`; `logits` stay
+        the device's), then the tokens and the counts. A pick lands on
+        the `_Slot` it was launched for; one whose slot has gone since
+        (EOS, a cancel, a deadline, a failure) is dropped. A step that
+        a later launch has swept takes its tokens here, at once: that
+        launch staged each of its rows behind this pick or, the pick
+        being the row's last, not at all, so the host has nothing left
+        to decide. Any other step leaves `next_token` to the next
+        launch's sweep, which takes it and stages it."""
+        import jax
+
+        span.open()
+        try:
+            with observe.phase("readback", cat="serving"):
+                out = jax.device_get(flight.out)
+        except Exception:
+            # dispatched, and its picks cannot be read: what it (and
+            # any step launched on its outputs) left in place of the
+            # pools is no KV to serve from
+            for a in self._arrays(self._pools + self._state):
+                a.delete()
+            raise
+        finally:
+            span.close()
+        done = time.monotonic()
         with observe.phase("commit", cat="serving"):
-            self._observe_step_latency(done - t0, prefill_tokens,
-                                       len(live) - n_pref)
-            self._count_computed(live, nvalid, out["aux"])
-            logits, pick = out["logits"], out["pick"]
-            for i in live:
-                slot = self._slots[i]
-                self._pos[i] += slot.advance
-                if slot.state == "prefill":
-                    slot.req.prefill_steps += 1
-                    slot.fill += slot.advance
-                    if slot.fill < slot.prompt_len:
-                        continue
-                    slot.state = "decode"
-                    self.metrics.inc("prefills")
+            self._observe_step_latency(
+                done - max(flight.at, self._landed), flight.prefill_tokens,
+                flight.decoding)
+            self._landed = done
+            if flight.inplace:
+                self.metrics.inc("pool_inplace_steps")
+            if flight.ahead:
+                self.metrics.inc("steps_launched_ahead")
+            self.metrics.inc("readback_bytes", sum(
+                a.nbytes for a in jax.tree_util.tree_leaves(out)))
+            self.metrics.inc("computed_tokens", flight.computed)
+            self.metrics.inc("attn_context_tokens", flight.context)
+            self._count_aux(out["aux"])
+            pick = out["pick"]
+            for i, slot in flight.rows:
+                if self._slots[i] is not slot:
+                    continue
+                slot.flying -= 1
                 slot.next_token = int(pick[i])
-                slot.next_logits = _LogitRow(logits, i, self.metrics)
-            if spec is not None:
-                spec.commit(out["verify"], done)
-            if self._state:
-                self._take_snapshots(live)
+                if flight.swept:
+                    self._take(i, slot, done)
+            if self._spec is not None:
+                self._spec.commit(out["verify"], done)
             self.metrics.inc("steps")
-            if prefill_tokens:
-                self.metrics.inc("prefill_tokens", prefill_tokens)
-            self.metrics.observe_occupancy(len(live), self.max_slots)
+            if flight.prefill_tokens:
+                self.metrics.inc("prefill_tokens", flight.prefill_tokens)
+            self.metrics.observe_occupancy(flight.live, self.max_slots)
             self.metrics.observe_blocks(self._alloc.blocks_in_use,
                                         self._alloc.usable)
 
-    def _device_step(self, tok, nvalid):
-        """The iteration's one dispatch of the compiled step and the
-        read-back of what the host needs of it: each slot's `pick`,
-        what the model's step counted in `aux`, a speculative engine's
-        verify logits (one blocking transfer, its size counted in
-        `readback_bytes`). Returns the step's `out` as host arrays but
-        for `logits`, which stay the device's, and the clock before the
-        dispatch and after the read-back.
-
-        The step is handed the pools and updates them in place: the
-        arrays that went in read `is_deleted()` afterwards, which
-        `pool_inplace_steps` counts. A backend that copied instead
-        leaves them alive and the counter behind `steps`."""
-        import jax
-
-        pools, handed = self._pools, self._pools + self._state
-        t0 = time.monotonic()
-        try:
-            with profiler.RecordEvent("serving.step", cat="serving"):
-                with observe.phase("dispatch", cat="serving"):
-                    out = self._dispatch(tok, self._pos, nvalid)
-                with observe.phase("readback", cat="serving"):
-                    logits = out.pop("logits")
-                    out = jax.device_get(out)
-        except Exception:
-            if self._pools is not pools:
-                # dispatched, and its picks cannot be read: what it
-                # left in place of the pools is no KV to serve from
-                for a in self._arrays(self._pools + self._state):
-                    a.delete()
-            raise
-        done = time.monotonic()
-        if all(a.is_deleted() for a in self._arrays(handed)):
-            self.metrics.inc("pool_inplace_steps")
-        self.metrics.inc("readback_bytes", sum(
-            a.nbytes for a in jax.tree_util.tree_leaves(out)))
-        out["logits"] = logits
-        return out, t0, done
-
     def _observe_step_latency(self, dt, prefill_tokens, n_decoding):
-        """Attribute one device step, dispatch to its picks on the
-        host, to the phase-latency series: a step staging prompt tokens
-        is a 'prefill' sample, a step advancing at least one decoding
-        slot is a 'decode' sample (a mixed colocated step is honestly
-        both — decoding slots really did wait for the chunk-wide
-        prefill program). These feed the decode p99 / prefill p50
-        columns the disaggregation bench compares. The same interval is
-        the timeline's `device-step`, the productive time of
-        `observe.goodput()`."""
+        """Attribute one device step to the phase-latency series: the
+        time it held the device as the host sees it, from the later of
+        its own dispatch and the previous step's landing to its own
+        landing (dispatch to picks on the host when nothing was in
+        flight before it; the period when the loop runs ahead). A step
+        staging prompt tokens is a 'prefill' sample, a step with at
+        least one slot decoding behind it is a 'decode' sample (a mixed
+        colocated step is honestly both — decoding slots really did
+        wait for the chunk-wide prefill program). These feed the decode
+        p99 / prefill p50 columns the disaggregation bench compares.
+        The same interval is the timeline's `device-step`, the
+        productive time of `observe.goodput()`."""
         observe.timeline.add("device-step", dt)
         if prefill_tokens:
             self.metrics.observe_latency("prefill", dt)
         if n_decoding:
             self.metrics.observe_latency("decode", dt)
 
-    def _count_computed(self, live, nvalid, aux):
-        """What this step's REAL columns cost, before the commit moves
-        `_pos`: `computed_tokens` (prompt tokens computed, not hit,
-        plus tokens fed back) and `attn_context_tokens` (the keys each
-        of them attended: its position + 1); padding columns count
-        nothing. And what the model's own step counted (`aux`, and
-        the constants it named at trace time), into `aux_totals` and a
-        counter of the same name."""
+    def _columns(self, live, nvalid):
+        """What a step's REAL columns cost, before the launch moves
+        `_pos`: ``(computed_tokens, attn_context_tokens)``, the prompt
+        tokens computed, not hit, plus tokens fed back, and the keys
+        each of them attends (its position + 1); padding columns count
+        nothing."""
         computed = context = 0
         for i in live:
             n, at = int(nvalid[i]), int(self._pos[i])
             computed += n
             context += n * at + n * (n + 1) // 2
-        self.metrics.inc("computed_tokens", computed)
-        self.metrics.inc("attn_context_tokens", context)
+        return computed, context
+
+    def _count_aux(self, aux):
+        """What the model's own step counted (`aux`, and the constants
+        it named at trace time), into `aux_totals` and a counter of the
+        same name."""
         for name, value in {**aux, **self._aux_const}.items():
             value = np.asarray(value, np.int64)
             self.aux_totals[name] = self.aux_totals.get(name, 0) + value
             self.metrics.inc(name, int(value.sum()))
 
+    def _take(self, i, slot, now):
+        """The slot's landed token (`_pick`) joins its answer, stamped
+        `now`; EOS or `max_new_tokens` ends the request here. Returns
+        whether the request goes on."""
+        nxt = self._pick(slot)
+        slot.tokens.append(nxt)
+        slot.produced += 1
+        slot.req.token_times.append(now)
+        self.metrics.inc("tokens_out")
+        gen = slot.req.gen
+        eos = gen.get("eos_token_id")
+        if (eos is not None and nxt == eos) or \
+                slot.produced >= gen.get("max_new_tokens", 16):
+            self._evict(i)
+            return False
+        return True
+
     def _consume(self, now, tok, nvalid, live):
-        """Host-side half of a step: take each decoding slot's next
-        token (`_pick`; finish/evict on EOS/max/deadline/cancel), stage
-        the next prompt chunk for prefilling slots, and fill the fixed
-        [max_slots, chunk] token matrix for the unified dispatch.
-        Returns the number of prompt tokens staged this step."""
+        """The sweep that opens a launch: evict what was cancelled or
+        is past its deadline, take each decoding slot's landed token
+        (`_take`), stage the next prompt chunk for prefilling slots,
+        and fill the fixed [max_slots, chunk] token matrix for the
+        unified dispatch. Returns the number of prompt tokens staged
+        this step."""
         prefill_tokens = 0
         for i, slot in enumerate(self._slots):
             if slot is None:
@@ -1845,24 +2046,21 @@ class SlotEngine:
                 prefill_tokens += n
                 live.append(i)
                 continue
-            if slot.next_logits is None:
-                # the last commit appended a token with no logits behind
-                # it (a speculative round's resample): it was counted
-                # and EOS-checked there, its KV write happens now
-                nxt = slot.tokens[-1]
-            else:
-                nxt = self._pick(slot)
-                slot.tokens.append(nxt)
-                slot.produced += 1
-                req.token_times.append(now)
-                self.metrics.inc("tokens_out")
-                gen = req.gen
-                eos = gen.get("eos_token_id")
-                if (eos is not None and nxt == eos) or \
-                        slot.produced >= gen.get("max_new_tokens", 16):
-                    self._evict(i)
+            if slot.flying:
+                # its last token is a pick still on the device. If that
+                # pick is also its last (max_new_tokens counts it), the
+                # slot waits for it to land: no column
+                if slot.produced + 1 >= req.gen.get("max_new_tokens", 16):
                     continue
-            tok[i, 0] = nxt
+                tok[i, 0] = _FROM_PICK
+            elif slot.next_logits is None or self._take(i, slot, now):
+                # (no logits: the last commit appended a token with
+                # none behind it, a speculative round's resample; it
+                # was counted and EOS-checked there, its KV write
+                # happens now)
+                tok[i, 0] = slot.tokens[-1]
+            else:
+                continue
             nvalid[i] = 1
             slot.advance = 1
             live.append(i)
@@ -1898,11 +2096,13 @@ class SlotEngine:
                 self._beat()
                 self._drain_boundary_calls()
                 if self._abort.is_set():
+                    self._settle()
                     self._fail_all_active(
                         self._abort_error or RequestCancelled(
                             "server aborted (non-drain shutdown)"))
                     return
-                if self.active == 0 and self.queue.depth == 0:
+                if self.active == 0 and self.queue.depth == 0 \
+                        and self._flight is None:
                     if self.queue.drained():
                         return
                     with observe.span("loop.idle", cat="serving"):
@@ -1912,22 +2112,65 @@ class SlotEngine:
                     self._iterate()
 
     def _iterate(self):
-        """One working iteration: join-at-step admission, then a step
-        if anything is live (everything queued may have expired)."""
+        """One working iteration: join-at-step admission, then the next
+        step is launched and the one in flight landed, in that order,
+        so that the device is handed step n+1 before the host turns to
+        step n's picks. Where only the host can make the next step's
+        tokens the step just launched is landed too, and the iteration
+        is `_step()`'s; and with nothing to launch (everything queued
+        expired, or every live slot waits for its last pick) the
+        iteration only lands."""
         with observe.phase("admit", cat="serving"):
             self._admit()
-        if self.active == 0:
+        if self.active == 0 and self._flight is None:
             return
         try:
             if self.supervised:
                 faults.fault_point("serving.replica_step", tag=self.name)
-            self._step()
+            with _StepSpan() as span:
+                new = self._launch(span)
+                if self._flight is not None:
+                    self._land(self._flight, span)
+                self._flight = new
+                if new is not None and self._host_draws():
+                    self._flight = None
+                    self._land(new, span)
         except Exception as e:  # noqa: BLE001 — engine stays up
-            self.metrics.inc("step_errors")
-            self._fail_all_active(e)
-            # a step that raised after its dispatch took the donated
-            # pools with it; one that raised before it left them whole
-            self._recover_pools(e)
+            self._survive(e)
+
+    def _host_draws(self):
+        """Whether the next step needs a token that only the host can
+        make, so that the step in flight has to land before it is
+        staged: a live decoding slot that samples (its row is fetched
+        and drawn from here), or a speculative engine (`propose` reads
+        every slot's tokens)."""
+        return self._spec is not None or any(
+            slot is not None and slot.rng is not None
+            and slot.state == "decode" for slot in self._slots)
+
+    def _settle(self):
+        """Land the step in flight, if any: whatever must see the
+        engine between two steps (a boundary call, an abort, an idle
+        loop) comes through here first."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        try:
+            with _StepSpan() as span:
+                self._land(flight, span)
+        except Exception as e:  # noqa: BLE001 — engine stays up
+            self._survive(e)
+
+    def _survive(self, error):
+        """A step raised, in either half: every live request fails with
+        its error, a step in flight (launched on the failed one's
+        outputs, or never to be read) is dropped, and the engine goes
+        on. A step that raised after its dispatch took the donated
+        pools with it; one that raised before it left them whole."""
+        self.metrics.inc("step_errors")
+        self._flight = None
+        self._fail_all_active(error)
+        self._recover_pools(error)
 
     def abandon(self, error):
         """Supervisor-side takeover of a dead/hung replica: stop the

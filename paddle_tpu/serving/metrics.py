@@ -58,7 +58,12 @@ class ServingMetrics:
     own pick, no logits read), `logit_rows_fetched` (rows of a step's
     logits brought to the host, one at a time, for a sampling request
     or a check) and `readback_bytes` (bytes of the step's outputs the
-    host read, summed over steps), and the fast-decode set:
+    host read, summed over steps), what the loop's one step in flight
+    did: `steps_launched_ahead` (steps dispatched while the step before
+    them was still unread; of `steps`) and `columns_wasted` (a row's
+    column whose pick was dropped because the request had ended by the
+    time it landed: EOS, a cancel, a deadline or a failure seen a step
+    late), and the fast-decode set:
     `spec_drafted_tokens` / `spec_accepted_tokens` /
     `spec_rejected_tokens` / `spec_rounds` / `spec_draft_faults`
     (speculative decoding, fed via `observe_spec`, surfaced under

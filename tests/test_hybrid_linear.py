@@ -369,6 +369,72 @@ def test_a_failed_step_takes_pools_and_state_and_both_come_back(hybrid):
     np.testing.assert_allclose(got, want[24:], atol=TOL)
 
 
+def _by_hand(eng, prompt, **gen):
+    fut = eng.submit(np.asarray(prompt, np.int32), timeout=None, **gen)
+    eng._admit()
+    while eng.active:
+        eng._step()
+    return np.asarray(fut.result(5))
+
+
+def _served(eng, prompt, **gen):
+    """One request through the engine's own loop (started by the
+    caller), which keeps a step in flight."""
+    return np.asarray(eng.submit(np.asarray(prompt, np.int32),
+                                 timeout=None, **gen).result(60))
+
+
+@pytest.mark.parametrize("ending", ["max_new_tokens", "eos_on_a_boundary"])
+def test_snapshots_under_the_loop_are_those_of_steps_by_hand(hybrid, ending):
+    """The row copies that take and restore a snapshot are enqueued
+    between the same two steps whether or not the host waited for the
+    first, since positions alone decide them. A turn that ends by
+    `max_new_tokens` leaves under the loop the snapshot it leaves by
+    hand: the next turn resumes from it, as deep, and answers and slot
+    state are equal to the bit. A turn that ends by EOS has one more
+    column in flight; where that column crosses a block boundary it
+    snapshots a state one token deeper than the request wrote: that
+    snapshot is freed, not recorded, and the next turn starts from
+    zero and still answers what the by-hand engine answers."""
+    cfg, model = hybrid
+    prompt, more = _tokens(1, 33), _tokens(2, 21)   # 33 + 7 tokens = 40
+    plain = _stepped(_engine(model, max_slots=1), prompt, 12)[1]
+    gen = {"max_new_tokens": 6}
+    if ending == "eos_on_a_boundary":
+        assert plain[39] not in plain[33:39]
+        gen = {"max_new_tokens": 12, "eos_token_id": int(plain[39])}
+    hand, loop = _engine(model, max_slots=1), _engine(model, max_slots=1)
+    hand.warmup(), loop.warmup()
+    want = _by_hand(hand, prompt, **gen)
+    wasted = int(ending == "eos_on_a_boundary")
+    loop.start()
+    try:
+        got = _served(loop, prompt, **gen)
+        np.testing.assert_array_equal(got, want)
+        assert want.size == (40 if wasted else 39)
+        assert hand._snapshots.free_entries == 3        # recorded at 32
+        assert loop.metrics.get("columns_wasted") == wasted
+        assert loop._snapshots.free_entries == 3 + wasted
+        assert loop.prefix_cache_size == hand.prefix_cache_size == 4
+        turn = np.concatenate([want, more])
+        want_b = _by_hand(hand, turn, max_new_tokens=5)
+        got_b = _served(loop, turn, max_new_tokens=5)
+    finally:
+        loop.shutdown(drain=True, timeout=60)
+    np.testing.assert_array_equal(got_b, want_b)
+    assert hand.metrics.get("state_snapshot_hits") == 1
+    assert loop.metrics.get("state_snapshot_hits") == 1 - wasted
+    assert loop.metrics.get("prefix_hit_tokens") == 32 * (1 - wasted)
+    if not wasted:
+        for mine, theirs in zip(loop._arrays(loop._state),
+                                hand._arrays(hand._state)):
+            np.testing.assert_array_equal(np.asarray(mine[0]),
+                                          np.asarray(theirs[0]))
+    assert loop.metrics.get("steps_launched_ahead") > 0
+    assert loop.compile_counts == {"decode": 1, "cow": 1, "snapshot": 1}
+    assert loop._decode._cache_size() == 1
+
+
 # -- the prefix cache's snapshots ---------------------------------------------
 
 

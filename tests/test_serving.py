@@ -404,16 +404,17 @@ def test_slot_engine_compiles_exactly_once_total(server):
 # token columns and the table's 8), the keys of `extras`, and the keys
 # of the returned `out`
 _BATCH = {"tok", "pos", "nvalid", "tables"}
+_EXTRAS = {"prev_pick"}
 _OUT = {"pick", "logits", "aux"}
 _SEAM = {
-    "plain": ({}, _BATCH, set(), _OUT),
-    "spec_k2_self_draft": ({"spec_len": 2}, _BATCH, set(),
+    "plain": ({}, _BATCH, _EXTRAS, _OUT),
+    "spec_k2_self_draft": ({"spec_len": 2}, _BATCH, _EXTRAS,
                            _OUT | {"verify"}),
     "adapters": ({"max_adapters": 3, "lora_rank": 2}, _BATCH | {"aid"},
-                 {"lora_a", "lora_b"}, _OUT),
+                 _EXTRAS | {"lora_a", "lora_b"}, _OUT),
     "int8_w8a8": ({"quantize": True, "w8a8": True}, _BATCH,
-                  {"act_scale"}, _OUT | {"amax"}),
-    "mesh_dp1_mp2": ({"mesh": "dp1.mp2"}, _BATCH, set(), _OUT),
+                  _EXTRAS | {"act_scale"}, _OUT | {"amax"}),
+    "mesh_dp1_mp2": ({"mesh": "dp1.mp2"}, _BATCH, _EXTRAS, _OUT),
 }
 
 
@@ -956,13 +957,8 @@ def test_export_racing_a_running_loop_returns_whole_blocks(gpt):
         srv.shutdown(drain=True)
 
 
-class _Unreadable:
-    def __array__(self, *a, **kw):
-        raise RuntimeError("device fell over")
-
-
 @pytest.mark.parametrize("when", ["before", "after", "readback"])
-def test_step_that_raises_leaves_a_serving_engine(gpt, when):
+def test_step_that_raises_leaves_a_serving_engine(gpt, when, monkeypatch):
     """A step that raises once its inputs were donated (or whose picks
     cannot be read) leaves no pool: the engine rebuilds empty ones,
     drops the prefix index, fails the live slots and serves the next
@@ -985,7 +981,16 @@ def test_step_that_raises_leaves_a_serving_engine(gpt, when):
             out = real(*args)                 # dispatched: inputs gone
             if when == "after":
                 raise RuntimeError("device fell over")
-            return {**out[0], "pick": _Unreadable()}, out[1]
+            # learnt of only when its picks are read, by which time the
+            # loop has launched the next step on its outputs
+            import jax
+
+            def unreadable(tree, get=jax.device_get):
+                monkeypatch.setattr(jax, "device_get", get)
+                raise RuntimeError("device fell over")
+
+            monkeypatch.setattr(jax, "device_get", unreadable)
+            return out
 
         eng._decode = broken
         fut = srv.submit(_prompt(91, 4), max_new_tokens=8, timeout=120)
@@ -1085,6 +1090,199 @@ def test_non_drain_shutdown_sheds_and_evicts(gpt):
     for f in futs:
         with pytest.raises(ServingError):   # evicted or shed, never hung
             f.result(5)
+
+
+# ---------------------------------------------------------------------------
+# the loop keeps one step in flight
+# ---------------------------------------------------------------------------
+
+
+def _by_hand(eng, waves):
+    """`waves` of ``(prompt, gen)`` through an engine with no thread,
+    each wave submitted when the one before it has been answered, one
+    whole `_step()` at a time."""
+    out = []
+    for wave in waves:
+        futs = [eng.submit(p, timeout=None, **gen) for p, gen in wave]
+        eng._admit()
+        while eng.active or eng.queue.depth:
+            eng._step()
+            eng._admit()
+        out += [f.result(5) for f in futs]
+    return out
+
+
+def _by_loop(eng, waves, gap_s=0.003):
+    """The same through the engine's own loop, which launches step n+1
+    before it reads step n; the requests of a wave arrive `gap_s`
+    apart, so they join a batch that is already decoding."""
+    out = []
+    eng.start()
+    try:
+        for wave in waves:
+            futs = []
+            for p, gen in wave:
+                futs.append(eng.submit(p, timeout=None, **gen))
+                time.sleep(gap_s)
+            out += [f.result(60) for f in futs]
+    finally:
+        eng.shutdown(drain=True, timeout=60)
+    assert eng._flight is None
+    return out
+
+
+@pytest.mark.parametrize("chunk", [4, 8])
+def test_the_loop_and_steps_by_hand_give_the_same_tokens(gpt, chunk):
+    """Greedy requests with staggered arrivals, a prefix hit and a
+    copy-on-write: the loop, which keeps a step in flight and feeds a
+    row's token back on the device, answers with the very ids of an
+    engine stepped by hand, which are the no-cache reference's. It
+    compiled nothing new for it (one trace, one executable, whether
+    `prev_pick` is a step's result or the first step's zeros), wasted
+    no column (every request ends by `max_new_tokens`, known at
+    launch) and left nearly every step unwaited-for."""
+    base = _prompt(400, 20)
+    first = [(base, {"max_new_tokens": 9})]
+    then = [(np.concatenate([base[:18], _prompt(401, 7)]),     # CoW at 16+2
+             {"max_new_tokens": 12}),
+            (np.concatenate([base, _prompt(402, 3)]),          # two blocks hit
+             {"max_new_tokens": 5}),
+            (_prompt(403, 31), {"max_new_tokens": 14}),
+            (_prompt(404, 2), {"max_new_tokens": 1}),
+            (_prompt(405, 6), {"max_new_tokens": 2})]
+    kw = dict(max_slots=3, block_size=8, prefill_chunk=chunk)
+    hand = serving.SlotEngine(gpt, **kw)
+    loop = serving.SlotEngine(gpt, strict_shapes=True, **kw)
+    assert loop.warmup() == {"decode": 1, "cow": 1}
+    want = _by_hand(hand, [first, then])
+    got = _by_loop(loop, [first, then])
+    for (p, gen), a, b in zip(first + then, want, got):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(
+            a, _ref_greedy(gpt, p, gen["max_new_tokens"]))
+    assert loop.compile_counts == {"decode": 1, "cow": 1}
+    assert loop._decode._cache_size() == 1
+    for eng in (hand, loop):
+        m = eng.metrics
+        assert m.get("cow_splits") >= 1 and m.get("prefix_hit_blocks") >= 2
+        assert m.get("pool_inplace_steps") == m.get("steps")
+        assert m.get("tokens_out") == m.get("device_picks") == 43
+        assert m.get("columns_wasted") == m.get("step_errors") == 0
+    assert hand.metrics.get("steps_launched_ahead") == 0
+    steps = loop.metrics.get("steps")
+    assert 0.8 * steps <= loop.metrics.get("steps_launched_ahead") < steps
+
+
+def test_eos_is_learnt_a_step_late_and_wastes_one_column(gpt):
+    """An `eos_token_id` that fires mid-answer, under the loop: the row
+    has one more column in flight by the time the host sees the token.
+    The answer ends at EOS all the same, `columns_wasted` counts that
+    column, and the request that takes the slot while the stray column
+    is still in flight is untouched by it: the stray pick lands on no
+    one, the stray row is written where no one reads."""
+    p, q = _prompt(411, 11), _prompt(410, 13)
+    plain = _ref_greedy(gpt, p, 12)
+    at = next(k for k in range(3, 12)
+              if plain[11 + k] not in plain[11:11 + k])
+    eos = int(plain[11 + at])
+    eng = serving.SlotEngine(gpt, max_slots=1, block_size=8,
+                             prefill_chunk=8)
+    eng.warmup()
+    first = eng.submit(p, max_new_tokens=12, eos_token_id=eos, timeout=None)
+    second = eng.submit(q, max_new_tokens=10, timeout=None)
+    eng.start()
+    try:
+        got, after = first.result(60), second.result(60)
+    finally:
+        eng.shutdown(drain=True, timeout=60)
+    np.testing.assert_array_equal(got, plain[:11 + at + 1])
+    assert got[-1] == eos and len(first.token_times) == at + 1
+    np.testing.assert_array_equal(after, _ref_greedy(gpt, q, 10))
+    m = eng.metrics
+    assert m.get("columns_wasted") == 1
+    assert m.get("tokens_out") == at + 1 + 10 == m.get("device_picks")
+    # the wasted column was computed, and said so; what the index was
+    # given ends before it
+    assert m.get("computed_tokens") == 11 + at + 1 + 13 + 9
+    assert eng.free_blocks + eng.prefix_cache_size == eng._alloc.usable
+
+
+def test_a_sampling_request_keeps_the_loop_in_order_while_it_lives(gpt):
+    """A `do_sample` request among greedy ones: its token is drawn on
+    the host from the row the step left, so while it decodes the loop
+    lands each step before it launches the next (`steps_launched_ahead`
+    stays where it was), and its draws are those of the engine stepped
+    by hand for the same seed (which the tests above tie to the
+    parent's host path). When it has gone the loop runs ahead again."""
+    gen = {"max_new_tokens": 14, "do_sample": True, "seed": 5,
+           "temperature": 0.8, "top_k": 20}
+    wave = [(_prompt(420, 9), gen),
+            (_prompt(421, 13), {"max_new_tokens": 8}),
+            (_prompt(422, 4), {"max_new_tokens": 10})]
+    kw = dict(max_slots=3, block_size=8, prefill_chunk=8)
+    want = _by_hand(serving.SlotEngine(gpt, **kw), [wave])
+    eng = serving.SlotEngine(gpt, **kw)
+    eng.warmup()
+    futs = [eng.submit(p, timeout=None, **g) for p, g in wave]
+    eng.start()
+    try:
+        got = [f.result(60) for f in futs]
+        m = eng.metrics
+        steps, ahead = m.get("steps"), m.get("steps_launched_ahead")
+        # all three joined the first step; the sampling request's two
+        # prefill steps are the only ones no draw stood behind
+        assert steps >= 2 + 13 and ahead == 1
+        assert m.get("logit_rows_fetched") == 14
+        tail = eng.submit(_prompt(423, 5), max_new_tokens=12, timeout=None)
+        np.testing.assert_array_equal(
+            tail.result(60), _ref_greedy(gpt, _prompt(423, 5), 12))
+        assert m.get("steps_launched_ahead") - ahead >= 10
+    finally:
+        eng.shutdown(drain=True, timeout=60)
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
+    assert eng.metrics.get("columns_wasted") == 0
+
+
+@pytest.mark.parametrize("what", ["cancel", "deadline", "fault"])
+def test_a_request_that_goes_with_a_step_in_flight(gpt, what):
+    """A cancel and a deadline are seen by the sweep that opens a
+    launch, a fault at ``serving.step`` fires there: each time the
+    row's last column is still in flight. The request fails with its
+    own error, the column is counted wasted, its pick lands on no one,
+    and the engine serves the next request from the same slot with the
+    reference's tokens."""
+    eng = serving.SlotEngine(gpt, max_slots=1, block_size=8,
+                             prefill_chunk=8)
+    eng.warmup()
+    eng.start()
+    error = {"cancel": RequestCancelled, "deadline": DeadlineExceededError,
+             "fault": faults.FaultError}[what]
+    spec = "serving.step@6:raise" if what == "fault" \
+        else "serving.step@*:delay:0.02"
+    try:
+        with faults.inject(spec):
+            fut = eng.submit(_prompt(430, 5), max_new_tokens=50,
+                             timeout=0.4 if what == "deadline" else None)
+            if what == "cancel":
+                until = time.monotonic() + 30
+                while len(fut.token_times) < 3:
+                    assert time.monotonic() < until
+                    time.sleep(0.002)
+                fut.cancel()
+            with pytest.raises(error):
+                fut.result(60)
+        nxt = _prompt(431, 7)
+        np.testing.assert_array_equal(
+            eng.submit(nxt, max_new_tokens=6, timeout=None).result(60),
+            _ref_greedy(gpt, nxt, 6))
+    finally:
+        eng.shutdown(drain=True, timeout=60)
+    m = eng.metrics
+    assert m.get("columns_wasted") == 1 and m.get("failed") == 1
+    assert m.get("step_errors") == 0 and m.get("pool_rebuilds") == 0
+    assert m.get("pool_inplace_steps") == m.get("steps")
+    assert eng.free_blocks + eng.prefix_cache_size == eng._alloc.usable
 
 
 # ---------------------------------------------------------------------------

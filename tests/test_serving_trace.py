@@ -290,6 +290,78 @@ def test_device_step_covers_the_decode_series(gpt):
     assert total["readback"] > 0
 
 
+def test_a_step_by_hand_is_one_span_round_its_dispatch_and_its_readback(
+        gpt):
+    """`_step()` is one whole step: its `serving.step` holds that
+    step's dispatch and that step's read-back, and is the decode
+    series' interval (nothing was in flight before it)."""
+    eng = _engine(gpt)
+    mine, since = threading.get_ident(), time.perf_counter() * 1e6
+    eng.submit(_prompt(40, 6), max_new_tokens=5)
+    _drive(eng)
+    seen = [e for e in profiler.events()
+            if e["tid"] == mine and e["ts"] >= since]
+    steps = [e for e in seen if e["name"] == "serving.step"]
+    assert len(steps) == eng.metrics.get("steps") > 0
+    assert eng.metrics.get("steps_launched_ahead") == 0
+    for st in steps:
+        inside = sorted((e for e in seen if e["depth"] == st["depth"] + 1
+                         and _contains(st, e)), key=lambda e: e["ts"])
+        assert [e["name"] for e in inside][:1] == ["step.dispatch"]
+        assert [e["name"] for e in inside][-1:] == ["step.readback"]
+    # one request: a step is a prefill sample or a decode sample, and
+    # each sample opens just before its span and closes just behind it
+    sampled = 1e6 * sum(eng.metrics.latency_since({}, "decode")
+                        + eng.metrics.latency_since({}, "prefill"))
+    assert 0.8 * sampled <= sum(e["dur"] for e in steps) <= sampled
+
+
+def test_a_decode_sample_is_the_period_with_a_step_in_flight(gpt):
+    """With a step in flight a step's sample runs from the landing of
+    the step before it to its own landing: the samples tile the time
+    the loop worked, where dispatch -> read-back of each would count
+    every moment twice. The loop's two counters are exported."""
+    eng = _engine(gpt)
+    real = eng._decode
+
+    def slow(*args):
+        out = real(*args)
+        time.sleep(0.01)          # a device step of 10 ms
+        return out
+
+    eng._decode = slow
+    eng.start()
+    try:
+        t0 = time.monotonic()
+        eng.submit(_prompt(41, 4), max_new_tokens=40,
+                   eos_token_id=None).result(60)
+        wall = time.monotonic() - t0
+        decode = eng.metrics.latency_since({}, "decode")
+        steps = eng.metrics.get("steps")
+        ahead = eng.metrics.get("steps_launched_ahead")
+        assert eng.metrics.get("columns_wasted") == 0
+        # one cancelled mid-answer: its last column was in flight
+        gone = eng.submit(_prompt(42, 4), max_new_tokens=50)
+        until = time.monotonic() + 30
+        while len(gone.token_times) < 3:
+            assert time.monotonic() < until
+            time.sleep(0.002)
+        gone.cancel()
+        with pytest.raises(serving.RequestCancelled):
+            gone.result(60)
+    finally:
+        eng.shutdown(drain=True, timeout=30)
+    assert len(decode) >= 39
+    assert 0.8 * wall < sum(decode) <= wall
+    assert 0.9 * steps <= ahead < steps
+    counters = eng.metrics.snapshot()["counters"]
+    assert counters["steps_launched_ahead"] > ahead
+    assert counters["columns_wasted"] == 1
+    text = observe.prometheus_text(serving=eng.metrics)
+    for name in ("steps_launched_ahead", "columns_wasted"):
+        assert f"paddle_serving_{name}_total {counters[name]}" in text
+
+
 def _contains(outer, inner):
     return outer["tid"] == inner["tid"] and outer["ts"] <= inner["ts"] \
         and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
@@ -297,11 +369,13 @@ def _contains(outer, inner):
 
 def test_an_idle_server_waits_under_loop_idle_a_busy_one_under_serving_loop(
         gpt):
+    since = time.perf_counter() * 1e6   # an earlier thread's id may be mine
     eng = _engine(gpt).start()
     mine = eng._thread.ident
 
     def events():
-        return [e for e in profiler.events() if e["tid"] == mine]
+        return [e for e in profiler.events()
+                if e["tid"] == mine and e["ts"] >= since]
 
     try:
         time.sleep(0.1)
@@ -322,10 +396,23 @@ def test_an_idle_server_waits_under_loop_idle_a_busy_one_under_serving_loop(
         inside = [e for e in seen if e["name"] == name]
         assert inside and all(any(_contains(lp, e) for lp in loops)
                               for e in inside), name
+    # the loop keeps a step in flight: an iteration's `serving.step` is
+    # round the dispatch of one step and the read-back of the step
+    # before it (the first iteration has nothing to read, the last ones
+    # nothing to dispatch), each step dispatched and read exactly once
+    made = eng.metrics.get("steps")
     for name in ("step.dispatch", "step.readback"):
         inside = [e for e in seen if e["name"] == name]
-        assert len(inside) == len(steps)
-        assert all(any(_contains(st, e) for st in steps) for e in inside)
+        assert len(inside) == made
+        assert all(sum(_contains(st, e) for st in steps) == 1
+                   for e in inside), name
+    assert made <= len(steps) <= made + 1
+    ahead = [st for st in steps
+             if [e["name"] for e in sorted(
+                 (e for e in seen if e["depth"] == st["depth"] + 1
+                  and _contains(st, e)), key=lambda e: e["ts"])]
+             == ["step.dispatch", "step.readback"]]
+    assert len(ahead) == eng.metrics.get("steps_launched_ahead") > 0
     # the wait after the answer is idle again, and no idle wait lies
     # inside an iteration
     idle = [e for e in seen if e["name"] == "loop.idle"]
@@ -453,16 +540,20 @@ def test_spans_and_phases_the_benchmark_reads_keep_their_names(served):
     events = [e for e in profiler.events() if e["tid"] == mine]
     stepping = [e for e in events if e["name"] == "serving.step"]
     # every step of this server was made by its one thread: the
-    # benchmark finds the driving thread by this span
-    assert len(stepping) == steps
+    # benchmark finds the driving thread by this span, which is round
+    # each step's dispatch and each step's read-back, once each
+    for half in ("step.dispatch", "step.readback"):
+        halves = [e for e in events if e["name"] == half]
+        assert len(halves) == steps
+        assert all(sum(_contains(st, e) for st in stepping) == 1
+                   for e in halves), half
+    # one an iteration that launched or landed a step: a burst of n
+    # steps takes n + 1 iterations with a step in flight
+    assert steps <= len(stepping) <= steps + len(srv.metrics.latency_since(
+        {}, "queue"))
     assert sum(1 for e in events if e["name"] == "step.sample") >= steps
     assert observe.timeline.total("sample") > 0
     assert srv.engine.compile_counts == {"decode": 1, "cow": 1}
-    # serving.step is dispatch + read-back: the decode series' interval
-    longest = max(srv.metrics.latency_since({}, "decode")
-                  + srv.metrics.latency_since({}, "prefill"))
-    assert max(e["dur"] for e in stepping) == pytest.approx(
-        longest * 1e6, rel=0.05)
 
 
 # ---------------------------------------------------------------------------

@@ -306,7 +306,11 @@ def test_cell_step_returns_the_pick_beside_logits_that_are_not_copied(
     vec = np.zeros((slots,), np.int32)
     batch, extras = eng._stage(
         np.zeros((slots, eng.prefill_chunk), np.int32), vec, vec)
-    assert not extras and batch.dtype == np.int32
+    # the previous step's pick, which a row that fed its token back on
+    # the device takes its column 0 from: 4 bytes a slot, one select
+    assert {k: (v.shape, v.dtype) for k, v in extras.items()} \
+        == {"prev_pick": ((slots,), jnp.int32)}
+    assert batch.dtype == np.int32
     assert batch.shape == (slots,
                            eng.prefill_chunk + 2 + eng.blocks_per_slot)
     shapes = eng._layout.pool_shapes(num_blocks, eng.block_size)
@@ -314,12 +318,15 @@ def test_cell_step_returns_the_pick_beside_logits_that_are_not_copied(
                                         sharding=one_chip) for s in shapes)
              for _ in range(eng._layout.layers)]
     values = jax.tree_util.tree_map(spec, eng._values)
-    compiled = eng._decode.lower(values, spec(batch), pools, {}).compile()
+    compiled = eng._decode.lower(
+        values, spec(batch), pools,
+        jax.tree_util.tree_map(spec, extras)).compile()
 
     memory = compiled.memory_analysis()
     pool_bytes = eng._layout.layers * sum(
         int(np.prod(s)) for s in shapes) * eng._pool_dtype.itemsize
-    held = sum(v.nbytes for v in eng._values.values()) + batch.nbytes
+    held = sum(v.nbytes for v in eng._values.values()) + batch.nbytes \
+        + extras["prev_pick"].nbytes
     assert memory.alias_size_in_bytes == pool_bytes
     assert abs(memory.argument_size_in_bytes - held - pool_bytes) < 1 << 16
     assert memory.temp_size_in_bytes < temp_limit
@@ -381,7 +388,8 @@ def test_hybrid_cell_step_updates_pools_and_state_arrays_in_place(
         == [(rows, 30, 96, 192), (rows, 3, 11520)]
     values = jax.tree_util.tree_map(spec, eng._values)
     compiled = eng._decode.lower(
-        values, spec(batch), {"blocks": pools, "state": state}, {}).compile()
+        values, spec(batch), {"blocks": pools, "state": state},
+        jax.tree_util.tree_map(spec, extras)).compile()
 
     memory = compiled.memory_analysis()
     pool_bytes = eng._layout.layers * sum(
