@@ -15,3 +15,7 @@ from .hybrid_linear import (  # noqa: F401
     GatedDeltaNet, HybridDecoderLayer, HybridFullAttention,
     HybridLinearConfig, HybridLinearForCausalLM, HybridLinearModel,
 )
+from .window_moe import (  # noqa: F401
+    WindowAttention, WindowMoEConfig, WindowMoEDecoderLayer,
+    WindowMoEForCausalLM, WindowMoEModel,
+)

@@ -85,56 +85,87 @@ def key_tiling(blocks_per_slot, block_size):
     return per_tile, -(-blocks_per_slot // per_tile)
 
 
-@functools.partial(jax.jit, static_argnums=(5,))
-def _attend_tiles(q, k_pool, v_pool, tables, t_idx, per_tile):
-    """Online-softmax attention of float32 queries ``[b, nh, s, hd]``,
+@functools.partial(jax.jit, static_argnames=("per_tile", "window"))
+def _attend_tiles(q, k_pool, v_pool, tables, t_idx, per_tile, base=None,
+                  window=None):
+    """Online-softmax attention of queries ``[b, nh, s, hd]``,
     column `c` of row `b` at position ``t_idx[b, c]``, over the pools
-    ``[num_blocks, block_size, nh, hd]`` read through `tables` in
-    tiles of `per_tile` entries: ``(out [b, nh, s, hd] float32, turns
-    run)``. Jitted on its own so that a model's layers share one trace
-    of the loop: inside a step's trace it is a call of that trace, not
-    a program of its own."""
+    ``[num_blocks, block_size, nkv, hd]`` (or ``[num_blocks,
+    block_size, nkv * hd]``, the heads side by side in a row) read
+    through `tables` in tiles of `per_tile` entries: ``(out [b, nh, s,
+    hd] float32, turns run)``. Jitted on its own so that a model's
+    layers share one trace of the loop: inside a step's trace it is a
+    call of that trace, not a program of its own.
+
+    The products run in the queries' dtype and accumulate in float32
+    (float32 queries widen the cached rows to float32, as GPT's do).
+    **Grouped heads**: with `nkv` pool heads for ``nh = g * nkv`` query
+    heads, the `g` query heads of a KV head fold into the query axis
+    (``[b, nkv, g * s, hd]``), so a K/V tile is gathered once for all
+    of them. **A window**: `window` keys are admitted counting the
+    query's own (``t - window < key <= t``), `tables` is then the
+    slot's SHORT table whose entry 0 holds position ``base[b]``, and
+    the loop runs over the whole of it, a number of turns that no
+    row's depth changes; without one it runs from tile 0 to the
+    batch's longest row."""
     import jax.numpy as jnp
     from jax import lax
 
     b, nh, s_new, hd = q.shape
     bs, mb = k_pool.shape[1], tables.shape[1]
+    nkv = math.prod(k_pool.shape[2:]) // hd
     tile = per_tile * bs
     n_tiles_max = -(-mb // per_tile)
     # a table that is no whole number of tiles ends in the null block
     tiled = jnp.pad(tables, ((0, 0), (0, n_tiles_max * per_tile - mb)))
     f32 = jnp.float32
     scale = 1.0 / (hd ** 0.5)
+    if nkv != nh:
+        q = q.reshape(b, nkv, (nh // nkv) * s_new, hd)
+        t_idx = jnp.tile(t_idx, (1, nh // nkv))
 
     def body(j, carry):
         m, l, acc = carry
         blocks = lax.dynamic_slice_in_dim(tiled, j * per_tile, per_tile,
                                           axis=1)
-        k_tile = k_pool[blocks].reshape(b, tile, nh, hd)
-        v_tile = v_pool[blocks].reshape(b, tile, nh, hd)
-        sc = jnp.einsum("bhqd,bkhd->bhqk", q, k_tile.astype(f32)) * scale
+        k_tile = k_pool[blocks].reshape(b, tile, nkv, hd)
+        v_tile = v_pool[blocks].reshape(b, tile, nkv, hd)
+        sc = jnp.einsum("bhqd,bkhd->bhqk", q, k_tile.astype(q.dtype),
+                        preferred_element_type=f32) * scale
         k_pos = j * tile + jnp.arange(tile)
-        mask = k_pos[None, None, :] <= t_idx[:, :, None]
+        if window is None:
+            mask = k_pos[None, None, :] <= t_idx[:, :, None]
+        else:
+            k_pos = base[:, None, None] + k_pos[None, None, :]
+            mask = (k_pos <= t_idx[:, :, None]) \
+                & (k_pos > t_idx[:, :, None] - window)
         sc = jnp.where(mask[:, None], sc, -1e30)
         m_new = jnp.maximum(m, sc.max(axis=-1))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(sc - m_new[..., None])
         l = l * alpha + p.sum(axis=-1)
         acc = acc * alpha[..., None] + jnp.einsum(
-            "bhqk,bkhd->bhqd", p, v_tile.astype(f32))
+            "bhqk,bkhd->bhqd", p.astype(q.dtype), v_tile.astype(q.dtype),
+            preferred_element_type=f32)
         return m_new, l, acc
 
-    # every column admits key 0, so after the first turn `m` is a real
-    # score and a wholly masked later tile adds exp(-1e30 - m). A column
-    # past the table is padding (its row went to the null block, its
-    # output is unread) and does not lengthen the loop
-    longest = jnp.max(jnp.where(t_idx < mb * bs, t_idx, 0))
-    n_tiles = (longest // tile + 1).astype(jnp.int32)
-    init = (jnp.full((b, nh, s_new), -1e30, f32),
-            jnp.zeros((b, nh, s_new), f32),
-            jnp.zeros((b, nh, s_new, hd), f32))
+    if window is None:
+        # every column admits key 0, so after the first turn `m` is a
+        # real score and a wholly masked later tile adds exp(-1e30 - m).
+        # A column past the table is padding (its row went to the null
+        # block, its output is unread) and does not lengthen the loop
+        longest = jnp.max(jnp.where(t_idx < mb * bs, t_idx, 0))
+        n_tiles = (longest // tile + 1).astype(jnp.int32)
+    else:
+        # the short table is read whole: a tile with no admitted key
+        # before a row's first real score leaves counts that the first
+        # real score's `alpha` of exp(-1e30 - m) wipes
+        n_tiles = n_tiles_max
+    init = (jnp.full(q.shape[:3], -1e30, f32),
+            jnp.zeros(q.shape[:3], f32),
+            jnp.zeros(q.shape, f32))
     _, l, acc = lax.fori_loop(0, n_tiles, body, init)
-    return acc / l[..., None], n_tiles
+    return (acc / l[..., None]).reshape(b, nh, s_new, hd), n_tiles
 
 
 class GPTAttention(nn.Layer):

@@ -317,7 +317,15 @@ class HeldExperts(nn.Layer):
     mask of the rows that are real (a serving step's padding columns
     are not) and returns ``(y, rows)``: `y` what this share adds, `rows`
     ``[num_experts]`` int32 the rows of the grouped product each held
-    expert computed."""
+    expert computed.
+
+    The scoring rule is the model's (`config.router_scoring`, absent =
+    ``"sigmoid"``): ``"sigmoid"`` scores each expert alone and selects
+    by score + bias (the latent family's); ``"softmax"`` scores over
+    all routed experts and selects the largest, no bias (a parameter
+    the layer then does not have). Either way the picked scores are
+    normalised to sum 1 and scaled by `routed_scaling_factor`, and the
+    grouped product below is the one piece of code under both."""
 
     def __init__(self, config: LatentMoEConfig):
         super().__init__()
@@ -327,13 +335,18 @@ class HeldExperts(nn.Layer):
         self.first = c.ep_rank * c.num_experts
         self.scaling = c.routed_scaling_factor
         self.inter = c.moe_intermediate_size
+        self.scoring = getattr(c, "router_scoring", "sigmoid")
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"router scoring {self.scoring!r} is neither "
+                             f"'sigmoid' nor 'softmax'")
         init = nn.initializer.Normal(std=c.initializer_range)
         self.router = nn.Linear(c.hidden_size, c.router_experts,
                                 weight_attr=init, bias_attr=False)
-        # the selection bias of auxiliary-loss-free balancing: float32
-        # whatever the weights' dtype, zeros from the seed
-        self.router_bias = self.create_parameter(
-            [c.router_experts], dtype="float32", is_bias=True)
+        if self.scoring == "sigmoid":
+            # the selection bias of auxiliary-loss-free balancing:
+            # float32 whatever the weights' dtype, zeros from the seed
+            self.router_bias = self.create_parameter(
+                [c.router_experts], dtype="float32", is_bias=True)
         self.gate_up = self.create_parameter(
             [self.held, c.hidden_size, 2 * self.inter],
             default_initializer=init)
@@ -355,8 +368,12 @@ class HeldExperts(nn.Layer):
         f32 = jnp.float32
         logits = jnp.matmul(h.astype(f32), _v(self.router.weight).astype(f32),
                             precision=lax.Precision.HIGHEST)
-        s = jax.nn.sigmoid(logits)
-        _, sel = lax.top_k(s + _v(self.router_bias), self.top_k)
+        if self.scoring == "softmax":
+            s = jax.nn.softmax(logits, axis=-1)
+            _, sel = lax.top_k(s, self.top_k)
+        else:
+            s = jax.nn.sigmoid(logits)
+            _, sel = lax.top_k(s + _v(self.router_bias), self.top_k)
         w = jnp.take_along_axis(s, sel, axis=-1)
         w = w / w.sum(axis=-1, keepdims=True) * self.scaling
         return sel.astype(jnp.int32), w

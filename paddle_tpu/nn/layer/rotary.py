@@ -78,7 +78,13 @@ class RotaryEmbedding(Layer):
     """Rotary positions over the last `dim` columns of a head, with
     optional YaRN scaling (`scaling`: a public config's `rope_scaling`
     group, ``{"factor", "original_max_position_embeddings",
-    "beta_fast", "beta_slow", "mscale", "mscale_all_dim"}``).
+    "beta_fast", "beta_slow", "mscale", "mscale_all_dim"}``, the
+    "deepseek_yarn" spelling; or the ``rope_type: "yarn"`` spelling,
+    the same frequencies with an optional `attention_factor` that cos
+    and sin carry, ``0.1 ln(factor) + 1`` when it is not given: what
+    `cos_sin_scale` already is where `mscale_all_dim` is absent. A
+    group of ``rope_type: "default"`` or with no `factor` is plain
+    RoPE).
 
     ``forward(x, positions)``: `x` is ``[..., s, n, dim]`` (or
     ``[..., s, dim]`` with `heads=False`), `positions` ``[..., s]``.
@@ -100,6 +106,9 @@ class RotaryEmbedding(Layer):
         m_all = yarn_mscale(factor, all_dim) if all_dim else 1.0
         self.cos_sin_scale = yarn_mscale(factor, float(s.get("mscale", 1.0))) \
             / m_all if factor > 1.0 else 1.0
+        if s.get("attention_factor") is not None:
+            # the public "yarn" group states the factor outright
+            self.cos_sin_scale = float(s["attention_factor"])
         self.attention_scale = m_all * m_all
 
     def angles(self, positions):

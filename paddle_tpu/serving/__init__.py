@@ -81,8 +81,8 @@ from .kvstore import (  # noqa: F401
 from .metrics import ServingMetrics, percentile  # noqa: F401
 from .migrate import KVMailbox, migrate_prefix  # noqa: F401
 from .paging import (  # noqa: F401
-    NULL_BLOCK, BlockAllocator, CacheLayout, PoolExhausted, PrefixCache,
-    positions_to_rows,
+    NULL_BLOCK, BlockAllocator, BlockGroup, CacheLayout, PoolExhausted,
+    PrefixCache, positions_to_rows,
 )
 from .queueing import (  # noqa: F401
     AdmissionQueue, BrownoutShedError, CapacityExhaustedError,
@@ -110,7 +110,7 @@ from .workload import Arrival, Scenario, replay  # noqa: F401
 __all__ = [
     "AdapterRollout", "AdmissionQueue", "Arrival", "Artifact",
     "ArtifactCatalog", "Autoscaler", "BlockAllocator",
-    "BrownoutShedError", "CacheLayout",
+    "BlockGroup", "BrownoutShedError", "CacheLayout",
     "CapacityExhaustedError", "CircuitBreaker", "DEFAULT_TENANT",
     "DeadlineExceededError",
     "DynamicBatcher", "GPT_PARTITION_RULES", "KVMailbox", "KVSpillStore",

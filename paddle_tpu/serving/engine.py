@@ -45,6 +45,29 @@ allocation and SGLang-style prefix sharing:
   refuse such a layout by name (`_refuse_state_arrays`). A layout
   that declares no state takes none of this: no arrays, no entries,
   no third program.
+- A layout may declare two BLOCK GROUPS (`CacheLayout.groups`): the
+  layers whose blocks are kept for a slot's life, and the layers that
+  read only the last `window` keys. Each group has its own pool size
+  (`num_blocks` a group), `BlockAllocator` and block table a slot; the
+  window group's table is short and MOVES (`paging.WindowTables`):
+  before every launch `_move_window` drops the slot's reference to
+  each block whose last key no query of the step admits any more
+  (first indexed in the prefix cache, so that a later request with
+  the same prefix finds it for as long as it lives; `reclaim_window`
+  takes the coldest when the group runs short), allocates the blocks
+  the step writes ahead, and rewrites the row. Both tables and the
+  window row's base position ride in the one `batch` array; the model
+  is handed ``{group name: (table, base)}`` and its window layers loop
+  over the short table only, whatever any row's depth. Admission
+  matches both groups at once (`PrefixCache.match_window`: a prefix
+  is usable only as deep as the window group still holds the `window`
+  tokens before that depth; what the full group matched deeper is
+  counted in `prefix_tokens_lost_to_window` and computed again), a
+  copy-on-write copies in both groups (one program), and `_evict`
+  indexes the last window's blocks with the full chain. Speculation,
+  KV export / adoption / migration and the spill tier know one kind
+  of block and refuse such a layout by name (`_refuse_block_groups`).
+  A layout of one group takes none of this.
 - The pools (and the state arrays) are DONATED to every program that
   returns them (the step, the CoW copy, the row copy, the draft
   micro-step) and updated in place: the arrays
@@ -220,7 +243,7 @@ from . import kvstore
 from .metrics import ServingMetrics
 from .paging import (
     BLOCK_ROW_ORDER, NULL_BLOCK, BlockAllocator, PoolExhausted, PrefixCache,
-    SnapshotEntries,
+    SnapshotEntries, WindowTables,
 )
 from .queueing import (
     AdmissionQueue, CapacityExhaustedError, DeadlineExceededError, Request,
@@ -271,6 +294,13 @@ class _Slot:
         # its state was last copied there at (0 = never)
         self.entry = None
         self.snap_depth = 0
+        # a layout with a windowed group: the window-group blocks this
+        # request holds, ``{block index: block id}``, the most it may
+        # hold at once (reserved at admission), and its own chain of
+        # prefix keys, for the blocks it indexes as it goes
+        self.held = None
+        self.demand = 0
+        self.chain = None
 
 
 class _LogitRow:
@@ -313,6 +343,7 @@ class _Flight:
     prefill_tokens: int
     computed: int
     context: int
+    window_context: int = 0
     rows: list = dataclasses.field(default_factory=list)
     swept: bool = False
 
@@ -414,13 +445,28 @@ class SlotEngine:
                                model.config.max_seq_len)
         self.block_size = block_size or flag("FLAGS_serving_kv_block_size")
         self.blocks_per_slot = -(-self.max_seq_len // self.block_size)
-        if num_blocks is None:
-            num_blocks = flag("FLAGS_serving_kv_blocks")
-        if not num_blocks:   # auto: dense-equivalent worst case + null
-            num_blocks = self.max_slots * self.blocks_per_slot + 1
-        if num_blocks < 2:
-            raise ValueError(f"num_blocks must be >= 2, got {num_blocks}")
-        self.num_blocks = num_blocks
+        # the model says what a block holds; the engine carries it
+        self._layout = model.cache_layout()
+        # `num_blocks` a group: one number sizes every group alike, a
+        # dict names each group's own (0 / absent = auto)
+        sized = num_blocks if isinstance(num_blocks, dict) \
+            else {g.name: num_blocks for g in self._layout.groups}
+        unknown = set(sized) - {g.name for g in self._layout.groups}
+        if unknown:
+            raise ValueError(
+                f"num_blocks names {sorted(unknown)}; the model's cache "
+                f"layout has {[g.name for g in self._layout.groups]}")
+        self._num_blocks = {}
+        for g in self._layout.groups:
+            n = sized.get(g.name)
+            if n is None:
+                n = flag("FLAGS_serving_kv_blocks")
+            if not n:   # auto: dense-equivalent worst case + null
+                n = self.max_slots * self.blocks_per_slot + 1
+            if n < 2:
+                raise ValueError(f"num_blocks must be >= 2, got {n}")
+            self._num_blocks[g.name] = int(n)
+        self.num_blocks = self._num_blocks[self._layout.groups[0].name]
         self.prefill_chunk = min(
             prefill_chunk or flag("FLAGS_serving_prefill_chunk"),
             self.max_seq_len)
@@ -512,8 +558,6 @@ class SlotEngine:
         else:
             self._lora_a = None
             self._lora_b = None
-        # the model says what a block holds; the engine carries it
-        self._layout = model.cache_layout()
         self._pool_dtype = cache_dtype or jnp.float32
         # held from a donating dispatch to the rebind of its outputs
         # (the arrays in between are deleted), and by a foreign
@@ -553,6 +597,10 @@ class SlotEngine:
         itemsize = jnp.dtype(self._pool_dtype).itemsize
         self.metrics.set_gauge("kv_bytes_per_token",
                                self._layout.bytes_per_token(itemsize))
+        if len(self._layout.groups) > 1:
+            for g in self._layout.groups:
+                self.metrics.set_gauge(f"kv_bytes_per_token_{g.name}",
+                                       g.bytes_per_token(itemsize))
         self.metrics.set_gauge("weight_bytes", sum(
             int(getattr(v, "nbytes", 0)) for v in self._values.values()))
         if self._layout.state:
@@ -564,10 +612,21 @@ class SlotEngine:
                                     dict)().items():
             self.metrics.set_gauge(gauge, value)
         self._alloc = BlockAllocator(self.num_blocks)
+        # the windowed group's allocator and moving tables (None for a
+        # layout of one group, which takes none of that code)
+        self._window = None
+        if len(self._layout.groups) > 1:
+            wgroup = self._layout.groups[1]
+            self._window = WindowTables(
+                BlockAllocator(self._num_blocks[wgroup.name]),
+                wgroup.window, self.block_size, self.prefill_chunk,
+                self.max_slots)
+            self.metrics.set_gauge("window_tokens", wgroup.window)
         if prefix_cache is None:
             prefix_cache = flag("FLAGS_serving_prefix_cache")
         self._cache = PrefixCache(self._alloc, self.block_size,
-                                  snapshots=self._snapshots) \
+                                  snapshots=self._snapshots,
+                                  window=self._window) \
             if prefix_cache else None
         if self._cache is not None and self._layout.state:
             self._cache.snapshot_evicted_hook = \
@@ -580,6 +639,7 @@ class SlotEngine:
             if self._cache is not None else None
         if self.spill_store is not None:
             self._refuse_state_arrays("the KV spill tier")
+            self._refuse_block_groups("the KV spill tier")
             if self._layout.row_order != BLOCK_ROW_ORDER:
                 # a spill record is K and V rows of [block_size, nh,
                 # hd]: any other block is refused, never written as one
@@ -675,6 +735,12 @@ class SlotEngine:
         self._batch_cols = {"tok": slice(0, chunk), "pos": chunk,
                             "nvalid": chunk + 1,
                             "tables": slice(chunk + 2, end)}
+        if self._window is not None:
+            # the window group's short table and its base position
+            self._batch_cols["wtables"] = slice(end,
+                                                end + self._window.entries)
+            end += self._window.entries + 1
+            self._batch_cols["wbase"] = end - 1
         if self.max_adapters:
             self._batch_cols["aid"] = end
         self._batch_width = end + bool(self.max_adapters)
@@ -720,6 +786,12 @@ class SlotEngine:
                     for name, at in self._batch_cols.items()}
             tok, pos, nvalid = cols["tok"], cols["pos"], cols["nvalid"]
             tables, aid = cols["tables"], cols.get("aid")
+            if "wtables" in cols:
+                # two block groups: each its table and the position of
+                # the table's first entry (None = position 0)
+                full, window = self._layout.groups
+                tables = {full.name: (tables, None),
+                          window.name: (cols["wtables"], cols["wbase"])}
             # the token a row fed back while the host had not seen it
             tok = jnp.where(tok == _FROM_PICK,
                             extras["prev_pick"][:, None], tok)
@@ -729,7 +801,7 @@ class SlotEngine:
             self._count_compile("decode")
             observe.record_compile(
                 "serving.step",
-                signature=observe.signature_of(tok, pos, tables))
+                signature=observe.signature_of(tok, pos, cols["tables"]))
             # int8-frozen weights dequantize IN-trace (one canonical
             # formula; XLA fuses it into operand reads) — except the
             # head, which _head routes through the epilogue kernel
@@ -807,12 +879,21 @@ class SlotEngine:
             self._count_compile("cow")
             observe.record_compile("serving.cow", signature="(block, block)")
 
-            def copy(pool):
+            def copy(pool, src, dst):
                 blk = lax.dynamic_slice_in_dim(pool, src, 1, axis=0)
                 return lax.dynamic_update_slice_in_dim(pool, blk, dst,
                                                        axis=0)
 
-            return jax.tree_util.tree_map(copy, pools)
+            if src.ndim:
+                # two block groups: a block id a group, in the groups'
+                # order (null to null where a group has nothing to copy)
+                groups = self._layout.groups
+                group_at = [groups.index(self._layout.group_of(i))
+                            for i in range(len(pools))]
+                return [tuple(copy(a, src[g], dst[g]) for a in layer)
+                        for layer, g in zip(pools, group_at)]
+            return jax.tree_util.tree_map(
+                lambda pool: copy(pool, src, dst), pools)
 
         def serving_snapshot(state, src, dst):
             """Row `src` of every state array copied over row `dst`:
@@ -910,11 +991,20 @@ class SlotEngine:
 
     # -- the pools ----------------------------------------------------------
 
+    def _pool_shapes(self, layout):
+        """One list of the pools' shapes a layer of the step's list,
+        each as its group sizes it."""
+        by_group = {g.name: g.pool_shapes(
+            self._num_blocks.get(g.name, self.num_blocks), self.block_size)
+            for g in layout.groups}
+        return [by_group[layout.group_of(i).name]
+                for i in range(layout.layers)]
+
     def _pool_shardings(self, layout):
-        """One tuple of the layout's arrays' shardings a layer."""
-        shapes = layout.pool_shapes(self.num_blocks, self.block_size)
-        return [tuple(self._plan.pool_sharding(layout, shape)
-                      for shape in shapes)] * layout.layers
+        """One tuple of the layer's arrays' shardings a layer."""
+        return [tuple(self._plan.pool_sharding(layout.group_of(i), shape)
+                      for shape in shapes)
+                for i, shapes in enumerate(self._pool_shapes(layout))]
 
     def _zero_pools(self, layout, place=True):
         """Fresh zeroed pools, ``[(array, ...), ...]``: one tuple of the
@@ -924,17 +1014,18 @@ class SlotEngine:
         import jax
         import jax.numpy as jnp
 
-        shapes = layout.pool_shapes(self.num_blocks, self.block_size)
-        shardings = self._pool_shardings(layout)[0] \
-            if place and self._plan is not None else [None] * len(shapes)
+        shapes = self._pool_shapes(layout)
+        shardings = self._pool_shardings(layout) \
+            if place and self._plan is not None \
+            else [[None] * len(layer) for layer in shapes]
 
         def pool(shape, sharding):
             zeros = jnp.zeros(shape, self._pool_dtype)
             return zeros if sharding is None \
                 else jax.device_put(zeros, sharding)
 
-        return [tuple(pool(sh, sd) for sh, sd in zip(shapes, shardings))
-                for _ in range(layout.layers)]
+        return [tuple(pool(sh, sd) for sh, sd in zip(layer, placed))
+                for layer, placed in zip(shapes, shardings)]
 
     def _state_shardings(self):
         """One tuple of the layout's state arrays' shardings a
@@ -1001,11 +1092,25 @@ class SlotEngine:
                 f"block without the state snapshot taken at its end is "
                 f"no prefix to resume from")
 
+    def _refuse_block_groups(self, what):
+        """`what` knows one kind of block and one table a slot: a
+        prefix of a layout with a windowed group is its full chain AND
+        the window group's blocks before its end."""
+        if self._window is not None:
+            raise ValueError(
+                f"{what} carries one group of blocks; this model's cache "
+                f"layout has the groups "
+                f"{[(g.name, g.window) for g in self._layout.groups]}, "
+                f"and a prefix without the window group's blocks before "
+                f"its end is no prefix to resume from")
+
     def _pool_bytes(self, layout):
         import jax.numpy as jnp
 
-        return self.num_blocks * self.block_size * layout.bytes_per_token(
-            jnp.dtype(self._pool_dtype).itemsize)
+        itemsize = jnp.dtype(self._pool_dtype).itemsize
+        return sum(self._num_blocks.get(g.name, self.num_blocks)
+                   * self.block_size * g.bytes_per_token(itemsize)
+                   for g in layout.groups)
 
     @staticmethod
     def _arrays(pools):
@@ -1083,6 +1188,9 @@ class SlotEngine:
 
         said = {"tok": tok, "pos": pos, "nvalid": nvalid,
                 "tables": self._bt, "aid": self._aid}
+        if self._window is not None:
+            said["wtables"] = self._window.table
+            said["wbase"] = self._window.base
         batch = np.empty((self.max_slots, self._batch_width), np.int32)
         for name, at in self._batch_cols.items():
             batch[:, at] = said[name]
@@ -1265,6 +1373,14 @@ class SlotEngine:
                 f"{self._alloc.usable} (block_size={self.block_size}); "
                 "retry with a smaller request or grow "
                 "FLAGS_serving_kv_blocks")
+        if self._window is not None:
+            demand = self._window.demand(ids.size + max_new_tokens)
+            if demand > self._window.alloc.usable:
+                self.metrics.inc("rejected_capacity")
+                raise CapacityExhaustedError(
+                    f"request needs {demand} blocks of the window group "
+                    f"at once but its pool holds "
+                    f"{self._window.alloc.usable}")
         return self.queue.submit(Request(
             ids, timeout=timeout, priority=priority,
             max_new_tokens=max_new_tokens, eos_token_id=eos_token_id,
@@ -1275,17 +1391,28 @@ class SlotEngine:
         """Reserve the physical blocks for one admission: reuse every
         prefix-cached block, allocate the rest, copy-on-write when the
         divergence falls inside a cached block. Returns
-        ``(blocks, fill, entry)`` or raises (`PoolExhausted` = wait and
-        retry; anything else = fail the request). All-or-nothing:
-        partial reservations are rolled back.
+        ``(blocks, fill, entry, held)`` or raises (`PoolExhausted` =
+        wait and retry; anything else = fail the request).
+        All-or-nothing: partial reservations are rolled back.
 
         A layout with state arrays cuts the match at the deepest state
         snapshot recorded on the matched chain (`match_snapshot`):
         `entry` is that snapshot's, to restore the slot's state from
         (None = the state starts from zero), blocks that matched deeper
         are computed again (`prefix_tokens_lost_to_state`), and there
-        is no copy-on-write inside a deeper block."""
+        is no copy-on-write inside a deeper block.
+
+        A layout with a windowed group matches both groups at once
+        (`match_window`): the match is cut at the deepest depth the
+        window group can still serve (`prefix_tokens_lost_to_window`
+        counts what the full chain matched deeper), `held` is the
+        window-group blocks the request resumes over (``{block index:
+        block id}``; None for a layout of one group), a copy-on-write
+        copies in both groups, and the request's `demand` of
+        window-group blocks is reserved against the group's pool."""
         shared, n_shared, cow, entry = [], 0, None, None
+        window = self._window
+        held = {} if window is not None else None
         if self._cache is not None:
             if self.spill_store is not None:
                 # session resume: pull spilled records extending the
@@ -1298,6 +1425,11 @@ class SlotEngine:
                 shared, n_shared, entry, matched = \
                     self._cache.match_snapshot(ids, ids.size - 1)
                 self.metrics.inc("prefix_tokens_lost_to_state",
+                                 matched - n_shared)
+            elif window is not None:
+                shared, n_shared, cow, held, matched = \
+                    self._cache.match_window(ids, ids.size - 1)
+                self.metrics.inc("prefix_tokens_lost_to_window",
                                  matched - n_shared)
             else:
                 shared, n_shared, cow = self._cache.match(ids,
@@ -1314,6 +1446,7 @@ class SlotEngine:
             self.prefix_hit_tokens += hit_tokens
         n_new = need_total - len(shared)
         taken, new, pinned_src = [], [], None
+        wtaken, wpinned = [], None
         try:
             # pin every matched block (and the CoW source) BEFORE any
             # reclaim: eviction under pressure must never free a block
@@ -1326,6 +1459,19 @@ class SlotEngine:
             if cow is not None:
                 self._alloc.incref(cow[0])
                 pinned_src = cow[0]
+            if window is not None:
+                for wbid in held.values():
+                    window.alloc.incref(wbid)
+                    wtaken.append(wbid)
+                if cow is not None:
+                    window.alloc.incref(cow[2])
+                    wpinned = cow[2]
+                demand = window.demand(need_total * self.block_size)
+                if window.reserved + demand > window.alloc.usable:
+                    raise PoolExhausted(
+                        f"need {demand} blocks of the window group beside "
+                        f"the {window.reserved} reserved, the pool holds "
+                        f"{window.alloc.usable}")
             if self._alloc.free_blocks < n_new and self._cache is not None:
                 self._cache.reclaim(n_new - self._alloc.free_blocks)
             if self._alloc.free_blocks < n_new:
@@ -1336,10 +1482,18 @@ class SlotEngine:
                 new.append(self._alloc.alloc())
             fill = n_shared
             if cow is not None:
-                src, rows = cow
+                src, rows = cow[:2]
                 faults.fault_point("serving.cow_split")
                 with profiler.RecordEvent("serving.cow", cat="serving"):
-                    self._copy_block(src, new[0])
+                    if window is None:
+                        self._copy_block(src, new[0])
+                    else:
+                        wdst = self._alloc_window_block()
+                        wtaken.append(wdst)
+                        held[len(shared)] = wdst
+                        self._copy_block(np.asarray([src, cow[2]], np.int32),
+                                         np.asarray([new[0], wdst],
+                                                    np.int32))
                 self.metrics.inc("cow_splits")
                 fill += rows
         except Exception:
@@ -1349,19 +1503,77 @@ class SlotEngine:
                 self._alloc.decref(bid)
             if pinned_src is not None:
                 self._alloc.decref(pinned_src)
+            for wbid in wtaken:
+                window.alloc.decref(wbid)
+            if wpinned is not None:
+                window.alloc.decref(wpinned)
             raise
         if pinned_src is not None:
             self._alloc.decref(pinned_src)
-        return taken + new, fill, entry
+        if wpinned is not None:
+            window.alloc.decref(wpinned)
+        return taken + new, fill, entry, held
+
+    def _alloc_window_block(self):
+        """One fresh block of the window group; the index gives up its
+        coldest when the group has none free (admission reserved what a
+        live slot needs, so one is free or reclaimable)."""
+        alloc = self._window.alloc
+        if not alloc.free_blocks and self._cache is not None:
+            self._cache.reclaim_window(1)
+        return alloc.alloc()
+
+    def _resume_block(self, slot):
+        """The first window-group block a request that resumes at the
+        END of this one's prompt would read. Blocks before it lie deep
+        inside the prompt: the index records them as its coldest (they
+        serve only a request that diverges there), the blocks from it
+        on as its most recent (the prompt's end and the sequence's end
+        are where a later request with this prefix resumes)."""
+        return self._window.first_block(slot.prompt_len)
+
+    def _move_window(self, i, slot, n):
+        """Before the launch of a step that computes positions ``[pos,
+        pos + n)`` of slot `i`: the window group's table moves. Blocks
+        whose last key lies before ``pos - window + 1`` are behind every
+        query from now on: they are indexed in the prefix cache (with
+        the full chain up to them, so a later request with this prefix
+        finds them for as long as they live) and the slot's reference
+        is dropped; blocks the step writes ahead are allocated; the
+        slot's row of the table is rewritten from its lowest block."""
+        w, bs = self._window, self.block_size
+        pos = int(self._pos[i])
+        first = w.first_block(pos)
+        behind = sorted(k for k in slot.held if k < first)
+        if behind:
+            if self._cache is not None:
+                upto = (behind[-1] + 1) * bs
+                self._cache.insert(
+                    slot.prompt if upto <= slot.prompt_len else slot.tokens,
+                    slot.blocks, upto,
+                    window={k: slot.held[k] for k in behind},
+                    chain=slot.chain, cold_below=self._resume_block(slot))
+            for k in behind:
+                w.alloc.decref(slot.held.pop(k))
+            self.metrics.inc("window_blocks_freed", len(behind))
+        ahead = [k for k in range(first, (pos + n - 1) // bs + 1)
+                 if k not in slot.held]
+        for k in ahead:
+            slot.held[k] = self._alloc_window_block()
+        if behind or ahead:
+            w.sync(i, slot.held)
 
     def _copy_block(self, src, dst):
         """The compiled copy-on-write copy, every layer's pools at
         once; they are donated to it like to the step."""
         import jax.numpy as jnp
 
+        if self._window is not None and np.ndim(src) == 0:
+            # a layout of two groups names a block a group
+            src, dst = np.asarray([src, src]), np.asarray([dst, dst])
         with self._pool_lock:
-            self._pools = self._cow(self._pools, jnp.int32(src),
-                                    jnp.int32(dst))
+            self._pools = self._cow(self._pools, jnp.asarray(src, jnp.int32),
+                                    jnp.asarray(dst, jnp.int32))
 
     def _copy_state(self, src, dst):
         """The compiled row copy over every state array at once (row
@@ -1437,7 +1649,7 @@ class SlotEngine:
             need = self._blocks_needed(
                 ids.size + req.gen.get("max_new_tokens", 16))
             try:
-                blocks, fill, entry = self._stage_blocks(ids, need)
+                blocks, fill, entry, held = self._stage_blocks(ids, need)
             except PoolExhausted:
                 # FIFO head-of-line wait: blocks free at step boundaries
                 self.queue.requeue(req)
@@ -1455,6 +1667,14 @@ class SlotEngine:
             self._slots[slot] = _Slot(req, ids, fill, blocks)
             if self._state:
                 self._seed_state(slot, entry)
+            if held is not None:
+                admitted = self._slots[slot]
+                admitted.held = held
+                admitted.demand = self._window.demand(need * self.block_size)
+                self._window.reserved += admitted.demand
+                self._window.sync(slot, held)
+                if self._cache is not None:
+                    admitted.chain = self._cache.chain()
             req.admitted = time.monotonic()
             req.queue_wait = req.admitted - req.arrival
             req.prefix_hit_tokens = fill
@@ -1481,6 +1701,7 @@ class SlotEngine:
         loop holds from a dispatch to the rebind of its outputs; the
         copies to the host wait outside it."""
         self._refuse_state_arrays("export_prefix_blocks")
+        self._refuse_block_groups("export_prefix_blocks")
         if self._cache is None:
             return None
         ids = np.asarray(prompt_ids, np.int32).reshape(-1)
@@ -1522,6 +1743,7 @@ class SlotEngine:
         mid-adoption frees every block taken so far — the pool is
         leak-free and the request simply prefills from scratch."""
         self._refuse_state_arrays("adopt_prefix_blocks")
+        self._refuse_block_groups("adopt_prefix_blocks")
         return self._at_step_boundary(
             lambda: self._apply_adoption(payload),
             "adopt migrated KV", timeout)
@@ -1749,12 +1971,22 @@ class SlotEngine:
             # releasing our references — shared system prompts survive;
             # with them the request's state snapshot, recorded on the
             # block that ends at its depth
+            # and the window-group blocks the request still holds, its
+            # last window
             self._cache.insert(slot.tokens, slot.blocks, written,
-                               snapshot=snapshot)
+                               snapshot=snapshot, window=slot.held,
+                               chain=slot.chain,
+                               cold_below=self._resume_block(slot)
+                               if slot.held is not None else 0)
         elif snapshot is not None:
             self._snapshots.free(slot.entry)
         for bid in slot.blocks:
             self._alloc.decref(bid)
+        if slot.held is not None:
+            for wbid in slot.held.values():
+                self._window.alloc.decref(wbid)
+            self._window.reserved -= slot.demand
+            self._window.clear(idx)
         self._bt[idx, :] = NULL_BLOCK
         self._pos[idx] = 0
         self._aid[idx] = 0
@@ -1863,6 +2095,16 @@ class SlotEngine:
             return None
         if self._spec is not None:
             self._spec.propose(live, tok, nvalid)
+        window_context = 0
+        if self._window is not None:
+            for i in live:
+                self._move_window(i, self._slots[i], int(nvalid[i]))
+                # the keys a window layer admits for the step's real
+                # columns: a column's position + 1, at most the window
+                first = int(self._pos[i]) + 1
+                window_context += int(np.minimum(
+                    np.arange(first, first + int(nvalid[i])),
+                    self._window.window).sum())
         computed, context = self._columns(live, nvalid)
         decoding = sum(1 for i in live if self._slots[i].state == "decode")
         handed = self._arrays(self._pools + self._state)
@@ -1879,7 +2121,8 @@ class SlotEngine:
             out, at, ahead=self._flight is not None,
             inplace=all(a.is_deleted() for a in handed), live=len(live),
             decoding=decoding, prefill_tokens=prefill_tokens,
-            computed=computed, context=context)
+            computed=computed, context=context,
+            window_context=window_context)
         for i in live:
             slot = self._slots[i]
             self._pos[i] += slot.advance
@@ -1939,6 +2182,9 @@ class SlotEngine:
                 a.nbytes for a in jax.tree_util.tree_leaves(out)))
             self.metrics.inc("computed_tokens", flight.computed)
             self.metrics.inc("attn_context_tokens", flight.context)
+            if flight.window_context:
+                self.metrics.inc("attn_window_context_tokens",
+                                 flight.window_context)
             self._count_aux(out["aux"])
             pick = out["pick"]
             for i, slot in flight.rows:

@@ -36,6 +36,23 @@ slot engine's needs):
   `match_snapshot` returns blocks no deeper than the deepest snapshot
   on the matched chain: a hit is only usable as deep as a snapshot
   exists. An entry lives as long as the block it is recorded on.
+- A layout may declare BLOCK GROUPS (`CacheLayout.groups`): which
+  layers' pools a group holds, its arrays and its `window`. The first
+  group keeps everything (today's kind); a second, windowed group
+  holds the layers that read only the last `window` keys of a slot.
+  It has its own `BlockAllocator` and pool size and, a slot, a short
+  table that MOVES (`WindowTables`): entry 0 stands for the block that
+  holds position ``base[slot]``, and as a slot's position passes a
+  block's last admissible key the engine drops its reference and
+  shifts the table. The prefix cache records a window-group block on
+  the full chain's key of the same depth (`insert(window=...)`), for
+  as long as it lives (a block freed deep inside a prompt as the
+  coldest: a later request most likely resumes near a prompt's or a
+  sequence's end); `match_window` returns the deepest depth at
+  which the full chain AND the window blocks that cover the `window`
+  tokens before it are present: the same shape of rule as "only as
+  deep as a snapshot exists", on blocks. `reclaim_window` frees the
+  window blocks of cold prefixes, least recently used first.
 
 Fault sites: ``serving.alloc_block`` fires on every physical block
 allocation (a `raise` action is deterministic pool exhaustion mid-
@@ -45,15 +62,17 @@ block copy.
 
 from __future__ import annotations
 
+import collections
 import hashlib
 
 import numpy as np
 
 from ..framework import faults
 
-__all__ = ["BLOCK_ROW_ORDER", "NULL_BLOCK", "CacheLayout", "PoolExhausted",
-           "BlockAllocator", "PrefixCache", "SnapshotEntries",
-           "positions_to_rows", "stored_width"]
+__all__ = ["BLOCK_ROW_ORDER", "NULL_BLOCK", "BlockGroup", "CacheLayout",
+           "PoolExhausted", "BlockAllocator", "PrefixCache",
+           "SnapshotEntries", "WindowTables", "positions_to_rows",
+           "stored_width"]
 
 #: physical block 0 — reserved scratch target for padding writes
 NULL_BLOCK = 0
@@ -69,6 +88,38 @@ BLOCK_ROW_ORDER = "thd"
 _ROOT = b"\x00root"
 # numpy has no bfloat16: its itemsize is float16's
 _NUMPY_NAME = {"bfloat16": "float16"}
+
+
+class BlockGroup:
+    """The layers of a layout whose blocks are of one kind: `layers`
+    indexes the step's list of pools (one tuple of arrays an entry),
+    `arrays` is ``((name, row_shape), ...)`` as `CacheLayout` says,
+    `window` the number of keys a query admits counting itself (None =
+    every earlier key: the blocks are kept for the slot's life).
+    `head_axis` is the pool axis a mesh may shard over mp and `heads`
+    how many heads lie along it (None = as many as the axis is long):
+    the axis is sharded only where mp divides the heads."""
+
+    def __init__(self, name, layers, arrays, window=None, head_axis=None,
+                 heads=None):
+        self.name = str(name)
+        self.layers = tuple(int(i) for i in layers)
+        self.arrays = tuple((str(n), tuple(int(d) for d in shape))
+                            for n, shape in arrays)
+        self.window = None if window is None else int(window)
+        self.head_axis = head_axis
+        self.heads = None if heads is None else int(heads)
+
+    def pool_shapes(self, num_blocks, block_size):
+        """The pools' shapes, one per array of a layer."""
+        return [(int(num_blocks), int(block_size)) + row
+                for _, row in self.arrays]
+
+    def bytes_per_token(self, itemsize):
+        """Cache bytes one token occupies over this group's layers
+        (while the token lies inside the window, for a windowed one)."""
+        return int(len(self.layers) * itemsize
+                   * sum(int(np.prod(row)) for _, row in self.arrays))
 
 
 class CacheLayout:
@@ -88,6 +139,14 @@ class CacheLayout:
     pool axis a mesh may shard over its model-parallel degree; None =
     the pool has no head axis and is replicated.
 
+    A layout whose layers do not all keep the same kind of block gives
+    `groups` (`BlockGroup`s) in place of `arrays`, `layers` and
+    `head_axis`: the first keeps everything, a second may be windowed.
+    One given `arrays` and `layers` declares the one group
+    ``"blocks"`` over all its layers and is carried exactly as before;
+    `arrays`, `layers` (all groups' together) and `head_axis` then
+    read as they always did.
+
     The second kind: `state` is ``((name, shape, dtype), ...)``, the
     arrays one SLOT keeps of each of `state_layers` state-holding
     layers, whatever its context (a recurrent state, a filter's tail).
@@ -97,28 +156,49 @@ class CacheLayout:
     not name is replicated. A layout that declares no state (dense and
     latent attention) is carried exactly as before."""
 
-    def __init__(self, row_order, arrays, layers, head_axis=None,
-                 state=(), state_layers=0, state_head_axis=None):
+    def __init__(self, row_order, arrays=None, layers=None, head_axis=None,
+                 state=(), state_layers=0, state_head_axis=None,
+                 groups=None):
         self.row_order = str(row_order)
-        self.arrays = tuple((str(n), tuple(int(d) for d in shape))
-                            for n, shape in arrays)
-        self.layers = int(layers)
-        self.head_axis = head_axis
+        if groups is None:
+            groups = (BlockGroup("blocks", range(int(layers)), arrays,
+                                 head_axis=head_axis),)
+        self.groups = tuple(groups)
+        self.layers = sum(len(g.layers) for g in self.groups)
+        if sorted(i for g in self.groups for i in g.layers) \
+                != list(range(self.layers)):
+            raise ValueError(
+                f"the block groups' layers "
+                f"{[g.layers for g in self.groups]} do not number the "
+                f"step's {self.layers} pools once each")
+        if self.groups[0].window is not None or len(self.groups) > 2 \
+                or (len(self.groups) == 2 and self.groups[1].window is None):
+            raise ValueError(
+                "a cache layout declares one group that keeps every "
+                "block and at most one windowed group beside it, got "
+                f"{[(g.name, g.window) for g in self.groups]}")
+        self.arrays = self.groups[0].arrays
+        self.head_axis = self.groups[0].head_axis
         self.state_layers = int(state_layers)
         self.state = tuple((str(n), tuple(int(d) for d in shape), str(dt))
                            for n, shape, dt in state) \
             if self.state_layers else ()
         self.state_head_axis = dict(state_head_axis or {})
 
+    def group_of(self, layer):
+        """The group that pool `layer` of the step's list belongs to."""
+        for g in self.groups:
+            if layer in g.layers:
+                return g
+        raise IndexError(layer)
+
     def pool_shapes(self, num_blocks, block_size):
-        """The pools' shapes, one per array of a layer."""
-        return [(int(num_blocks), int(block_size)) + row
-                for _, row in self.arrays]
+        """The first group's pools' shapes, one per array of a layer."""
+        return self.groups[0].pool_shapes(num_blocks, block_size)
 
     def bytes_per_token(self, itemsize):
         """Cache bytes one token occupies over all layers."""
-        return int(self.layers * itemsize
-                   * sum(int(np.prod(row)) for _, row in self.arrays))
+        return sum(g.bytes_per_token(itemsize) for g in self.groups)
 
     def state_shapes(self, rows):
         """The state arrays' shapes with `rows` leading rows, one per
@@ -248,6 +328,73 @@ class SnapshotEntries:
         self._free.append(entry)
 
 
+class WindowTables:
+    """The host's side of a windowed block group: its allocator and,
+    a slot, a SHORT block table that moves. Entry `j` of slot `b`'s
+    row stands for the block that holds positions ``base[b] + j *
+    block_size`` onward; `base` is a multiple of the block size. A
+    step that computes positions ``[pos, pos + n)`` of a slot reads
+    keys from ``pos - window + 1`` on, so the row holds at most
+    ``ceil((window + chunk) / block_size) + 1`` entries whatever the
+    slot's depth: the length of the compiled loop over it is a
+    constant of the layout.
+
+    A slot's blocks are a dict ``{block index: block id}`` that the
+    engine keeps with the slot (`held`); `sync` drops nothing itself.
+    `reserved` is the sum over live slots of the most blocks each can
+    hold at once (`demand`): admission keeps it within the pool, so a
+    block a live slot needs ahead is always free or reclaimable."""
+
+    def __init__(self, allocator, window, block_size, chunk, max_slots):
+        self.alloc = allocator
+        self.window = int(window)
+        self.block_size = int(block_size)
+        self.entries = -(-(self.window + int(chunk)) // self.block_size) + 1
+        self.table = np.full((max_slots, self.entries), NULL_BLOCK, np.int32)
+        self.base = np.zeros((max_slots,), np.int32)
+        self.reserved = 0
+
+    def demand(self, n_positions):
+        """The most blocks a request of `n_positions` holds at once."""
+        return min(self.entries, -(-int(n_positions) // self.block_size))
+
+    def first_block(self, pos):
+        """Index of the block that holds the earliest key a query at
+        position `pos` admits (``pos - window + 1``)."""
+        return max(int(pos) - self.window + 1, 0) // self.block_size
+
+    def sync(self, slot, held):
+        """Write `held` into `slot`'s row, from its lowest block on."""
+        first = min(held) if held else 0
+        if held and max(held) - first >= self.entries:
+            raise AssertionError(
+                f"slot {slot} holds window blocks {first}..{max(held)}, "
+                f"more than the table's {self.entries} entries")
+        row = self.table[slot]
+        row[:] = NULL_BLOCK
+        for k, bid in held.items():
+            row[k - first] = bid
+        self.base[slot] = first * self.block_size
+
+    def clear(self, slot):
+        self.table[slot, :] = NULL_BLOCK
+        self.base[slot] = 0
+
+
+class _Chain:
+    """The prefix keys of ONE token sequence, made incrementally: key
+    `k` is the digest of its first ``(k + 1) * block_size`` tokens, the
+    very bytes `PrefixCache._digest` hashes at once. `indexed` counts
+    the leading blocks `insert` has already walked for this sequence,
+    so that a live request that indexes its blocks as it goes pays for
+    each once."""
+
+    def __init__(self):
+        self.keys: list = []
+        self.indexed = 0
+        self._hasher = hashlib.sha1()
+
+
 class PrefixCache:
     """Radix prefix index over fully written KV blocks.
 
@@ -256,10 +403,17 @@ class PrefixCache:
     allocator reference per entry, so indexed blocks survive slot
     eviction and are physically shared by later requests with the same
     prefix (`match` -> the caller increfs per consuming slot).
+
+    `window` (a `WindowTables`) is the layout's windowed group, if it
+    has one: an entry may then also name the window-group block of the
+    same positions (`_wblocks`, one reference of the window group's
+    allocator each), recorded by `insert(window=...)` and dropped with
+    its entry or, before it, by `reclaim_window`.
     """
 
     def __init__(self, allocator: BlockAllocator, block_size,
-                 snapshots: SnapshotEntries = None):
+                 snapshots: SnapshotEntries = None,
+                 window: WindowTables = None):
         self._alloc = allocator
         self.block_size = block_size
         #: the entries of the state snapshot pool, for a layout with
@@ -270,6 +424,11 @@ class PrefixCache:
         #: called once for every recorded snapshot that eviction (not a
         #: deeper snapshot on its chain) dropped
         self.snapshot_evicted_hook = None
+        #: the windowed group, for a layout with one (None otherwise);
+        #: `_wblocks` maps a key to the window-group block of the same
+        #: positions, least recently used first
+        self.window = window
+        self._wblocks = collections.OrderedDict()
         self._blocks: dict = {}     # key -> block id
         self._chunks: dict = {}     # key -> np.int32 chunk tokens
         self._parent: dict = {}     # key -> parent key
@@ -292,9 +451,58 @@ class PrefixCache:
         return hashlib.sha1(
             np.ascontiguousarray(ids, np.int32).tobytes()).digest()
 
+    def chain(self):
+        """A fresh `_Chain` for one sequence (`insert(chain=...)`)."""
+        return _Chain()
+
+    def _extend(self, chain, ids, n_blocks):
+        """Grow `chain.keys` to the first `n_blocks` blocks of `ids`:
+        each block's bytes are hashed once, and key `k` is
+        `_digest(ids[:(k + 1) * block_size])`."""
+        bs = self.block_size
+        have = len(chain.keys)
+        if have >= n_blocks:
+            return
+        data = np.ascontiguousarray(ids[have * bs:n_blocks * bs], np.int32)
+        for k in range(n_blocks - have):
+            chain._hasher.update(data[k * bs:(k + 1) * bs].tobytes())
+            chain.keys.append(chain._hasher.copy().digest())
+
+    def _walk(self, ids, limit):
+        """Keys and blocks of the longest indexed chain of whole
+        blocks under ``ids[:limit]``."""
+        bs = self.block_size
+        chain, blocks = _Chain(), []
+        ids = np.asarray(ids)
+        while (len(blocks) + 1) * bs <= limit:
+            self._extend(chain, ids, len(blocks) + 1)
+            bid = self._blocks.get(chain.keys[-1])
+            if bid is None:
+                chain.keys.pop()
+                break
+            blocks.append(bid)
+        return chain.keys, blocks
+
     def _touch(self, key):
         self._clock += 1
         self._lru[key] = self._clock
+
+    def _diverging_child(self, parent, want):
+        """Among `parent`'s children, the one whose chunk shares the
+        longest proper prefix with `want`: ``(key, n_rows)`` or None."""
+        want = np.asarray(want, np.int32)
+        best_key, best_c = None, 0
+        if want.size:
+            for child in self._children.get(parent, ()):
+                chunk = self._chunks[child]
+                m = min(chunk.size, want.size)
+                neq = np.nonzero(chunk[:m] != want[:m])[0]
+                c = int(neq[0]) if neq.size else m
+                if c > best_c:
+                    best_key, best_c = child, c
+        if best_key is None or best_c >= self.block_size:
+            return None
+        return best_key, best_c
 
     def match(self, ids, limit):
         """Longest indexed prefix of ``ids[:limit]``.
@@ -305,31 +513,16 @@ class PrefixCache:
         ``(src_block, n_rows)`` copy-on-write candidate when a cached
         block matches only the first `n_rows` of the next chunk (the
         divergence point lies inside it)."""
-        bs = self.block_size
-        blocks, n, parent = [], 0, _ROOT
-        while n + bs <= limit:
-            key = self._digest(ids[:n + bs])
-            bid = self._blocks.get(key)
-            if bid is None:
-                break
-            blocks.append(bid)
-            parent = key
-            n += bs
+        keys, blocks = self._walk(ids, limit)
+        for key in keys:
             self._touch(key)
+        n = len(blocks) * self.block_size
         cow = None
-        want = np.asarray(ids[n:limit], np.int32)
-        if want.size:
-            best_key, best_c = None, 0
-            for child in self._children.get(parent, ()):
-                chunk = self._chunks[child]
-                m = min(chunk.size, want.size)
-                neq = np.nonzero(chunk[:m] != want[:m])[0]
-                c = int(neq[0]) if neq.size else m
-                if c > best_c:
-                    best_key, best_c = child, c
-            if best_key is not None and best_c < bs:
-                cow = (self._blocks[best_key], best_c)
-                self._touch(best_key)
+        found = self._diverging_child(keys[-1] if keys else _ROOT,
+                                      ids[n:limit])
+        if found is not None:
+            cow = (self._blocks[found[0]], found[1])
+            self._touch(found[0])
         return blocks, n, cow
 
     def match_snapshot(self, ids, limit):
@@ -345,22 +538,59 @@ class PrefixCache:
         deeper than any snapshot and have to be computed again. No
         copy-on-write candidate: a state cannot be cut inside a
         block."""
-        bs = self.block_size
-        blocks, n, keys = [], 0, []
-        while n + bs <= limit:
-            key = self._digest(ids[:n + bs])
-            bid = self._blocks.get(key)
-            if bid is None:
-                break
-            blocks.append(bid)
-            keys.append(key)
-            n += bs
+        keys, blocks = self._walk(ids, limit)
         deep = max((i for i, key in enumerate(keys) if key in self._snap),
                    default=-1) + 1
         for key in keys[:deep]:
             self._touch(key)
         entry = self._snap[keys[deep - 1]] if deep else None
-        return blocks[:deep], deep * bs, entry, n
+        return blocks[:deep], deep * self.block_size, entry, \
+            len(blocks) * self.block_size
+
+    def match_window(self, ids, limit):
+        """`match` for a layout with a windowed group: the longest
+        indexed prefix of ``ids[:limit]``, cut at the deepest depth at
+        which the window group still holds every block of the `window`
+        tokens before it (a query that resumes there reads them).
+
+        Returns ``(blocks, n_tokens, cow, held, n_matched)``: the
+        full group's shared blocks and the tokens they cover; a
+        copy-on-write candidate ``(src_block, n_rows, src_window_block)``
+        when the chain was not cut and a cached block of BOTH groups
+        matches the first `n_rows` of the next chunk; `held`, the
+        window-group blocks to resume over as ``{block index: block
+        id}`` (NOT yet increfed); and how many tokens' blocks the full
+        chain matched in all: ``n_matched - n_tokens`` of them are
+        lost to the window and computed again."""
+        bs, w = self.block_size, self.window
+        keys, blocks = self._walk(ids, limit)
+        # run[i]: how many consecutive keys up to and with `i` have a
+        # window-group block
+        run, deep = [], len(keys)
+        for key in keys:
+            run.append((run[-1] if run else 0) + 1
+                       if key in self._wblocks else 0)
+        while deep and run[deep - 1] < deep - w.first_block(deep * bs):
+            deep -= 1
+        n, cow = deep * bs, None
+        if deep == len(keys):
+            found = self._diverging_child(keys[-1] if keys else _ROOT,
+                                          ids[n:limit])
+            if found is not None and found[0] in self._wblocks:
+                cow = (self._blocks[found[0]], found[1],
+                       self._wblocks[found[0]])
+                self._touch(found[0])
+                self._touch_window(found[0])
+        for key in keys[:deep]:
+            self._touch(key)
+        held = {}
+        for k in range(w.first_block(n + (cow[1] if cow else 0)), deep):
+            held[k] = self._wblocks[keys[k]]
+            self._touch_window(keys[k])
+        return blocks[:deep], n, cow, held, len(blocks) * bs
+
+    def _touch_window(self, key):
+        self._wblocks.move_to_end(key)      # most recent last
 
     def _record_snapshot(self, key, entry):
         """`entry` holds the state at the end of `key`'s block: record
@@ -395,8 +625,9 @@ class PrefixCache:
             if self.snapshot_evicted_hook is not None:
                 self.snapshot_evicted_hook()
 
-    def insert(self, tokens, blocks, written, snapshot=None):
-        """Index every fully written block of a finished sequence.
+    def insert(self, tokens, blocks, written, snapshot=None, window=None,
+               chain=None, cold_below=0):
+        """Index every fully written block of a sequence.
 
         `tokens` is the full id sequence, `blocks` its physical block
         list (table order), `written` how many positions hold real KV
@@ -407,31 +638,53 @@ class PrefixCache:
         `snapshot` ``(entry, depth)`` hands over the snapshot entry that
         holds the sequence's state after exactly `depth` tokens: it is
         recorded on the block that ends there, or freed when no block
-        does."""
+        does.
+
+        `window` ``{block index: block id}`` names window-group blocks
+        of the same sequence: each that is fully written is recorded
+        on its depth's entry (one reference of the window group's
+        allocator), unless the entry has one already; one whose index
+        lies under `cold_below` is recorded as the LEAST recently used
+        (the caller knows no later request is likely to resume there),
+        the others as the most. `chain` is the
+        sequence's own `_Chain` (`chain()`), for a request that
+        indexes its blocks more than once as it goes: blocks it has
+        walked before are not walked again."""
         bs = self.block_size
         tokens = np.asarray(tokens, np.int32)
-        parent, added = _ROOT, 0
-        if snapshot is not None:
-            entry, depth = snapshot
-            at = self._digest(tokens[:depth]) \
-                if 0 < depth <= written and depth % bs == 0 else None
-        for k in range(1, written // bs + 1):
-            key = self._digest(tokens[:k * bs])
+        chain = chain if chain is not None else _Chain()
+        if chain.indexed and chain.keys[chain.indexed - 1] \
+                not in self._blocks:
+            chain.indexed = 0       # the index was cleared meanwhile
+        n_blocks = written // bs
+        self._extend(chain, tokens, n_blocks)
+        keys, added = chain.keys, 0
+        parent = keys[chain.indexed - 1] if chain.indexed else _ROOT
+        for k in range(chain.indexed, n_blocks):
+            key = keys[k]
             if key not in self._blocks:
-                bid = blocks[k - 1]
+                bid = blocks[k]
                 self._alloc.incref(bid)
                 self._blocks[key] = bid
-                self._chunks[key] = tokens[(k - 1) * bs:k * bs].copy()
+                self._chunks[key] = tokens[k * bs:(k + 1) * bs].copy()
                 self._parent[key] = parent
                 self._children.setdefault(parent, set()).add(key)
                 added += 1
             self._touch(key)
             parent = key
+        chain.indexed = max(chain.indexed, n_blocks)
         if snapshot is not None:
-            if at is None:
-                self.snapshots.free(entry)
+            entry, depth = snapshot
+            if 0 < depth <= written and depth % bs == 0:
+                self._record_snapshot(keys[depth // bs - 1], entry)
             else:
-                self._record_snapshot(at, entry)
+                self.snapshots.free(entry)
+        for k, wbid in (window or {}).items():
+            if k < n_blocks and keys[k] not in self._wblocks:
+                self.window.alloc.incref(wbid)
+                self._wblocks[keys[k]] = wbid
+                if k < cold_below:
+                    self._wblocks.move_to_end(keys[k], last=False)
         return added
 
     def prefix_tokens(self, key):
@@ -453,6 +706,9 @@ class PrefixCache:
             self.spill_hook(key, self.prefix_tokens(key), bid,
                             len(self._chunks[key]))
         self._drop_snapshot(key)
+        wbid = self._wblocks.pop(key, None)
+        if wbid is not None:
+            self.window.alloc.decref(wbid)
         self._children.get(self._parent[key], set()).discard(key)
         self._children.pop(key, None)
         bid = self._blocks.pop(key)
@@ -464,7 +720,8 @@ class PrefixCache:
     def reclaim(self, n_blocks):
         """Evict LRU leaf entries until `n_blocks` physical blocks were
         actually freed (entries whose block a live slot still references
-        free nothing but are dropped last-resort too). Returns #freed."""
+        free nothing but are dropped last-resort too). Returns #freed.
+        An entry goes with the window-group block recorded on it."""
         freed = 0
         while freed < n_blocks:
             leaves = [k for k in self._blocks
@@ -480,6 +737,25 @@ class PrefixCache:
             if self._evict(victim):
                 freed += 1
         return freed
+
+    def reclaim_window(self, n_blocks):
+        """Free `n_blocks` window-group blocks that only the index
+        still holds, least recently used first; their entries stay
+        (the full chain then matches deeper than the window group can
+        serve, and the rest is computed again). Returns #freed."""
+        alloc, freed = self.window.alloc, 0
+        for key in list(self._wblocks):
+            if freed >= n_blocks:
+                break
+            if alloc.refcount(self._wblocks[key]) == 1:
+                alloc.decref(self._wblocks.pop(key))
+                freed += 1
+        return freed
+
+    @property
+    def window_blocks(self):
+        """How many window-group blocks the index names."""
+        return len(self._wblocks)
 
     def clear(self, spill=True):
         """Drop every entry (and its allocator reference). Leaves go

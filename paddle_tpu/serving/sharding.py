@@ -181,14 +181,17 @@ class ShardingPlan:
         sh = self.values_shardings(values)
         return {k: jax.device_put(v, sh[k]) for k, v in values.items()}
 
-    def pool_sharding(self, layout, shape):
-        """Sharding of one pool of `shape` under the model's
-        `paging.CacheLayout`: the head axis the layout names over mp
-        when it divides it; a pool whose layout has no head axis
-        (latent rows) or whose heads do not divide mp is replicated
-        (the engine still serves; it just stops saving cache memory —
-        same silent-guard stance as the overlap kernels)."""
-        return self._over_heads(layout.head_axis, shape)
+    def pool_sharding(self, group, shape):
+        """Sharding of one pool of `shape` under a block group of the
+        model's `paging.CacheLayout` (or the layout itself, which reads
+        as its first group): the head axis the group names over mp
+        when mp divides its heads; a pool with no head axis (latent
+        rows) or whose heads do not divide mp is replicated (the
+        engine still serves; it just stops saving cache memory — same
+        silent-guard stance as the overlap kernels). Each group of a
+        layout is placed by its own rule."""
+        return self._over_heads(group.head_axis, shape,
+                                getattr(group, "heads", None))
 
     def state_sharding(self, layout, name, shape):
         """Sharding of the per-slot state array `name` of `shape`
@@ -197,8 +200,12 @@ class ShardingPlan:
         for pools."""
         return self._over_heads(layout.state_head_axis.get(name), shape)
 
-    def _over_heads(self, axis, shape):
-        if self.mp > 1 and axis is not None and shape[axis] % self.mp == 0:
+    def _over_heads(self, axis, shape, heads=None):
+        """`heads` lie along `axis` (None = one an element): sharded
+        over mp only where mp divides them."""
+        if self.mp > 1 and axis is not None \
+                and (shape[axis] if heads is None else heads) % self.mp == 0 \
+                and shape[axis] % self.mp == 0:
             spec = [None] * len(shape)
             spec[axis] = MP_AXIS
             return self._named(P(*spec))

@@ -114,11 +114,13 @@ class Speculation:
         # rows are masked, then overwritten); out of a recurrent state
         # it cannot be
         eng._refuse_state_arrays("speculation (spec_len > 0)")
-        if self.layout.state:
+        # and its rows do not move with a window group's table
+        eng._refuse_block_groups("speculation (spec_len > 0)")
+        if self.layout.state or len(self.layout.groups) > 1:
             raise ValueError(
                 "speculation: the draft model's cache layout keeps "
-                "per-slot state arrays, which a rejected draft cannot "
-                "be rolled out of")
+                "per-slot state arrays or a windowed block group, which "
+                "a rejected draft cannot be rolled out of")
         self.pools = eng._zero_pools(self.layout, place=False)
         self.pool_bytes = eng._pool_bytes(self.layout)
         # the draft trace is a separate, narrower program

@@ -266,6 +266,8 @@ def _cell_engine(name, layers):
                            transformers.LatentMoEConfig),
             "hybrid_linear": (transformers.HybridLinearForCausalLM,
                               transformers.HybridLinearConfig),
+            "window_moe": (transformers.WindowMoEForCausalLM,
+                           transformers.WindowMoEConfig),
         }[config["family"]]
         was = paddle.get_default_dtype()
         paddle.set_default_dtype(dep["weight_dtype"])
@@ -275,7 +277,9 @@ def _cell_engine(name, layers):
             paddle.set_default_dtype(was)
     eng = serving.SlotEngine(
         model, max_slots=dep["max_slots"], max_seq_len=dep["max_seq_len"],
-        block_size=dep.get("block_size"), num_blocks=2,
+        block_size=dep.get("block_size"),
+        num_blocks={name: 2 for name in dep["num_blocks"]}
+        if isinstance(dep["num_blocks"], dict) else 2,
         prefill_chunk=dep.get("prefill_chunk"),
         cache_dtype=jnp.dtype(dep["cache_dtype"]),
         snapshot_entries=dep.get("snapshot_entries"))
@@ -417,3 +421,71 @@ def test_hybrid_cell_step_updates_pools_and_state_arrays_in_place(
         .memory_analysis()
     assert helper.alias_size_in_bytes == aliased
     assert helper.temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("layers", [
+    pytest.param(4, id="mellum2-12b-a2.5b-one-period"),
+    pytest.param(None, id="mellum2-12b-a2.5b", marks=pytest.mark.slow)])
+def test_two_group_cell_step_updates_both_groups_pools_in_place(
+        layers, one_chip, no_compile_cache):
+    """The step of a layout with TWO block groups: the full layers'
+    pools `bf16[10241, 16, 512]` and the sliding layers' `bf16[4097,
+    16, 512]`, K and V each, the 4 KV heads of a token side by side in
+    a row of 512 columns. Every byte of both groups is aliased to the
+    arguments at its LOGICAL size (a row declared `[4, 128]` pads 4 ->
+    16 in the chip's bfloat16 tiles, four times the pool; 512 columns
+    tile as they are) and no pool is copied. The host's one array
+    carries both tables and the window group's base position. And the
+    copy-on-write copy, one program over both groups, works in place
+    (its temporaries hold a block of each pool, 161 KB). (At the cell's real depth, `-m slow`, 11 GB
+    of host RAM: arguments 13.147 GB, 2.215 GB aliased, 24.1 MB of
+    temporaries.)"""
+    eng, num_blocks, vocab = _cell_engine("mellum2-12b-a2.5b", layers)
+    slots = eng.max_slots
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    vec = np.zeros((slots,), np.int32)
+    batch, extras = eng._stage(
+        np.zeros((slots, eng.prefill_chunk), np.int32), vec, vec)
+    entries = eng._window.entries
+    assert entries == (1024 + 64) // 16 + 1 == 69
+    assert batch.shape == (slots, eng.prefill_chunk + 2
+                           + eng.blocks_per_slot + entries + 1)
+    eng._num_blocks = dict(num_blocks)
+    shapes = eng._pool_shapes(eng._layout)
+    kinds = eng.model.config.layer_types
+    assert [layer for layer in shapes] == [
+        [((10241 if kind == "full_attention" else 4097), 16, 512)] * 2
+        for kind in kinds]
+    pools = [tuple(jax.ShapeDtypeStruct(s, eng._pool_dtype,
+                                        sharding=one_chip) for s in layer)
+             for layer in shapes]
+    values = jax.tree_util.tree_map(spec, eng._values)
+    compiled = eng._decode.lower(
+        values, spec(batch), pools,
+        jax.tree_util.tree_map(spec, extras)).compile()
+
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(s)) for layer in shapes
+                     for s in layer) * eng._pool_dtype.itemsize
+    held = sum(v.nbytes for v in eng._values.values()) + batch.nbytes \
+        + extras["prev_pick"].nbytes
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert abs(memory.argument_size_in_bytes - held - pool_bytes) < 1 << 16
+    assert memory.temp_size_in_bytes < 32 * _MB
+
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("ENTRY"):]
+    big = ["10241,16,512", "4097,16,512"]
+    moved = [line.split(" = ")[0].strip() for line in entry.splitlines()
+             if re.search(r" copy(-start)?\(", line)
+             and any(f"[{dims}]" in line.split(" = ")[1].split("(")[0]
+                     for dims in big)]
+    assert not moved, f"pool-sized copies: {moved}"
+
+    pair = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    helper = eng._cow.lower(pools, pair, pair).compile().memory_analysis()
+    assert helper.alias_size_in_bytes == pool_bytes
+    assert helper.temp_size_in_bytes < _MB      # a block a pool, staged
