@@ -26,11 +26,15 @@ attention share (`model_type` ``sarvam_mla`` / ``deepseek_v2`` /
   ep_size)``: it holds experts ``[ep_rank * n, (ep_rank + 1) * n)``,
   routes over all of them and computes the picks that land on its own;
   what absent experts would have added is left out (their chips would
-  add it in a deployment). The grouped product is `lax.ragged_dot` over
-  the picks sorted by held expert: padding columns of a serving step
-  and picks of absent experts sort behind every group and are
-  multiplied by nothing. The first `first_k_dense_replace` layers are a
-  plain SwiGLU.
+  add it in a deployment). The grouped product (`grouped_product`) runs
+  over the picks sorted by held expert: padding columns of a serving
+  step and picks of absent experts sort behind every group and are
+  multiplied by nothing. A program lowered for a TPU runs JAX's Pallas
+  `megablox.gmm` on bfloat16 operands, one turn a (non-empty expert,
+  row tile) pair with tiles chosen from the product's shape
+  (`GMM_TILES`), so a step pays for the bytes of the experts it picked;
+  any other platform, dtype or row count runs `lax.ragged_dot`. The
+  first `first_k_dense_replace` layers are a plain SwiGLU.
 
 Serving: the model states its cache layout (`cache_layout`: one
 ``[block_size, cache_row_stored]`` array a layer) and
@@ -39,6 +43,9 @@ owns the scatter through the block table and the attention over it
 """
 
 from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
 
 from ... import nn
 from ...core.tensor import Tensor
@@ -308,6 +315,89 @@ class LatentAttention(nn.Layer):
 
 # -- experts ------------------------------------------------------------------
 
+#: rows of one turn of the Pallas grouped product. A group of a few rows
+#: pays for a whole tile on the MXU, but its turn is bound by the bytes
+#: of its expert, not by the tile's FLOPs (64 and 256 measured within
+#: 5 % of 128).
+GMM_ROW_TILE = 128
+#: elements of an expert's ``[k, n]`` matrix one turn reads: 2 MiB of
+#: bfloat16, two of them in flight. The most at which the backward's
+#: kernel (`tgmm`, whose float32 accumulator is a whole weight tile)
+#: still fits the 16 MiB of fast memory a kernel may use; 4 MiB tiles
+#: measured 1-3 % faster forward and are refused backward.
+GMM_WEIGHT_TILE = 1024 * 1024
+#: ``(k, n) -> (tm, tk, tn)`` of the four products swept on a TPU v5e
+#: (PERF.md section 6, PR 36: the gate-up and down products of experts
+#: 2304 x 896 and 4096 x 2048 wide, 4,096 sorted rows of which 5-7 %
+#: are real): the best measured under `GMM_WEIGHT_TILE`. They are what
+#: `gmm_tiling`'s rule gives today; the table keeps the cells' tiles
+#: where they were measured if the rule is changed for another shape.
+GMM_TILES = {
+    (2304, 1792): (128, 2304, 384),
+    (896, 2304): (128, 896, 1152),
+    (4096, 4096): (128, 4096, 256),
+    (2048, 4096): (128, 2048, 512),
+}
+
+
+def gmm_tiling(m, k, n):
+    """``(tm, tk, tn)`` of `megablox.gmm` for ``[m, k] x [groups, k,
+    n]``. The work is one read of ``k x n`` per (non-empty group, row
+    tile) pair, so: the whole `k` where a tile 256 columns wide holds
+    it (the accumulator is then written once: a split `k` measured
+    5-25 % slower), and `tn` as wide as `GMM_WEIGHT_TILE` allows, in
+    whole lanes."""
+    del m
+    if (k, n) in GMM_TILES:
+        return GMM_TILES[k, n]
+    tk = min(k, GMM_WEIGHT_TILE // 256)
+    tn = n if tk * n <= GMM_WEIGHT_TILE \
+        else max(GMM_WEIGHT_TILE // tk // 128, 1) * 128
+    return GMM_ROW_TILE, tk, tn
+
+
+def _ragged_product(x, w, sizes):
+    return jax.lax.ragged_dot(x, w, sizes)
+
+
+@jax.custom_vjp
+def _real_rows_gradient(x, real):
+    """`x`, with a gradient that is zero where `real` is false: costs
+    the forward nothing (a `where` on the rows was one more pass over
+    them a layer)."""
+    return x
+
+
+_real_rows_gradient.defvjp(
+    lambda x, real: (x, real),
+    lambda real, g: (jnp.where(real, g, 0), None))
+
+
+def _pallas_product(x, w, sizes, interpret=False):
+    """`megablox.gmm` (with its `tgmm` backward). The kernel writes no
+    row behind the groups, in its result and in the gradient it hands
+    back for `x` alike: the caller masks the first (`routed` does),
+    `_real_rows_gradient` the second."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    real = (jnp.arange(x.shape[0]) < sizes.sum())[:, None]
+    return megablox.gmm(_real_rows_gradient(x, real), w, sizes, x.dtype,
+                        gmm_tiling, interpret=interpret)
+
+
+def grouped_product(x, w, sizes):
+    """``x[rows of group g] @ w[g]`` for rows ``[m, k]`` sorted by
+    group, ``w [groups, k, n]`` and ``sizes [groups]`` int32 that may
+    sum to less than `m`: float32 accumulation, `x`'s dtype out. Which
+    form runs follows what the program is lowered for and the operands:
+    bfloat16 rows in whole row tiles on a TPU take the Pallas kernel,
+    everything else `lax.ragged_dot`."""
+    bf16 = x.dtype == w.dtype == jnp.bfloat16
+    if not bf16 or x.shape[0] % gmm_tiling(x.shape[0], *w.shape[1:])[0]:
+        return _ragged_product(x, w, sizes)
+    return jax.lax.platform_dependent(x, w, sizes, tpu=_pallas_product,
+                                      default=_ragged_product)
+
 
 class HeldExperts(nn.Layer):
     """The routed-expert layer of ONE share of an expert-parallel
@@ -325,7 +415,9 @@ class HeldExperts(nn.Layer):
     all routed experts and selects the largest, no bias (a parameter
     the layer then does not have). Either way the picked scores are
     normalised to sum 1 and scaled by `routed_scaling_factor`, and the
-    grouped product below is the one piece of code under both."""
+    grouped product (`grouped_product`: `megablox.gmm` in a program
+    lowered for a TPU, `lax.ragged_dot` elsewhere) is the one piece of
+    code under both."""
 
     def __init__(self, config: LatentMoEConfig):
         super().__init__()
@@ -383,7 +475,6 @@ class HeldExperts(nn.Layer):
         ``[T, H]``."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
 
         n, k, held = h.shape[0], self.top_k, self.held
         with jax.named_scope("moe.route"):
@@ -399,9 +490,9 @@ class HeldExperts(nn.Layer):
                 .astype(jnp.int32)
         with jax.named_scope("moe.experts"):
             x = h[order // k]
-            gu = lax.ragged_dot(x, _v(self.gate_up), sizes)
+            gu = grouped_product(x, _v(self.gate_up), sizes)
             act = jax.nn.silu(gu[:, :self.inter]) * gu[:, self.inter:]
-            out = lax.ragged_dot(act, _v(self.down), sizes)
+            out = grouped_product(act, _v(self.down), sizes)
             # rows behind the groups are whatever the product left there
             out = jnp.where((jnp.arange(n * k) < sizes.sum())[:, None],
                             out, 0)

@@ -2238,11 +2238,15 @@ class SlotEngine:
     def _count_aux(self, aux):
         """What the model's own step counted (`aux`, and the constants
         it named at trace time), into `aux_totals` and a counter of the
-        same name."""
+        same name. `expert_rows` ``[expert layers, held experts]`` also
+        counts `expert_groups_run`, its non-empty (layer, expert)
+        groups: what the grouped product's time is proportional to."""
         for name, value in {**aux, **self._aux_const}.items():
             value = np.asarray(value, np.int64)
             self.aux_totals[name] = self.aux_totals.get(name, 0) + value
             self.metrics.inc(name, int(value.sum()))
+            if name == "expert_rows":
+                self.metrics.inc("expert_groups_run", int((value > 0).sum()))
 
     def _take(self, i, slot, now):
         """The slot's landed token (`_pick`) joins its answer, stamped

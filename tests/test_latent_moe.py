@@ -186,6 +186,42 @@ def test_the_steps_pick_is_the_argmax_of_the_row_it_leaves_behind(latent):
     assert m.get("readback_bytes") == m.get("steps") * (2 * 4 + 2 * 4 * 4)
 
 
+def test_expert_groups_run_counts_a_steps_non_empty_groups(latent):
+    """`expert_groups_run` after a step = the (layer, held expert)
+    groups of that step's `expert_rows` that hold a row: what the
+    grouped product's time follows. In `snapshot()` and the Prometheus
+    text like every counter."""
+    from paddle_tpu import observe
+
+    cfg, model = latent
+    eng = serving.SlotEngine(model, max_slots=2, max_seq_len=128,
+                             block_size=8, prefill_chunk=16)
+    futs = [eng.submit(_tokens(7, 40), max_new_tokens=4, timeout=None),
+            eng.submit(_tokens(8, 5), max_new_tokens=6, timeout=None)]
+    assert eng.metrics.get("expert_groups_run") == 0
+    seen = set()
+    while eng.active or eng.queue.depth:
+        eng._admit()
+        rows = np.array(eng.aux_totals.get("expert_rows", 0))
+        groups = eng.metrics.get("expert_groups_run")
+        eng._step()
+        step_rows = np.asarray(eng.aux_totals["expert_rows"]) - rows
+        assert step_rows.shape == (2, 4)
+        ran = int((step_rows > 0).sum())
+        assert eng.metrics.get("expert_groups_run") - groups == ran
+        seen.add(ran)
+    for fut in futs:
+        fut.result(10)
+    # a decode step of a row or two leaves groups empty, a prefill chunk
+    # fills all eight
+    assert min(seen) < 8 and max(seen) == 8
+    total = eng.metrics.get("expert_groups_run")
+    assert 0 < total < eng.metrics.get("expert_rows")
+    assert eng.metrics.snapshot()["counters"]["expert_groups_run"] == total
+    assert f"paddle_serving_expert_groups_run_total {total}" \
+        in observe.prometheus_text(serving=eng.metrics)
+
+
 def test_absorbed_attention_equals_expanded(latent):
     """One layer's attention: the expanded form over the whole sequence
     (`forward`) against the absorbed form over a paged pool
@@ -301,6 +337,181 @@ def test_padding_columns_and_absent_picks_are_no_rows():
     for t in range(12):
         if t >= 5 or bool(absent[t]):
             np.testing.assert_allclose(np.asarray(y)[t], lone[t], atol=1e-7)
+
+
+# -- the grouped product's two forms ------------------------------------------
+
+# 128 tokens x 2 picks = 256 sorted rows = two row tiles of the Pallas
+# product; experts 128 x 128 wide in bfloat16, this share holds experts
+# 4-7 of 8. Per case: the picks of each token, or None for the seeded
+# router's own; the mask of real rows; the share; the rows each held
+# expert must count (None: whatever the router gives).
+_T, _PICKS = 128, 2
+_PRODUCT_CASES = {
+    # expert 4 empty, expert 5 one row, expert 6 rows 1-100, expert 7
+    # rows 101-200 across the boundary at 128; 55 picks of absent
+    # experts behind them
+    "empty_one_straddle": (
+        [(5, 1)] + [(6, 7)] * 100 + [(0, 1)] * 27, None, (1, 2),
+        [0, 1, 100, 100]),
+    # a serving step none of whose columns is real
+    "all_behind": (None, np.zeros(_T, bool), (1, 2), [0, 0, 0, 0]),
+    # every expert held, every row real: the groups fill the rows
+    "all_real": (None, None, (0, 1), None),
+    # the seeded router over this share, a third of the rows padding
+    "router_and_padding": (None, np.arange(_T) % 3 > 0, (1, 2), None),
+}
+
+
+def _held_bf16(scoring, share, picks):
+    sizes = {**SIZES, "hidden_size": 128, "moe_intermediate_size": 128,
+             "num_experts_per_tok": _PICKS}
+    cfg = LatentMoEConfig(**sizes, ep_rank=share[0], ep_size=share[1])
+    cfg.router_scoring = scoring
+    paddle.seed(5)
+    paddle.set_default_dtype("bfloat16")
+    try:
+        layer = HeldExperts(cfg)
+    finally:
+        paddle.set_default_dtype("float32")
+    h = np.random.RandomState(9).randn(_T, 128).astype(np.float32)
+    if picks is not None:
+        # logits = the row's first 8 features; its two picks stand out
+        route = np.zeros((128, 8), np.float32)
+        route[np.arange(8), np.arange(8)] = 1.0
+        layer.router.weight._value = jnp.asarray(route, jnp.bfloat16)
+        h *= 0.1
+        for t, pair in enumerate(picks):
+            h[t, list(pair)] = 4.0
+    return layer, jnp.asarray(h, jnp.bfloat16)
+
+
+def _pallas_in_place_of_ragged(monkeypatch):
+    """What a program lowered for a TPU runs, here: the default branch
+    of `grouped_product` becomes the Pallas kernel, interpreted."""
+    import functools
+
+    from paddle_tpu.nlp.transformers import latent_moe
+
+    monkeypatch.setattr(
+        latent_moe, "_ragged_product",
+        functools.partial(latent_moe._pallas_product, interpret=True))
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("case", list(_PRODUCT_CASES))
+def test_pallas_grouped_product_equals_ragged_dot(case, scoring,
+                                                  monkeypatch):
+    """`HeldExperts.routed` with `megablox.gmm` under it against the
+    same call with `lax.ragged_dot`: the same group sizes, and `y`
+    within a rounding of bfloat16 (both accumulate in float32 and round
+    once, in another order of summation)."""
+    picks, valid, share, want_rows = _PRODUCT_CASES[case]
+    layer, h = _held_bf16(scoring, share, picks)
+    valid = None if valid is None else jnp.asarray(valid)
+    y_ragged, rows_ragged = jax.jit(layer.routed)(h, valid)
+    _pallas_in_place_of_ragged(monkeypatch)
+    y_pallas, rows_pallas = jax.jit(layer.routed)(h, valid)
+    traced = str(jax.make_jaxpr(layer.routed)(h, valid))
+    assert "pallas_call" in traced and "ragged_dot" not in traced
+    np.testing.assert_array_equal(np.asarray(rows_pallas),
+                                  np.asarray(rows_ragged))
+    if want_rows is not None:
+        assert np.asarray(rows_ragged).tolist() == want_rows
+    else:
+        assert int(rows_ragged.sum()) > 64 and (rows_ragged > 0).all()
+    a = np.asarray(y_ragged, np.float32)
+    b = np.asarray(y_pallas, np.float32)
+    assert np.isfinite(b).all()
+    if int(rows_ragged.sum()):
+        assert np.abs(a).max() > 0.01
+    else:
+        assert not a.any() and not b.any()
+    np.testing.assert_allclose(b, a, atol=2 ** -8 * np.abs(a).max(),
+                               rtol=2 ** -7)
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_gradients_agree_between_the_two_grouped_products(scoring,
+                                                          monkeypatch):
+    """`jax.grad` of a scalar of `y`, with respect to the rows and to
+    every weight of the layer: `megablox.ops.gmm` carries its own
+    backward (`tgmm`), and what it leaves unwritten for the rows behind
+    the groups reaches no token's gradient."""
+    from paddle_tpu.engine import functional_apply
+
+    picks, _, share, _ = _PRODUCT_CASES["empty_one_straddle"]
+    layer, h = _held_bf16(scoring, share, picks)
+    values = dict(state_values(layer))
+    probe = jnp.asarray(np.random.RandomState(2).randn(_T, 128),
+                        jnp.float32)
+
+    def scalar(values, h):
+        y, _ = functional_apply(layer, values, lambda held: held.routed(h))
+        return (y.astype(jnp.float32) * probe).sum()
+
+    grads = jax.jit(jax.grad(scalar, argnums=(0, 1)))
+    ragged = grads(values, h)
+    _pallas_in_place_of_ragged(monkeypatch)
+    pallas = jax.jit(jax.grad(scalar, argnums=(0, 1)))(values, h)
+    flat_r, tree = jax.tree_util.tree_flatten(ragged)
+    flat_p, tree_p = jax.tree_util.tree_flatten(pallas)
+    assert tree == tree_p
+    moved = 0
+    for r, q in zip(flat_r, flat_p):
+        r, q = np.asarray(r, np.float32), np.asarray(q, np.float32)
+        assert np.isfinite(q).all()
+        moved += bool(np.abs(r).max() > 0)
+        np.testing.assert_allclose(q, r, atol=2 ** -6 * np.abs(r).max(),
+                                   rtol=2 ** -5)
+    # the rows, both expert stacks and the router at least
+    assert moved >= 4
+    # the empty expert's weights and the padding tokens' rows get none
+    held = np.asarray(pallas[0]["gate_up"], np.float32)
+    assert not held[0].any() and held[2].any()
+    assert not np.asarray(pallas[1], np.float32)[101:].any()
+
+
+def test_which_grouped_product_a_program_gets():
+    """The form follows the platform the program is lowered for and the
+    operands, nothing else: bfloat16 rows in whole row tiles lowered
+    for a TPU carry the Pallas kernel, the same call lowered for the
+    CPU, float32 operands and a handful of rows `ragged_dot`."""
+    from paddle_tpu.nlp.transformers.latent_moe import (
+        GMM_TILES, gmm_tiling, grouped_product,
+    )
+
+    def text(rows, dtype, platform):
+        x = jax.ShapeDtypeStruct((rows, 256), dtype)
+        w = jax.ShapeDtypeStruct((4, 256, 384), dtype)
+        sizes = jax.ShapeDtypeStruct((4,), jnp.int32)
+        return jax.jit(grouped_product).trace(x, w, sizes).lower(
+            lowering_platforms=(platform,)).as_text()
+
+    on_tpu = text(256, jnp.bfloat16, "tpu")
+    assert "tpu_custom_call" in on_tpu and "ragged_dot" not in on_tpu
+    # (for the CPU `ragged_dot` lowers to a masked dense product)
+    assert "tpu_custom_call" not in text(256, jnp.bfloat16, "cpu")
+    for rows, dtype in [(256, jnp.float32), (36, jnp.bfloat16)]:
+        plain = text(rows, dtype, "tpu")
+        assert "ragged_dot" in plain and "tpu_custom_call" not in plain
+    # tiles: the swept shapes from the table, any other by the rule
+    # (whole `k` down to a tile 256 columns wide, `tn` in whole lanes
+    # under 2 MiB of bfloat16 a tile)
+    assert gmm_tiling(4096, 2304, 1792) == GMM_TILES[2304, 1792]
+    assert gmm_tiling(256, 256, 384) == (128, 256, 384)
+    assert gmm_tiling(4096, 3072, 8192) == (128, 3072, 256)
+    assert gmm_tiling(4096, 16384, 1024) == (128, 4096, 256)
+    for (k, n), (tm, tk, tn) in GMM_TILES.items():
+        assert 4096 % tm == 0 and tk <= k and tn <= n and tn % 128 == 0
+        # forward: two weight tiles, two row tiles, two output tiles and
+        # the float32 accumulator; backward (`tgmm`): the accumulator
+        # and two output tiles are whole weight tiles. Both inside the
+        # 16 MiB a kernel may use (compiled for the chip in
+        # `test_v5e_compile.py`).
+        assert 2 * 2 * (tk * tn + tm * tk + tm * tn) + 4 * tm * tn \
+            < 16 << 20
+        assert (4 + 2 * 2) * tk * tn + 2 * 2 * tm * (tk + tn) < 16 << 20
 
 
 def test_vocabulary_slice_is_the_uncut_heads_first_rows():

@@ -104,6 +104,29 @@ def _all_shapes(hlo):
             for m in re.finditer(r"\b([a-z]+\d+)\[([\d,]+)\]", hlo)}
 
 
+def _grouped_products(hlo):
+    """(instruction name, dims) of every Mosaic kernel of the compiled
+    text. The experts' grouped product is one either way: XLA's own
+    `lax.ragged_dot` is called `ragged-dot-...`, JAX's Pallas
+    `megablox.gmm` `gmm` (`gmm.1`, ...), after the function that
+    wraps the kernel."""
+    return [(m.group(1), tuple(int(d) for d in m.group(2).split(",")))
+            for m in re.finditer(
+                r"%([\w.\-]+) = \w+\[([\d,]+)\]\S* custom-call\([^\n]*"
+                r"custom_call_target=\"tpu_custom_call\"", hlo)]
+
+
+def _assert_pallas_grouped_products(hlo, expert_layers, widths):
+    """Two Pallas products an expert layer over the step's 4,096 sorted
+    rows, gate-up then down, and `ragged_dot` nowhere."""
+    kernels = _grouped_products(hlo)
+    assert sorted(dims for _, dims in kernels) \
+        == sorted([(4096, w) for w in widths] * expert_layers), kernels
+    assert all(re.fullmatch(r"gmm(\.\d+)?", name) for name, _ in kernels), \
+        kernels
+    assert "ragged" not in hlo
+
+
 def test_serving_kv_path_updates_donated_pools_in_place(one_chip,
                                                         no_compile_cache):
     """With the pool token-major and donated, the entry computation
@@ -217,6 +240,46 @@ def test_latent_pool_at_its_logical_width_would_be_copied(
     assert len(_pool_copies(compiled, 576)) >= LAYERS
 
 
+# -- the experts' grouped product ---------------------------------------------
+
+
+@pytest.mark.parametrize("groups, k, n", [
+    pytest.param(64, 2304, 2 * 896, id="mellum2-gate-up"),
+    pytest.param(64, 896, 2304, id="mellum2-down"),
+    pytest.param(32, 4096, 2 * 2048, id="sarvam-gate-up"),
+    pytest.param(32, 2048, 4096, id="sarvam-down")])
+def test_grouped_product_and_its_gradient_fit_fast_memory(
+        groups, k, n, one_chip, no_compile_cache):
+    """The four products the expert cells run, 4,096 sorted rows in
+    bfloat16, with the tiles `gmm_tiling` gives them: forward `gmm`,
+    and under `jax.grad` `gmm` again (transposed) and `tgmm`, whose
+    float32 accumulator is a whole weight tile: Mosaic refuses a kernel
+    over 16 MiB of fast memory (a 4 MiB weight tile compiles forward
+    and not backward)."""
+    from paddle_tpu.nlp.transformers.latent_moe import grouped_product
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def scalar(x, w, sizes):
+        return grouped_product(x, w, sizes).astype(jnp.float32).sum()
+
+    args = (spec((4096, k), jnp.bfloat16),
+            spec((groups, k, n), jnp.bfloat16), spec((groups,), jnp.int32))
+
+    def kernels_of(compiled):
+        return sorted(re.sub(r"\.\d+$", "", name)
+                      for name, _ in _grouped_products(compiled.as_text()))
+
+    assert kernels_of(jax.jit(grouped_product).lower(*args).compile()) \
+        == ["gmm"]
+    kernels = kernels_of(jax.jit(jax.grad(scalar, argnums=(0, 1)))
+                         .lower(*args).compile())
+    # (the forward product of a sum is dead code: the transposed `gmm`
+    # for the rows and `tgmm` for the weights are left)
+    assert kernels == ["gmm", "tgmm"]
+
+
 # -- a cell's whole serving step ----------------------------------------------
 
 # The step of a benchmark cell, built from the cell's own configuration
@@ -300,7 +363,10 @@ def test_cell_step_returns_the_pick_beside_logits_that_are_not_copied(
     size of a pool. (At the cells' real depth, `-m slow`: arguments
     9.293 GB, 4.030 GB aliased and 23.1 MB of temporaries for
     gpt3-1.3b, where the step before the pick had none; 12.935 GB,
-    2.013 GB and 105.8 MB for sarvam-105b, which had 105.7 MB.)"""
+    2.013 GB and 106.7 MB for sarvam-105b, 105.8 MB before its grouped
+    product was the Pallas kernel: the kernel's group metadata.) The
+    experts' grouped product of a program lowered for a TPU is
+    `megablox.gmm`; gpt3-1.3b has none."""
     eng, num_blocks, vocab = _cell_engine(name, layers)
     slots = eng.max_slots
 
@@ -352,6 +418,13 @@ def test_cell_step_returns_the_pick_beside_logits_that_are_not_copied(
              if re.search(r" copy(-start)?\(", line)
              and any(f"[{','.join(map(str, s))}]" in line for s in shapes)]
     assert not moved, f"pool-sized copies are back: {moved}"
+    if name == "sarvam-105b":
+        # every layer but the dense first: `[4096, 2 x 2048]` gate-up,
+        # `[4096, 4096]` down
+        _assert_pallas_grouped_products(
+            hlo, eng.model.config.num_layers - 1, [4096, 4096])
+    else:
+        assert not _grouped_products(hlo)
 
 
 @pytest.mark.parametrize("layers", [
@@ -415,6 +488,7 @@ def test_hybrid_cell_step_updates_pools_and_state_arrays_in_place(
              and any(f"[{dims}]" in line.split(" = ")[1].split("(")[0]
                      for dims in big)]
     assert not moved, f"pool- or state-sized copies: {moved}"
+    assert not _grouped_products(hlo)
 
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     helper = eng._snapshot.lower(state, scalar, scalar).compile() \
@@ -437,9 +511,11 @@ def test_two_group_cell_step_updates_both_groups_pools_in_place(
     tile as they are) and no pool is copied. The host's one array
     carries both tables and the window group's base position. And the
     copy-on-write copy, one program over both groups, works in place
-    (its temporaries hold a block of each pool, 161 KB). (At the cell's real depth, `-m slow`, 11 GB
-    of host RAM: arguments 13.147 GB, 2.215 GB aliased, 24.1 MB of
-    temporaries.)"""
+    (its temporaries hold a block of each pool, 161 KB). Every layer's
+    two grouped products are the Pallas kernel. (At the cell's real
+    depth, `-m slow`, 11 GB of host RAM: arguments 13.147 GB, 2.215 GB
+    aliased, 26.1 MB of temporaries: 24.1 MB with `lax.ragged_dot`,
+    the rest the kernel's group metadata; the limit is 32 MB.)"""
     eng, num_blocks, vocab = _cell_engine("mellum2-12b-a2.5b", layers)
     slots = eng.max_slots
 
@@ -484,6 +560,7 @@ def test_two_group_cell_step_updates_both_groups_pools_in_place(
              and any(f"[{dims}]" in line.split(" = ")[1].split("(")[0]
                      for dims in big)]
     assert not moved, f"pool-sized copies: {moved}"
+    _assert_pallas_grouped_products(hlo, len(kinds), [2 * 896, 2304])
 
     pair = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
     helper = eng._cow.lower(pools, pair, pair).compile().memory_analysis()
