@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 
 import jax
 import jax.numpy as jnp
@@ -662,6 +662,9 @@ class Engine:
         self._batch_sig = None
         self._ckpt_manager = None
         self._last_batch = None
+        # the losses of dispatched steps the device had not finished at
+        # the last dispatch, oldest first
+        self._in_flight = deque()
 
     def _build(self):
         self._step_fn = make_train_step(
@@ -693,8 +696,19 @@ class Engine:
             else jnp.asarray(t)
             for t in ts)
 
+    def _steps_in_flight(self):
+        """Steps dispatched and not yet finished, without a sync: a
+        step's loss is ready when the step is done, and steps finish in
+        the order they were dispatched. 0 = the next dispatch goes to a
+        device with nothing to do."""
+        flying = self._in_flight
+        while flying and flying[0].is_ready():
+            flying.popleft()
+        return len(flying)
+
     def train_batch(self, inputs, labels=()):
         from . import observe as _observe
+        from .framework import monitor as _monitor
 
         t_step0 = time.perf_counter()
         if self._step_fn is None:
@@ -742,11 +756,17 @@ class Engine:
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                 (self.state.params, self.state.buffers, opt_state,
                  batch, lr, key))
+        in_flight = self._steps_in_flight()
+        _monitor.stat_add("train_steps")
+        if in_flight == 0:
+            _monitor.stat_add("train_dispatches_device_idle")
         t_fn0 = time.perf_counter()
-        with _observe.phase("compile" if compiling else "device-step"):
+        with _observe.phase("compile" if compiling else "device-step",
+                            step=self.state.step + 1, in_flight=in_flight):
             loss, self.state.params, self.state.buffers, new_opt = \
                 self._step_fn(self.state.params, self.state.buffers,
                               opt_state, batch, lr, key)
+        self._in_flight.append(loss)
         if compiling:
             # the step body's trace-time record_compile logged the
             # event; backfill how long trace+compile+first-dispatch took
@@ -770,14 +790,14 @@ class Engine:
                 with _observe.phase("anomaly-readback"):
                     self._check_anomaly()
         self._flight_record(loss, compiling,
-                            time.perf_counter() - t_step0)
+                            time.perf_counter() - t_step0, in_flight)
         from . import profiler as _profiler
 
         if _profiler.is_op_profiling_enabled():
             _profiler.record_device_memory("train_batch")
         return Tensor(loss)
 
-    def _flight_record(self, loss, compiling, step_s):
+    def _flight_record(self, loss, compiling, step_s, in_flight):
         """One flight-recorder entry per step. Loss / grad-norm /
         anomaly counter stay as device arrays (no host sync here); the
         recorder materializes them only when a black box is dumped."""
@@ -785,7 +805,7 @@ class Engine:
         from .framework import flags as _flags
 
         fields = {"loss": loss, "step_ms": step_s * 1e3,
-                  "compiled": compiling}
+                  "in_flight": in_flight, "compiled": compiling}
         if self._record_grad_norm:
             fields["grad_norm"] = self.state.buffers[GRAD_NORM_KEY]
         if self.anomaly_guard:

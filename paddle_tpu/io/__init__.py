@@ -25,7 +25,7 @@ import numpy as np
 
 from .. import observe
 from ..core.tensor import Tensor
-from ..framework import random as _random
+from ..framework import monitor, random as _random
 
 
 class Dataset:
@@ -349,7 +349,6 @@ class _MultiprocessIter:
         self.num_workers = loader.num_workers
         self.timeout = loader.timeout or None  # 0/None => wait, watch pool
         ctx = mp.get_context("fork")
-        self.result_queue = ctx.Queue()
         self.index_queues = []
         self.workers = []
         # fresh base seed per iterator/epoch: identical reseeding every
@@ -359,9 +358,21 @@ class _MultiprocessIter:
         base_seed = (int(_random.default_generator.initial_seed())
                      * 1000003 + epoch * 7919) & 0x7FFFFFFF
         collate = loader._worker_collate_fn
-        # spans close in this (the parent) process only, and hold no
-        # lock while open: nothing is held across the fork
+        self._next_send = 0
+        self._next_recv = 0
+        self._reorder: dict[int, object] = {}
+        self._batches = iter(loader._index_batches())
+        self._exhausted = False
+        self._window = max(2, loader.prefetch_factor * self.num_workers)
+        self._shutdown_done = False
+        monitor.stat_add("input_epochs")
+        # the whole start of an epoch's pool: its queues, the forks, and
+        # the first index lists (a queue's first put starts its feeder
+        # thread, slow in a process that has just forked). Spans close
+        # in this (the parent) process only, and hold no lock while
+        # open: nothing is held across the fork
         with observe.span("input.spawn", cat="input"):
+            self.result_queue = ctx.Queue()
             for w in range(self.num_workers):
                 iq = ctx.Queue()
                 p = ctx.Process(
@@ -373,15 +384,8 @@ class _MultiprocessIter:
                 p.start()
                 self.index_queues.append(iq)
                 self.workers.append(p)
-        self._next_send = 0
-        self._next_recv = 0
-        self._reorder: dict[int, object] = {}
-        self._batches = iter(loader._index_batches())
-        self._exhausted = False
-        self._window = max(2, loader.prefetch_factor * self.num_workers)
-        self._shutdown_done = False
-        for _ in range(self._window):
-            self._dispatch_one()
+            for _ in range(self._window):
+                self._dispatch_one()
 
     def _dispatch_one(self):
         if self._exhausted:
@@ -402,9 +406,19 @@ class _MultiprocessIter:
         if self._next_recv >= self._next_send and self._exhausted:
             self._shutdown()
             raise StopIteration
-        if self._next_recv not in self._reorder:
-            with observe.span("input.wait", cat="input"):
-                self._take_next()
+        # an iterator's first take waits for workers that have only just
+        # been forked: it belongs to the epoch's turnover, and
+        # `input.wait` is a steady-state take only. `ready` = how far
+        # the workers are ahead of the consumer: the batches they have
+        # put and this side has not yielded (the queue's count is a
+        # semaphore's value: nothing is read for it)
+        ready = len(self._reorder) + self.result_queue.qsize()
+        name = "input.wait" if self._next_recv else "input.first_batch"
+        with observe.span(name, cat="input", ready=ready):
+            self._take_next()
+        monitor.stat_add("input_batches")
+        if ready == 0:
+            monitor.stat_add("input_batches_waited")
         status, data = self._reorder.pop(self._next_recv)
         self._next_recv += 1
         self._dispatch_one()
@@ -412,7 +426,8 @@ class _MultiprocessIter:
             self._shutdown()
             raise RuntimeError(
                 f"DataLoader worker raised {data.type_name}:\n{data.tb}")
-        return _to_tensor_tree(data)
+        with observe.span("input.convert", cat="input"):
+            return _to_tensor_tree(data)
 
     def _take_next(self):
         """Block on the workers' queue until the next batch in order
@@ -440,16 +455,19 @@ class _MultiprocessIter:
         if self._shutdown_done:
             return
         self._shutdown_done = True
-        for iq in self.index_queues:
-            try:
-                iq.put(None)
-            except (OSError, ValueError):
-                pass
-        for p in self.workers:
-            p.join(timeout=5)
-            if p.is_alive():
-                p.terminate()
-        self.result_queue.close()
+        # runs inside the `next()` that returns an epoch's LAST batch
+        # when `_device_prefetch` reads one batch ahead
+        with observe.span("input.close", cat="input"):
+            for iq in self.index_queues:
+                try:
+                    iq.put(None)
+                except (OSError, ValueError):
+                    pass
+            for p in self.workers:
+                p.join(timeout=5)
+                if p.is_alive():
+                    p.terminate()
+            self.result_queue.close()
 
     def __del__(self):
         try:
@@ -475,7 +493,8 @@ def _device_prefetch(iterator):
 
     prev = None
     for batch in iterator:
-        cur = put(batch)
+        with observe.span("input.convert", cat="input"):
+            cur = put(batch)
         if prev is not None:
             yield prev
         prev = cur

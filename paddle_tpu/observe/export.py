@@ -40,6 +40,9 @@ _GOODPUT_CATS = {
     "commit": "host",
     "input.wait": "host",
     "input.spawn": "host",
+    "input.close": "host",
+    "input.first_batch": "host",
+    "input.convert": "host",
     "anomaly-readback": "host",
     # a serving loop with no live slot, parked on its queue
     "loop.idle": "idle",

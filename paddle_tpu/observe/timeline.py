@@ -6,7 +6,13 @@ The runtime's engines wrap each stage of a step in a named phase::
     h2d                  host->device batch placement (+ offload moves)
     compile              a step call that traces/compiles a new program
     device-step          training: the compiled step's dispatch (the
-                         enqueue; closing it would need a sync).
+                         enqueue; closing it would need a sync). Its
+                         event carries `step` and `in_flight`, the steps
+                         dispatched before it that the device had not
+                         finished (their losses' `is_ready()`, no sync):
+                         0 is an enqueue onto a device with nothing to
+                         do, so the events say where the device ran dry
+                         though none of them times a step.
                          serving: the time a step held the device as
                          the host sees it, later of (its dispatch, the
                          previous step's landing) -> its picks on the
@@ -34,14 +40,25 @@ and the serving loop's thread spans every part of an iteration::
 
 and the input pipeline's consumer (parent process only)::
 
-    input.spawn          starting one epoch's fork workers
-    input.wait           the blocking take from the worker queue
+    input.close          an epoch's end: the sentinels to its workers
+                         and their joins
+    input.spawn          starting one epoch's fork workers: their
+                         queues, the forks, the first index lists
+    input.first_batch    an iterator's first take, from workers that
+                         have only just started (`ready`, as below)
+    input.wait           a steady-state take from the worker queue;
+                         `ready` = batches the workers had put and the
+                         consumer not yet yielded (at 0 the take waits
+                         for a worker)
+    input.convert        a host batch to `Tensor`s, and the prefetcher's
+                         `device_put`
 
 A `phase(name)` context emits the `profiler.RecordEvent` span
 `step.<name>`, a `span(name)` context the span `<name>` as it stands;
 both land in the chrome trace and, being `TraceAnnotation`s, in a
-profiler capture on the device trace's clock. Both fold the duration
-into an O(1) aggregate under `name` here — the aggregate is what
+profiler capture on the device trace's clock. Keyword `fields` ride on
+the span's ring event and are the annotation's stats. Both fold the
+duration into an O(1) aggregate under `name` here — the aggregate is what
 `goodput()` and the Prometheus export read, so the timeline stays
 bounded no matter how long the run is.
 
@@ -68,12 +85,12 @@ class _Timed:
 
     __slots__ = ("_timeline", "_name", "_event", "_t0")
 
-    def __init__(self, timeline, name, span, cat):
+    def __init__(self, timeline, name, span, cat, fields):
         from .. import profiler
 
         self._timeline = timeline
         self._name = name
-        self._event = profiler.RecordEvent(span, cat=cat)
+        self._event = profiler.RecordEvent(span, cat=cat, **fields)
 
     def __enter__(self):
         self._t0 = time.perf_counter()
@@ -93,15 +110,16 @@ class StepTimeline:
         self._lock = threading.Lock()
         self._agg: dict = {}  # name -> [calls, total_s, max_s]
 
-    def phase(self, name, cat="phase"):
-        """Span `step.<name>`, aggregate `name`."""
-        return _Timed(self, name, f"step.{name}", cat)
+    def phase(self, name, cat="phase", **fields):
+        """Span `step.<name>`, aggregate `name`; `fields` ride on the
+        span's ring event and its trace annotation."""
+        return _Timed(self, name, f"step.{name}", cat, fields)
 
-    def span(self, name, cat="phase"):
+    def span(self, name, cat="phase", **fields):
         """Span and aggregate both `name`: for what is no stage of a
         step (the serving loop's iteration, its idle wait, the input
         pipeline's waits)."""
-        return _Timed(self, name, name, cat)
+        return _Timed(self, name, name, cat, fields)
 
     def add(self, name, seconds):
         """Fold an externally-timed duration into a phase aggregate."""

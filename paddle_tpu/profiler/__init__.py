@@ -55,11 +55,16 @@ class RecordEvent:
     """Named host-side span (ref platform/profiler.h RecordEvent).
 
     Context manager; nests. Also emits a jax TraceAnnotation so the name
-    appears in XProf device timelines captured via start_trace."""
+    appears in XProf device timelines captured via start_trace.
 
-    def __init__(self, name, cat="host"):
+    `fields` ride along on the ring event, as `record_span`'s do, and
+    are the annotation's keyword stats, so a capture shows them on the
+    device trace's clock."""
+
+    def __init__(self, name, cat="host", **fields):
         self.name = name
         self.cat = cat
+        self.fields = fields
         self._t0 = None
         self._jax_ann = None
 
@@ -70,7 +75,7 @@ class RecordEvent:
         try:
             import jax.profiler as jp
 
-            self._jax_ann = jp.TraceAnnotation(self.name)
+            self._jax_ann = jp.TraceAnnotation(self.name, **self.fields)
             self._jax_ann.__enter__()
         except Exception:
             self._jax_ann = None
@@ -81,12 +86,12 @@ class RecordEvent:
             self._jax_ann.__exit__(*exc)
         dur = _now_us() - self._t0
         _tls.depth -= 1
+        ev = {"name": self.name, "cat": self.cat, "ts": self._t0,
+              "dur": dur, "tid": threading.get_ident(),
+              "depth": _tls.depth}
+        ev.update(self.fields)
         with _lock:
-            _events.append({
-                "name": self.name, "cat": self.cat, "ts": self._t0,
-                "dur": dur, "tid": threading.get_ident(),
-                "depth": _tls.depth,
-            })
+            _events.append(ev)
         return False
 
 
