@@ -432,8 +432,18 @@ def make_train_step(layer, loss_fn, optimizer, *, grad_clip=None,
                 return loss * scale, (loss, nb)
             return loss, (loss, nb)
 
+        from .framework import monitor as _monitor
+
+        traced = ("dropout_masks_traced", "dropout_mask_elements_traced")
+        before = [_monitor.stat_get(name) for name in traced]
         (_, (loss, new_buffers)), grads = jax.value_and_grad(
             scaled_loss, has_aux=True)(params, model_buffers, batch, key)
+        # the dropout masks this program draws, counted where they were
+        # traced: one a site, whatever reads it forward and backward
+        masks, elements = (_monitor.stat_get(name) - was
+                           for name, was in zip(traced, before))
+        _observe.annotate("train_step", dropout_masks=masks,
+                          dropout_mask_elements=elements)
         if loss_scale is not None:
             grads = jax.tree.map(lambda g: g / scale, grads)
             # finiteness is judged on the raw unscaled grads BEFORE

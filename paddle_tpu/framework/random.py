@@ -90,7 +90,14 @@ def next_key():
 
 
 def seed(s):
-    """paddle.seed"""
+    """paddle.seed: reseed the default generator and restart its site
+    counter. Keys, parameter initialisers, sampling and `random_ops`
+    draw from JAX's Threefry, whose values are the same on every
+    platform. A dropout keep-mask takes its key from here too, but its
+    bits from XLA's bit generator (`ops/_common.keep_mask_u16`): a
+    fixed seed gives the same masks run after run on ONE platform, and
+    other masks, as fair, on another (a meshed step's compiler may also
+    draw a sharded mask shard by shard; the CPU's does not)."""
     default_generator.manual_seed(s)
     return default_generator
 

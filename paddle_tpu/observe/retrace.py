@@ -71,15 +71,19 @@ def record_compile(name, *args, signature=None):
             f"changed (pad batches / bucket sequence lengths)")
 
 
-def annotate(name, wall_s=None, peak_bytes=None):
-    """Attach wall time / memory peak to the most recent `name` event."""
+def annotate(name, wall_s=None, peak_bytes=None, **counts):
+    """Attach wall time / memory peak / trace-time counts (ints, e.g.
+    the train step's `dropout_masks`) to the most recent `name` event."""
     with _lock:
+        if _suppressed:
+            return
         for ev in reversed(_events):
             if ev["name"] == name:
                 if wall_s is not None:
                     ev["wall_s"] = wall_s
                 if peak_bytes is not None:
                     ev["peak_bytes"] = int(peak_bytes)
+                ev.update({k: int(v) for k, v in counts.items()})
                 return
 
 

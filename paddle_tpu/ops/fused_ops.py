@@ -491,8 +491,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, seed, scale, causal,
 
 def _jnp_keep_mask(seed, shape, dropout_p):
     """bool keep mask (u16 threshold compare, see _common.keep_mask_u16):
-    random-bit traffic dominates attention-dropout cost on this path —
-    one s x s bits array per layer per pass."""
+    16 bits a score from XLA's bit generator, keyed by a Threefry key
+    made of the seed. The forward and the backward each call this with
+    the same seed, so the mask is drawn twice a layer and no s x s
+    array is kept between them; random-bit traffic dominates
+    attention-dropout cost on this path."""
     from ._common import keep_mask_u16
 
     key = jax.random.PRNGKey(seed.astype(jnp.uint32))

@@ -188,8 +188,10 @@ def test_bf16_matmul_mxu_tolerance():
 
 
 def test_dropout_rbg_prng_on_chip():
-    """Dropout on the chip's PRNG: masks are
-    deterministic for a fixed key and differ across keys."""
+    """Dropout on the chip's bit generator (`keep_mask_u16` draws
+    from `lax.rng_bit_generator`, the TPU's own algorithm, since PR
+    39): masks are deterministic for a fixed key and differ across
+    keys."""
     from paddle_tpu.nn import functional as F
     from paddle_tpu.core.tensor import Tensor
     import paddle_tpu as paddle

@@ -566,3 +566,94 @@ def test_two_group_cell_step_updates_both_groups_pools_in_place(
     helper = eng._cow.lower(pools, pair, pair).compile().memory_analysis()
     assert helper.alias_size_in_bytes == pool_bytes
     assert helper.temp_size_in_bytes < _MB      # a block a pool, staged
+
+
+# ernie-base.pretrain behind benchmarks/configs/ernie-base.json and
+# benchmarks/traffic/pretrain-mlm.json: 64 x 512 tokens a step
+TRAIN_BATCH, TRAIN_SEQ = 64, 512
+
+
+def _train_cell_step(layers, one_chip, monkeypatch):
+    """The cell's `Engine` as its runner builds it, `layers` deep, and
+    its step compiled from shapes for the described chip under the
+    cell's bf16 autocast. A chip's dispatch takes the Mosaic kernels;
+    here the platform is the CPU, so the test says so in their place."""
+    import json
+    from pathlib import Path
+
+    from benchmarks.runners import train as runner
+    from paddle_tpu import amp
+    from paddle_tpu.engine import compile_step
+    from paddle_tpu.framework import random as _random
+    from paddle_tpu.ops import fused_loss, fused_ops
+
+    monkeypatch.setattr(fused_ops, "_use_pallas", lambda seq_q=None: True)
+    monkeypatch.setattr(fused_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(fused_loss, "_use_pallas_lm", lambda: True)
+    monkeypatch.setattr(fused_loss, "_interpret", lambda: False)
+    config = json.loads((Path(__file__).resolve().parent.parent
+                         / "benchmarks" / "configs" / "ernie-base.json")
+                        .read_text())
+    config["model"]["num_layers"] = layers
+    cfg, engine = runner._build(types.SimpleNamespace(config=config, seed=1))
+    engine._build()
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    ids = jax.ShapeDtypeStruct((TRAIN_BATCH, TRAIN_SEQ), jnp.int32,
+                               sharding=one_chip)
+    state = engine.state
+    protos = (*jax.tree.map(spec, (state.params, state.buffers,
+                                   state.opt_state)),
+              {"inputs": (ids,), "labels": (ids,)},
+              spec(jnp.asarray(config["training"]["learning_rate"],
+                               jnp.float32)),
+              spec(_random.default_generator.next_key()))
+    with amp.auto_cast(enable=True, dtype=config["training"]["autocast"]):
+        return cfg, compile_step(engine._step_fn, protos)
+
+
+@pytest.mark.parametrize("layers, peak_limit", [
+    (2, 3.15e9),
+    pytest.param(12, 15.3e9, marks=pytest.mark.slow),
+])
+def test_train_cell_step_draws_each_dropout_mask_once(
+        layers, peak_limit, one_chip, no_compile_cache, monkeypatch):
+    """`ernie-base.pretrain`'s step at its real widths: one
+    `rng-bit-generator` of the mask's shape a dropout site (1 in the
+    embeddings, 2 a layer), read by ONE instruction, the compare that
+    makes the one-byte mask every other reader takes; and no fused
+    computation derives a mask again (Threefry's `xor` over a
+    `[64, 512, 768]` shape: 100 masks' worth for 25 sites before).
+    Peak, arguments + temporaries + results - aliased (sandbox CPU,
+    PR 39): 2 layers 3.105 GB (kept as 16 bits a position 3.206,
+    Threefry 2.855); the cell's 12 layers 15.103 GB of the chip's
+    16.909 (16 bits 15.812, Threefry 14.407), under `-m slow` (a
+    minute). The Mosaic kernels are in it: flash forward, dq, dk/dv a
+    layer and the LM-head loss's three."""
+    cfg, compiled = _train_cell_step(layers, one_chip, monkeypatch)
+    hlo = compiled.as_text()
+    mask = (TRAIN_BATCH, TRAIN_SEQ, cfg.hidden_size)
+    dims = ",".join(map(str, mask))
+
+    draws = [(name, dtype, shape)
+             for name, dtype, shape, op in _entry_instructions(hlo)
+             if op == "rng-bit-generator"]
+    assert len(draws) == 1 + 2 * layers, draws
+    assert {(dtype, shape) for _, dtype, shape in draws} == {("u16", mask)}
+    entry = hlo[hlo.index("ENTRY"):]
+    for name, _, _ in draws:
+        readers = re.findall(rf"%{re.escape(name)}[,)]", entry)
+        assert len(readers) == 1, (name, len(readers))
+    rederived = [line.strip()[:120] for line in hlo.splitlines()
+                 if " xor(" in line and f"[{dims}]" in line]
+    assert not rederived, rederived[:3]
+    assert hlo.count("tpu_custom_call") >= 3 * layers + 3
+
+    memory = compiled.memory_analysis()
+    peak = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(f"train step, {layers} layers: compiled peak {peak} B "
+          f"(temporaries {memory.temp_size_in_bytes} B)")
+    assert peak < peak_limit, peak
